@@ -14,7 +14,7 @@ def main(argv=None) -> int:
         prog="khipu_tpu", description="khipu-tpu node"
     )
     parser.add_argument("--engine", default="memory",
-                        choices=["memory", "native", "sqlite"])
+                        choices=["memory", "native", "sqlite", "kesque"])
     parser.add_argument("--data-dir", default=None)
     parser.add_argument("--chain-id", type=int, default=1)
     parser.add_argument("--rpc-port", type=int, default=8546)
@@ -27,9 +27,11 @@ def main(argv=None) -> int:
                         help="route trie commits through the TPU batch path")
     args = parser.parse_args(argv)
 
+    from khipu_tpu import device
     from khipu_tpu.config import DbConfig, fixture_config
     from khipu_tpu.service_board import ServiceBoard
 
+    device.place_compile_cache()
     config = dataclasses.replace(
         fixture_config(chain_id=args.chain_id),
         db=DbConfig(engine=args.engine, data_dir=args.data_dir),

@@ -6,7 +6,7 @@ honest if EVERY ``jax.device_get`` / ``jax.device_put`` /
 ``.block_until_ready()`` site is metered. A crossing added outside the
 ledger silently disappears from ``khipu_device_transfer_*`` and the
 gate's bytes/block ratio — the budget then lies exactly when it is
-supposed to catch a regression (docs/roofline.md "the tunnel tax").
+supposed to catch a regression.
 
 A crossing counts as metered when it is lexically inside a
 ``with *.transfer(...)`` timing context, or when the enclosing function

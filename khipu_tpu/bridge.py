@@ -143,6 +143,12 @@ class BridgeServer:
         self.device_commit = device_commit
         self.max_workers = max_workers
         self._exec_lock = threading.Lock()  # blocks apply serially
+        # ONE driver for the server's lifetime (built on the first
+        # ExecuteBlocks): its device mirror and adaptive controller
+        # are device state that must outlive a batch — a driver per
+        # call re-allocated the mirror in HBM and restarted the
+        # controller's history every few windows
+        self._driver = None
         self._server: Optional[grpc.Server] = None
         # the SHARD's own span ring (per-instance: two in-process
         # servers — the 2-shard tests — must not interleave rings),
@@ -171,13 +177,15 @@ class BridgeServer:
                 grpc.StatusCode.INVALID_ARGUMENT, f"bad batch: {e}"
             )
         with self._exec_lock:
-            driver = ReplayDriver(
-                self.blockchain, self.config,
-                device_commit=self.device_commit,
-                tracer=self.tracer,
-            )
+            if self._driver is None:
+                self._driver = ReplayDriver(
+                    self.blockchain, self.config,
+                    device_commit=self.device_commit,
+                    tracer=self.tracer,
+                )
             try:
-                driver.replay(blocks)
+                # khipu-lint: ok KL004 the lock IS the serial-apply rule: batches execute one at a time
+                self._driver.replay(blocks)
             except Exception as e:
                 context.abort(
                     grpc.StatusCode.FAILED_PRECONDITION,

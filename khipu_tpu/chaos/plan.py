@@ -14,7 +14,7 @@ a per-(rule, site) RNG derived from ``(seed, rule index, site)`` and
 is consumed in per-site hit order, so the same seed over the same
 workload fires the same faults at the same hits, run after run.
 
-Fault taxonomy (docs/recovery.md):
+Fault kinds (docs/recovery.md):
 
 * ``raise``   — raise ``InjectedFault`` (an ``Exception``): transport
   errors, store failures. Exercises retries, breakers, failover and

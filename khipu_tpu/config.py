@@ -91,7 +91,7 @@ class DbConfig:
     """Engine selection (DbConfig.scala:5-19): the values here are the
     engines Storages actually dispatches on."""
 
-    engine: str = "memory"  # memory | native
+    engine: str = "memory"  # memory | native | sqlite | kesque
     data_dir: Optional[str] = None
     cache_size: int = 1 << 20  # node FIFO cache entries (cache-size)
     unconfirmed_depth: int = 20  # block-resolving-depth reorg ring
@@ -121,10 +121,13 @@ class SyncConfig:
     sender_cache_entries: int = 65536  # LRU cap (~100 B/entry)
     # batch the per-tx signing-hash keccaks through ops.keccak when a
     # TPU backend is up (one device call per block instead of N host
-    # hashes). Host keccak is native C (~7 us/hash), so the batch path
-    # only engages where the device genuinely wins; on CPU backends
-    # this knob is a no-op
-    sender_batch_hash: bool = True
+    # hashes); on the CPU backend this knob is a no-op. OFF by default:
+    # on an attached v5e one 200-tx block's pre-images take 4.0 ms
+    # through the Pallas path (pad, upload a 1024-row tile, dispatch,
+    # fetch) against 0.53 ms on the host's native keccak (my chip run,
+    # PR 21, medians of 30) — a loss at the widest blocks the replay
+    # sees. Still available; ROADMAP Queue 3 item 4 keeps the question
+    sender_batch_hash: bool = False
     # fast-sync pivot choice (FastSyncService.scala:184-273 role)
     min_peers_to_choose_pivot: int = 5
     pivot_block_offset: int = 500  # pivot = median(best) - offset

@@ -722,10 +722,11 @@ class EthService:
 
     def khipu_window_costs(self, number) -> dict:
         """Roofline verdict for the window containing block ``n``:
-        per-seal-sub-phase attainable vs achieved seconds against the
-        calibrated floors (docs/roofline.md — tunnel rate, dispatch
-        RTT, kernel hash rate), each classified bytes-bound /
-        dispatch-bound / compute-bound / fixed-overhead, plus the
+        per-seal-sub-phase attainable vs achieved seconds against this
+        device's measured floors (costmodel.DEVICE_FLOORS — upload
+        rate, fetch round trip, kernel hash rate), each classified
+        bytes-bound / dispatch-bound / compute-bound / fixed-overhead
+        (or "not calibrated" on a device without a row), plus the
         headline verdict naming the costliest sub-phase."""
         from khipu_tpu.observability import costmodel
 

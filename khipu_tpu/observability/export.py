@@ -89,7 +89,7 @@ def counter_tracks(spans: Optional[Sequence[Span]] = None,
 
     * ``transfer bytes in flight`` — running sum per direction: +nbytes
       at each transfer's start, -nbytes at its end, so perfetto shows
-      WHEN the host↔device tunnel was loaded, not just how much total;
+      WHEN the host↔device link was loaded, not just how much total;
     * ``transfer bytes (cumulative)`` — per-phase cumulative bytes, the
       area chart that makes "collect moved 10x what seal did" visual;
     * ``pipeline occupancy`` — the recorder's driver/collector coverage
@@ -110,7 +110,7 @@ def counter_tracks(spans: Optional[Sequence[Span]] = None,
         cum_events: List[tuple] = []
         for e in transfers:
             if e.direction == "host":
-                continue  # host persistence is not tunnel traffic
+                continue  # host persistence is not device traffic
             edges.append((e.t0, e.direction, e.nbytes))
             edges.append((e.t0 + e.duration, e.direction, -e.nbytes))
             phase = e.phase or "untagged"

@@ -328,7 +328,7 @@ def window_report(number: int, spans: Sequence[Span] = ()) -> dict:
     with the span-derived phase wall seconds when a snapshot is given.
     This is what the ``khipu_window_report(n)`` RPC serves — the answer
     to "WHICH bytes crossed for this window, from which site, during
-    which phase" that BENCH_r05's collect-share number begs for.
+    which phase" that a bare collect-share number begs for.
 
     Returns ``{"found": False, ...}`` when the ledger has no window
     covering ``number`` (ledger disabled, or the window rotated out).

@@ -1,4 +1,5 @@
-"""Batched Keccak-256 dispatcher: Pallas TPU kernel with jnp fallback.
+"""Batched Keccak-256 dispatcher: the Pallas kernel on TPU, the jnp
+sponge on CPU (tests).
 
 The public hashing entry point for the framework (trie commit, fast-sync
 snapshot verify, content addressing). Replaces the reference's scalar
@@ -10,25 +11,17 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
-import jax
-
+from khipu_tpu import device
 from khipu_tpu.ops.keccak_jnp import keccak256_batch_jnp
-
-
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def keccak256_batch(messages: Sequence[bytes], impl: str = "auto") -> List[bytes]:
     """Hash a batch of byte strings to 32-byte Keccak-256 digests.
 
-    impl: "auto" (pallas on TPU, jnp elsewhere), "jnp", or "pallas".
+    impl: "auto" (pallas on TPU, jnp on CPU), "jnp", or "pallas".
     """
     if impl == "auto":
-        impl = "pallas" if _tpu_available() else "jnp"
+        impl = "pallas" if device.platform() == "tpu" else "jnp"
     if impl == "pallas":
         from khipu_tpu.ops.keccak_pallas import keccak256_batch_pallas
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from khipu_tpu.native.keccak import keccak256_batch as _host_keccak_batch
 from khipu_tpu.observability.profiler import D2H, H2D, LEDGER
 from khipu_tpu.ops.keccak_jnp import (
     _RC32,
@@ -36,6 +37,16 @@ from khipu_tpu.ops.keccak_jnp import (
 )
 
 TILE = 8 * 128  # messages per grid step
+
+# Largest rate-block class the kernel is built for. State-trie nodes
+# need classes 1-4 (a full branch is ~532 B) and the 576 B snapshot
+# class is 5. The sponge is unrolled in Python, so compile time grows
+# with the class (~2.4-3 s per block on a v5e: 16 blocks ~45 s, a
+# 24 KB contract-creation pre-image at 181 blocks ~9 min) and the
+# (1, nwords, 8, 128) u32 input block outgrows scoped VMEM. Messages
+# past the bound hash on the host's native Keccak instead, so no
+# caller can ask Mosaic for such a program.
+MAX_PALLAS_BLOCKS = 5
 
 
 def _make_kernel(nblocks: int, nwords_in: int = None):
@@ -83,6 +94,11 @@ def _build(nblocks: int, interpret: bool, nwords_in: int = None):
     ``nwords_in``, input planes carry only the message words and the
     pad is fused in-kernel. Normalizes the default BEFORE memoizing so
     `_build(n, i)` and `_build(n, i, nwords_in=full)` share one compile."""
+    if nblocks > MAX_PALLAS_BLOCKS:
+        raise ValueError(
+            f"rate class {nblocks} exceeds the Pallas bound "
+            f"({MAX_PALLAS_BLOCKS} blocks); hash it on the host"
+        )
     full = nblocks * 2 * LANES_PER_BLOCK
     if nwords_in is not None and nwords_in >= full:
         nwords_in = None
@@ -242,6 +258,11 @@ def keccak256_fixed(
     """
     n, length = data.shape
     nblocks = length // RATE + 1
+    if nblocks > MAX_PALLAS_BLOCKS:
+        digests = _host_keccak_batch([row.tobytes() for row in data])
+        return np.frombuffer(b"".join(digests), dtype=np.uint8).reshape(
+            n, 32
+        )
     padded = np.zeros((n, nblocks * RATE), dtype=np.uint8)
     padded[:, :length] = data
     padded[:, length] ^= 0x01
@@ -258,7 +279,9 @@ def keccak256_fixed(
         )
     with LEDGER.transfer("ops.keccak", D2H, int(out.size) * 4):
         got = jax.device_get(out)
-    digest_words = np.asarray(got, dtype="<u4")[:n]
+    # contiguous copy: a TPU fetch may hand back a strided array, which
+    # cannot be re-viewed as bytes
+    digest_words = np.ascontiguousarray(got, dtype="<u4")[:n]
     return digest_words.view(np.uint8).reshape(n, 32)
 
 
@@ -299,8 +322,21 @@ def keccak256_batch_pallas(
 
     Buckets by rate-block count, zero-pads each bucket to a whole
     1024-message tile (padding digests discarded), chunks at MAX_TILES.
+    Messages past MAX_PALLAS_BLOCKS rate blocks never reach the kernel:
+    they hash on the host and scatter back into input order.
     """
     from khipu_tpu.ops.keccak_jnp import bucketed_batch
+
+    limit = MAX_PALLAS_BLOCKS * RATE  # first length of the next class
+    is_long = [len(m) >= limit for m in messages]
+    if any(is_long):
+        long_d = iter(_host_keccak_batch(
+            [m for m, lg in zip(messages, is_long) if lg]
+        ))
+        short_d = iter(keccak256_batch_pallas(
+            [m for m, lg in zip(messages, is_long) if not lg], interpret
+        ))
+        return [next(long_d if lg else short_d) for lg in is_long]
 
     def run_bucket(nblocks, msgs):
         packed = pad_to_words(msgs, nblocks)  # (B, nwords) batch-major

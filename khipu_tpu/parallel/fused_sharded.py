@@ -49,9 +49,8 @@ def _build_fused_sharded(sig: Tuple[Tuple[int, int, int], ...],
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from khipu_tpu.parallel.compat import shard_map
 
     from khipu_tpu.ops.keccak_jnp import hash_padded_u8 as _hash
 

@@ -20,9 +20,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from khipu_tpu.parallel.compat import shard_map
 
 from khipu_tpu.observability.profiler import D2H, H2D, LEDGER
 from khipu_tpu.ops.keccak_jnp import LANES_PER_BLOCK, RATE, absorb

@@ -22,10 +22,16 @@ AXIS = "nodes"
 def device_mesh(n_devices: Optional[int] = None, axis_name: str = AXIS) -> Mesh:
     """A 1-D mesh over the first ``n_devices`` available devices.
 
-    On real hardware the devices are the v5e slice's chips; in tests a
-    virtual CPU mesh (``--xla_force_host_platform_device_count=8``)
-    stands in, exactly as akka-multi-node-testkit would have for the
-    reference's cluster (SURVEY §4).
+    On real hardware the devices are the host's v5e chips; in tests a
+    virtual CPU mesh (``--xla_force_host_platform_device_count=8``, set
+    from outside) stands in. The devices must exist — nothing here
+    conjures a mesh.
+
+    The replay path (bridge -> windowed replay -> fused commit -> RPC)
+    does NOT come through here: it is single-device, everything it
+    dispatches lands on ``jax.devices()[0]``. ``parallel/`` is reached
+    only from ``__graft_entry__.py`` (dry run), chip_smoke's multi-chip
+    leg and the tests.
     """
     devs = jax.devices()
     if n_devices is None:

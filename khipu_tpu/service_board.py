@@ -22,6 +22,12 @@ from khipu_tpu.txpool import OmmersPool, PendingTransactionsPool
 class ServiceBoard:
     def __init__(self, config: KhipuConfig,
                  genesis: Optional[GenesisSpec] = None):
+        from khipu_tpu import device
+
+        # before anything compiles: one fused window signature is
+        # ~30 s cold on a v5e, and a node should pay it once per
+        # checkout, not once per start
+        device.place_compile_cache()
         self.config = config
         self.storages = Storages(
             engine=config.db.engine,

@@ -3,9 +3,9 @@ actually fast at (docs/roofline.md "The adaptive commit rule").
 
 The device-resident commit (storage/device_mirror.py) is a bet: that
 d2d gathers and the fused fixpoint beat the host memcpy + scalar keccak
-they replaced. BENCH_r07 shows the bet losing 20x on a 1-core CPU
-backend — there "device" memory IS host RAM, so every d2d gather is a
-memcpy with dispatch overhead on top, and the fused fixpoint re-hashes
+they replaced. On the CPU backend the bet loses ~20x (BENCH_r07) —
+there "device" memory IS host RAM, so every d2d gather is a memcpy with
+dispatch overhead on top, and the fused fixpoint re-hashes
 ``rounds x padded_rows`` where the host path hashes each node once.
 This module closes the loop the cost model (observability/costmodel.py)
 opened: measure, decide, and keep deciding.
@@ -20,8 +20,10 @@ Two instruments, one controller:
   construction, clear the margin. The probe's upload is billed to the
   ledger site ``adaptive.probe`` (KL001).
 * ``AdaptiveCommitController`` — an EWMA over each window's seal-stage
-  cost per hash, one series per mode, with a Schmitt trigger between
-  them: flip device -> host when the device EWMA exceeds
+  cost per hash (a window whose dispatch COMPILED its program is counted
+  but not sampled: compile seconds say nothing about the steady state),
+  one series per mode, with a Schmitt trigger between them: flip
+  device -> host when the device EWMA exceeds
   ``adaptive_flip_ratio`` x the host estimate, flip back only below
   ``adaptive_flip_back_ratio`` x, and never flip before
   ``adaptive_dwell_windows`` windows have passed in the current mode
@@ -34,7 +36,9 @@ The controller also turns the ``seal.upload`` roofline verdict into a
 ``pipeline_depth`` recommendation: a bytes-bound upload overlaps with
 more windows in flight (raise depth toward ``adaptive_depth_max``,
 GPipe-style), a fixed-overhead upload does not (lower it and stop
-paying queue memory for overlap that cannot happen).
+paying queue memory for overlap that cannot happen). The verdict needs
+this device's own floors (costmodel.DEVICE_FLOORS, keyed by
+``device_kind``); a device without a row gets no hint.
 
 Every decision is exported as the ``khipu_adaptive_*`` registry family
 and a ``window.adapt`` flight-recorder event. Both commit paths
@@ -48,7 +52,12 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional
 
-from khipu_tpu.observability.costmodel import classify, subphase_floors
+from khipu_tpu import device
+from khipu_tpu.observability.costmodel import (
+    classify,
+    device_floors,
+    subphase_floors,
+)
 from khipu_tpu.observability.profiler import H2D, LEDGER
 from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.observability.trace import event
@@ -78,9 +87,8 @@ ADAPTIVE_GAUGES = REGISTRY.gauge_group("khipu_adaptive", {
     "flap_suppressed_total": 0,
 }, help="cost-model-adaptive commit controller (sync/adaptive.py)")
 
-# probe workload: ~0.5 MB gathered through ~2k rows — big enough that
-# a real tunnel/HBM difference dominates the clock, small enough to be
-# noise at startup
+# probe workload: ~0.5 MB gathered through ~2k rows — small enough to
+# be noise at startup
 _PROBE_ROWS = 2048
 _PROBE_COLS = 256
 _PROBE_REPS = 3
@@ -111,12 +119,11 @@ class ProbeResult:
         )
 
 
-def _measure_probe(margin: float) -> ProbeResult:
+def _measure_probe(platform: str, margin: float) -> ProbeResult:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    platform = jax.default_backend()
     rng = np.random.default_rng(0)  # KL003: seeded, replay-stable
     host = rng.integers(0, 256, size=(_PROBE_ROWS, _PROBE_COLS),
                         dtype=np.uint8)
@@ -155,22 +162,15 @@ def _measure_probe(margin: float) -> ProbeResult:
 
 def probe_backend(margin: float = 1.5) -> ProbeResult:
     """Measure (once per backend platform) whether d2d gathers beat the
-    host memcpy they would replace by ``margin``. A backend without a
-    working jax reports ``device_ok=False`` — the host path needs no
-    device."""
-    try:
-        import jax
-
-        platform = jax.default_backend()
-    except Exception:
-        return ProbeResult("none", 0.0, 0.0, False)
+    host memcpy they would replace by ``margin``. A backend that fails
+    to start, or a probe that fails to run, raises: the caller asked
+    for device commit, and "no device" is not an answer to hide behind
+    a host fallback."""
+    platform = device.platform()
     cached = _PROBE_CACHE.get(platform)
     if cached is not None:
         return cached
-    try:
-        result = _measure_probe(margin)
-    except Exception:
-        result = ProbeResult(platform, 0.0, 0.0, False)
+    result = _measure_probe(platform, margin)
     _PROBE_CACHE[platform] = result
     ADAPTIVE_GAUGES["probe_d2d_bytes_per_s"] = int(result.d2d_bytes_per_s)
     ADAPTIVE_GAUGES["probe_memcpy_bytes_per_s"] = int(
@@ -185,8 +185,8 @@ def exec_device_allowed(sync_cfg) -> bool:
     (``exec_device``) AND the one-shot backend probe must show real
     device memory — d2d beating host memcpy by the same margin the
     adaptive commit controller demands. Where device memory is host
-    RAM (CPU jax), shipping row tiles out just adds a tunnel tax to a
-    numpy pass, so the probe keeps the host path authoritative."""
+    RAM (CPU jax), shipping row tiles out just adds dispatch overhead
+    to a numpy pass, so the probe keeps the host path authoritative."""
     if not getattr(sync_cfg, "exec_device", False):
         return False
     if not getattr(sync_cfg, "adaptive_probe", True):
@@ -238,13 +238,16 @@ class AdaptiveCommitController:
         return "device" if self.device_mode else "host"
 
     def observe_window(self, mode: str, hashes: int,
-                       seal_seconds: float) -> None:
+                       seal_seconds: float,
+                       compiled: bool = False) -> None:
         """One window's seal-stage verdict: ``hashes`` nodes resolved in
         ``seal_seconds`` under ``mode``. Updates that mode's EWMA, then
-        re-runs the Schmitt trigger."""
+        re-runs the Schmitt trigger. ``compiled``: the dispatch built
+        its program inside ``seal_seconds`` — the window counts toward
+        the dwell but is not an EWMA sample."""
         self.windows += 1
         self._dwell += 1
-        if hashes > 0 and seal_seconds > 0:
+        if hashes > 0 and seal_seconds > 0 and not compiled:
             per_hash = seal_seconds / hashes
             prev = self._ewma.get(mode)
             alpha = self.cfg.adaptive_ewma_alpha
@@ -262,8 +265,11 @@ class AdaptiveCommitController:
         deeper pipelines; fixed-overhead ones do not."""
         if upload_seconds <= 0:
             return
+        floors = device_floors()
+        if floors is None:
+            return  # not calibrated for this device: no opinion
         verdict = classify(
-            upload_seconds, subphase_floors(upload_bytes, 0, 0)
+            upload_seconds, subphase_floors(floors, upload_bytes, 0, 0)
         )
         prev = self.depth_hint
         base = prev if prev is not None else self.cfg.pipeline_depth

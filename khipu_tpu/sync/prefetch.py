@@ -82,15 +82,12 @@ def _signing_preimage(stx, chain_id: Optional[int]) -> bytes:
 
 def _batch_hash_wanted(flag: bool) -> bool:
     """Device-batched signing hashes only pay where the device wins:
-    host keccak is native C, so CPU backends always hash scalar."""
+    host keccak is native C, so the CPU backend always hashes scalar."""
     if not flag:
         return False
-    try:
-        import jax
+    from khipu_tpu import device
 
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return device.platform() == "tpu"
 
 
 def recover_block_senders(

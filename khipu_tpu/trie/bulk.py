@@ -198,8 +198,7 @@ def _resolve_fused(levels, stats_out=None) -> Dict[bytes, bytes]:
     on."""
     import time as _time
 
-    import jax
-
+    from khipu_tpu import device
     from khipu_tpu.trie.deferred import (
         _PLACEHOLDER_PREFIX,
         _make_placeholder,
@@ -229,7 +228,7 @@ def _resolve_fused(levels, stats_out=None) -> Dict[bytes, bytes]:
     t0 = _time.perf_counter()
     job = fused_submit(
         to_resolve, {}, _PLACEHOLDER_PREFIX,
-        use_jnp=jax.default_backend() != "tpu",
+        use_jnp=device.platform() != "tpu",
         depth=len(levels),
     )
     t1 = _time.perf_counter()

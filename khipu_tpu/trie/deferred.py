@@ -306,14 +306,13 @@ def finalize(
     final_encoded: Dict[bytes, bytes] = {}  # real hash -> final rlp
     if fused and to_resolve:
         try:
-            import jax
-
+            from khipu_tpu import device
             from khipu_tpu.trie.fused import (
                 FusedUnsupported,
                 fused_resolve,
             )
 
-            jnp_path = jax.default_backend() != "tpu"
+            jnp_path = device.platform() != "tpu"
             resolved = fused_resolve(
                 to_resolve, deps, _PLACEHOLDER_PREFIX, use_jnp=jnp_path
             )

@@ -82,6 +82,34 @@ class TestProbeGate:
         assert p1.d2d_bytes_per_s >= 0 and p1.memcpy_bytes_per_s >= 0
 
 
+    def test_probe_exception_on_tpu_propagates(self, monkeypatch):
+        """On a chip a probe that cannot run is a fault to surface,
+        not a reason to commit on the host: nothing turns it into
+        ``device_ok=False``."""
+        monkeypatch.setattr(adaptive_mod.device, "platform", lambda: "tpu")
+        monkeypatch.delitem(adaptive_mod._PROBE_CACHE, "tpu", raising=False)
+
+        def boom(platform, margin):
+            raise RuntimeError("mosaic said no")
+
+        monkeypatch.setattr(adaptive_mod, "_measure_probe", boom)
+        with pytest.raises(RuntimeError, match="mosaic said no"):
+            probe_backend(margin=1.5)
+        assert "tpu" not in adaptive_mod._PROBE_CACHE  # nothing cached
+        with pytest.raises(RuntimeError):
+            AdaptiveCommitController(
+                _sync_cfg(adaptive_probe=True), device_cap=True
+            )
+
+    def test_backend_that_fails_to_start_propagates(self, monkeypatch):
+        def no_backend():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(adaptive_mod.device, "platform", no_backend)
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            probe_backend(margin=1.5)
+
+
 class TestSchmittTrigger:
     def _device_ctrl(self, **overrides):
         ctrl = AdaptiveCommitController(_sync_cfg(**overrides),
@@ -108,6 +136,29 @@ class TestSchmittTrigger:
         for _ in range(3 * dwell):
             ctrl.observe_window("host", 1000, 1000 * ctrl.host_floor_s)
         assert ctrl.mode() == "host" and ctrl.flips == 1
+
+    def test_compiling_window_is_not_an_observation(self):
+        """A first window whose dispatch compiled for 30 s (10 ms per
+        hash against a host floor of microseconds) followed by fast
+        ones must leave device mode on past the dwell — with the
+        compile in the EWMA the 0.6^n tail alone held the ratio above
+        ``adaptive_flip_ratio`` and flipped for good at the dwell."""
+        ctrl = self._device_ctrl()
+        dwell = ctrl.cfg.adaptive_dwell_windows
+        fast = 0.5 * ctrl.host_floor_s  # per-hash, ratio 0.5 < 2.0
+        ctrl.observe_window("device", 3000, 30.0, compiled=True)
+        assert ctrl._ewma["device"] is None  # counted, not sampled
+        assert ctrl.windows == 1
+        for _ in range(3 * dwell):
+            ctrl.observe_window("device", 3000, 3000 * fast)
+        assert ctrl.mode() == "device"
+        assert ctrl.flips == 0 and ctrl.flaps_suppressed == 0
+        # control: the same 30 s window AS a sample does flip
+        bad = self._device_ctrl()
+        bad.observe_window("device", 3000, 30.0)
+        for _ in range(dwell):
+            bad.observe_window("device", 3000, 3000 * fast)
+        assert bad.mode() == "host"
 
     def test_hysteresis_band_blocks_flap(self):
         """A ratio inside the band (flip_back_ratio < r < flip_ratio)
@@ -146,6 +197,17 @@ class TestSchmittTrigger:
 
 
 class TestDepthHint:
+    @pytest.fixture(autouse=True)
+    def _calibrated(self, monkeypatch):
+        # the hint needs this device's floors; the tests' CPU has no
+        # row, so hand it one (the verdict itself is doctored below)
+        from khipu_tpu.observability.costmodel import DeviceFloors
+
+        monkeypatch.setattr(
+            adaptive_mod, "device_floors",
+            lambda: DeviceFloors(1e-3, 1e9, 1e8),
+        )
+
     def _ctrl(self):
         return AdaptiveCommitController(_sync_cfg(), device_cap=False)
 
@@ -177,6 +239,14 @@ class TestDepthHint:
     def test_zero_duration_upload_is_ignored(self):
         ctrl = self._ctrl()
         ctrl.note_upload(1 << 20, 0.0)
+        assert ctrl.depth_hint is None
+
+    def test_uncalibrated_device_gets_no_hint(self, monkeypatch):
+        """A device without a floors row is never classified against
+        another device's numbers: no verdict, no depth hint."""
+        monkeypatch.setattr(adaptive_mod, "device_floors", lambda: None)
+        ctrl = self._ctrl()
+        ctrl.note_upload(1 << 20, 0.5)
         assert ctrl.depth_hint is None
 
 

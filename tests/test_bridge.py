@@ -77,6 +77,41 @@ class TestBridge:
         client.execute_blocks(blocks[2:])
         assert client.best_block()[0] == 4
 
+    def test_one_driver_serves_every_batch(self):
+        """Device state outlives a batch: the server builds ONE
+        ReplayDriver on the first ExecuteBlocks and keeps it, so the
+        device mirror is allocated once and the adaptive controller
+        keeps one history (a controller per call restarted its dwell
+        and flip count every few windows)."""
+        import dataclasses
+
+        from khipu_tpu.config import SyncConfig
+        from khipu_tpu.sync.adaptive import ADAPTIVE_GAUGES
+
+        cfg = dataclasses.replace(
+            CFG, sync=SyncConfig(commit_window_blocks=2)
+        )
+        bc = Blockchain(Storages(), cfg)
+        bc.load_genesis(GenesisSpec(alloc=ALLOC))
+        server = BridgeServer(bc, cfg, device_commit=True)
+        client = BridgeClient(f"127.0.0.1:{server.start()}")
+        try:
+            blocks = build_blocks(8)
+            assert server._driver is None  # nothing built until asked
+            client.execute_blocks(blocks[:4])
+            driver = server._driver
+            ctrl, mirror = driver._adaptive, driver._mirror
+            assert ctrl is not None and ctrl.windows == 2
+            client.execute_blocks(blocks[4:])
+            assert server._driver is driver
+            assert driver._adaptive is ctrl and driver._mirror is mirror
+            assert ctrl.windows == 4  # one history across both batches
+            assert ADAPTIVE_GAUGES["windows_observed"] == 4
+            assert client.best_block()[0] == 8
+        finally:
+            client.close()
+            server.stop()
+
     def test_invalid_block_aborts(self, bridge):
         import dataclasses
 

@@ -7,19 +7,26 @@ import types
 
 import pytest
 
-from khipu_tpu.observability import recorder
+from khipu_tpu.observability import costmodel, recorder
 from khipu_tpu.observability.costmodel import (
-    DISPATCH_FLOOR_S,
     FIXED_OVERHEAD_FACTOR,
-    KERNEL_HASHES_PER_S,
-    TUNNEL_BYTES_PER_S,
+    NOT_CALIBRATED,
+    DeviceFloors,
     classify,
     cost_tracks,
+    device_floors,
     subphase_floors,
     window_costs,
 )
 from khipu_tpu.observability.profiler import D2H, H2D, HOST, LEDGER
 from khipu_tpu.observability.trace import Tracer
+
+# the tests' OWN floors (round numbers, no device's): 100 ms per
+# materialised fetch, 20 MB/s upload, 80 M hashes/s
+FLOORS = DeviceFloors(
+    fetch_round_trip_s=0.1, h2d_bytes_per_s=20e6,
+    kernel_hashes_per_s=80e6,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -30,6 +37,13 @@ def _clean_ledger():
     LEDGER.reset()
 
 
+@pytest.fixture
+def calibrated(monkeypatch):
+    """Run as a device whose floors are FLOORS."""
+    monkeypatch.setattr(costmodel, "_device_kind", lambda: "test-device")
+    monkeypatch.setitem(costmodel.DEVICE_FLOORS, "test-device", FLOORS)
+
+
 def _span(name, duration, **tags):
     """A snapshot-shaped span: window_costs only reads name, duration,
     and tags."""
@@ -38,19 +52,30 @@ def _span(name, duration, **tags):
 
 class TestFloors:
     def test_no_observed_quantity_no_floor(self):
-        assert subphase_floors(0, 0, 0) == {}
+        assert subphase_floors(FLOORS, 0, 0, 0) == {}
 
     def test_each_quantity_drives_its_floor(self):
-        floors = subphase_floors(22_000_000, 2, 79_000_000)
+        floors = subphase_floors(FLOORS, 20_000_000, 2, 80_000_000)
         assert floors["bytes_s"] == pytest.approx(1.0)
-        assert floors["dispatch_s"] == pytest.approx(
-            2 * DISPATCH_FLOOR_S
-        )
+        assert floors["dispatch_s"] == pytest.approx(0.2)
         assert floors["compute_s"] == pytest.approx(1.0)
 
     def test_partial_quantities_partial_floors(self):
-        floors = subphase_floors(4096, 0, 0)
+        floors = subphase_floors(FLOORS, 4096, 0, 0)
         assert set(floors) == {"bytes_s"}
+
+    def test_v5e_row_is_the_one_measured_row(self):
+        assert set(costmodel.DEVICE_FLOORS) == {"TPU v5 lite"}
+        row = device_floors("TPU v5 lite")
+        assert all(v > 0 for v in row)
+        # an attached chip: millisecond fetches, GB/s uploads
+        assert row.fetch_round_trip_s < 0.01
+        assert row.h2d_bytes_per_s > 1e9
+
+    def test_unknown_device_kind_has_no_row(self):
+        assert device_floors("no such accelerator") is None
+        # the CPU the tests run on is not a calibrated device either
+        assert device_floors() is None
 
 
 class TestClassify:
@@ -76,6 +101,11 @@ class TestClassify:
         assert v["attainable_s"] == 0.0
         assert v["efficiency"] == 0.0
 
+    def test_uncalibrated_device_gets_no_verdict(self):
+        v = classify(0.5, None)
+        assert v["bound"] == NOT_CALIBRATED
+        assert v["floors"] == {} and v["attainable_s"] == 0.0
+
     def test_efficiency_caps_at_one(self):
         # achieved FASTER than the floor (calibration drift) reads as
         # fully efficient, never >1
@@ -89,7 +119,7 @@ def _synthetic_window():
     LEDGER.enable()
     LEDGER.note_window(1, 0, 7)
     with LEDGER.context(window=1, phase="seal"):
-        LEDGER.record("seal.upload", H2D, 2_200_000, duration=0.02)
+        LEDGER.record("seal.upload", H2D, 2_000_000, duration=0.02)
         LEDGER.record("seal.pack", HOST, 4096, duration=0.01)
     with LEDGER.context(window=1, phase="collect"):
         # the collect-thread rootcheck keeps phase="collect"; its SITE
@@ -106,17 +136,17 @@ class TestWindowCosts:
             "ledgerEnabled": LEDGER.enabled,
         }
 
-    def test_join_and_verdicts(self):
+    def test_join_and_verdicts(self, calibrated):
         _synthetic_window()
         spans = [
-            # 2.2 MB / 22 MB/s = 0.1 s floor; 0.15 s achieved -> within
+            # 2 MB / 20 MB/s = 0.1 s floor; 0.15 s achieved -> within
             # the overhead factor, bytes-bound
             _span("seal.upload", 0.15),
-            # 2 d2h crossings * 91 ms = 0.182 s floor; 0.2 s achieved
+            # 2 d2h crossings * 100 ms = 0.2 s floor; 0.2 s achieved
             _span("seal.rootcheck", 0.20),
-            # 790k hashes / 79 M/s = 10 ms floor; 0.5 s achieved is
+            # 800k hashes / 80 M/s = 10 ms floor; 0.5 s achieved is
             # >3x over it -> fixed-overhead (host-side work)
-            _span("seal.pack", 0.5, nodes=790_000),
+            _span("seal.pack", 0.5, nodes=800_000),
         ]
         out = window_costs(3, spans=spans)
         assert out["found"]
@@ -124,7 +154,7 @@ class TestWindowCosts:
         rows = out["subphases"]
         up = rows["seal.upload"]
         assert up["bound"] == "bytes-bound"
-        assert up["device_bytes"] == 2_200_000
+        assert up["device_bytes"] == 2_000_000
         assert up["d2h_crossings"] == 0  # h2d enqueues pay no RTT
         assert up["floors"]["bytes_s"] == pytest.approx(0.1)
         assert up["efficiency"] == pytest.approx(0.6667, abs=1e-3)
@@ -134,10 +164,25 @@ class TestWindowCosts:
         pk = rows["seal.pack"]
         assert pk["bound"] == "fixed-overhead"
         assert pk["device_bytes"] == 0  # HOST bytes never cross
-        assert pk["hashes"] == 790_000
+        assert pk["hashes"] == 800_000
         # headline: the costliest sub-phase names the verdict
         assert out["verdict"]["subphase"] == "seal.pack"
         assert out["verdict"]["bound"] == "fixed-overhead"
+        assert out["device_kind"] == "test-device"
+        assert out["floors"] == FLOORS._asdict()
+
+    def test_uncalibrated_device_reports_not_calibrated(self):
+        """No row for this device_kind (the tests' CPU): every
+        sub-phase still reports what it achieved and moved, but its
+        bound is 'not calibrated' — never another device's verdict."""
+        _synthetic_window()
+        out = window_costs(3, spans=[_span("seal.upload", 0.15)])
+        assert out["floors"] is None
+        for row in out["subphases"].values():
+            assert row["bound"] == NOT_CALIBRATED
+            assert row["floors"] == {}
+        assert out["subphases"]["seal.upload"]["device_bytes"] == 2_000_000
+        assert out["verdict"]["bound"] == NOT_CALIBRATED
 
     def test_ledger_seconds_are_the_span_fallback(self):
         """No spans at all (tracer off while the ledger ran): achieved
@@ -152,7 +197,7 @@ class TestWindowCosts:
             pytest.approx(0.10)
         )
 
-    def test_cost_tracks_emit_one_counter_per_window(self):
+    def test_cost_tracks_emit_one_counter_per_window(self, calibrated):
         _synthetic_window()
         t = Tracer()
         events = cost_tracks(tracer_=t)

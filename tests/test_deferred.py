@@ -232,6 +232,42 @@ class TestFusedFinalize:
         for h, enc in fused_up.items():
             assert keccak256(enc) == h
 
+    def test_ext_tile_shapes_are_bucketed(self):
+        """The resolved-input tile is gathered with row lists padded
+        to multiples of EXT_FLOOR and its total to a pow-2: windows
+        whose cross-ref counts differ (1298 vs 1303 on the chip) share
+        their gather programs and ONE fused ext bucket, instead of
+        compiling a handful of small programs per window."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from khipu_tpu.trie.fused import EXT_FLOOR, gather_ext_tile
+
+        rng = np.random.default_rng(4)
+        t1 = jnp.asarray(rng.integers(0, 256, (512, 32), dtype=np.uint8))
+        t2 = jnp.asarray(rng.integers(0, 256, (256, 32), dtype=np.uint8))
+        totals = {}
+        for n1, n2 in ((3, 0), (60, 0), (64, 0), (65, 0), (100, 5),
+                       (128, 64), (70, 70), (120, 10), (125, 3)):
+            r1 = rng.integers(0, 512, n1).astype(np.int32)
+            r2 = rng.integers(0, 256, n2).astype(np.int32)
+            sources = [(t1, r1)] + ([(t2, r2)] if n2 else [])
+            tile, offsets = gather_ext_tile(sources)
+            n = tile.shape[0]
+            totals[(n1, n2)] = n
+            assert n >= EXT_FLOOR and n & (n - 1) == 0  # pow-2 bucket
+            assert all(o % EXT_FLOOR == 0 for o in offsets)
+            got = np.asarray(tile)
+            np.testing.assert_array_equal(
+                got[offsets[0] : offsets[0] + n1], np.asarray(t1)[r1])
+            if n2:
+                np.testing.assert_array_equal(
+                    got[offsets[1] : offsets[1] + n2], np.asarray(t2)[r2])
+        assert set(totals.values()) == {64, 128, 256}
+        # a small second part does not double the bucket
+        assert totals[(100, 5)] == totals[(120, 10)] == totals[(125, 3)] == 256
+        assert totals[(65, 0)] == 128
+
     def test_fused_windowed_replay_equals_host(self):
         """End to end: windowed replay with the fused committer produces
         the same chain as the eager per-block host path."""

@@ -110,3 +110,76 @@ class TestRlpEncodeResizeGuard:
                 ext.encode([[inner], [b"x", [inner]]])
         finally:
             ext._set_encode_hook(None)
+
+
+# --------------------------------------------------- build stamps
+
+
+class TestBuildStamp:
+    """native/build.py keys a binary on (sources, compile command, host
+    CPU flags) in its file name: the checkout is copied between
+    machines with ``-march=native`` binaries in it, and "newer than the
+    source" says nothing about where a binary was built."""
+
+    @pytest.fixture
+    def fresh_build(self, tmp_path, monkeypatch):
+        """native/build.py pointed at a private output directory (the
+        real sources, no loaded state)."""
+        from khipu_tpu.native import build
+
+        monkeypatch.setattr(build, "_DIR", str(tmp_path))
+        monkeypatch.setattr(build, "_lib", None)
+        monkeypatch.setattr(build, "_failed", False)
+        monkeypatch.setattr(build, "_ext_mod", None)
+        monkeypatch.setattr(build, "_ext_failed", False)
+        return build
+
+    def test_foreign_stamp_is_rebuilt_not_loaded(self, fresh_build):
+        build = fresh_build
+        # a binary "from another machine": right stem, foreign stamp,
+        # and not even a valid ELF — loading it would fail loudly
+        foreign = os.path.join(
+            build._DIR, "_khipu_rlp_ext.0123456789abcdef.so"
+        )
+        with open(foreign, "wb") as f:
+            f.write(b"built elsewhere")
+        assert not build.rlp_ext_is_fresh()
+        ext = build.load_rlp_ext()
+        assert ext is not None and ext.__file__ == build.rlp_ext_path()
+        assert ext.encode([b"cat", b"dog"]) == b"\xc8\x83cat\x83dog"
+        assert build.rlp_ext_is_fresh()
+        assert not os.path.exists(foreign)  # the stale binary is gone
+
+    def test_stamp_moves_with_cpu_flags_command_and_sources(
+            self, fresh_build, monkeypatch, tmp_path):
+        build = fresh_build
+        here = build.lib_path()
+        # another host's CPU
+        monkeypatch.setattr(build, "_cpu_flags", lambda: "fpu vme avx9000")
+        other_cpu = build.lib_path()
+        assert other_cpu != here
+        monkeypatch.undo()
+        monkeypatch.setattr(build, "_DIR", str(tmp_path))
+        # another compile command
+        monkeypatch.setattr(
+            build, "_lib_cmd", lambda: ["g++", "-O0", "-shared", "-fPIC"]
+        )
+        assert build.lib_path() not in (here, other_cpu)
+        monkeypatch.undo()
+        monkeypatch.setattr(build, "_DIR", str(tmp_path))
+        # edited sources
+        src = tmp_path / "edited.cc"
+        src.write_text("// changed\n")
+        monkeypatch.setattr(build, "_sources", lambda: [str(src)])
+        assert build.lib_path() not in (here, other_cpu)
+
+    def test_toolchain_failure_warns_instead_of_hiding(
+            self, fresh_build, monkeypatch, capsys):
+        build = fresh_build
+        monkeypatch.setattr(
+            build, "_ext_cmd", lambda: ["gcc-that-does-not-exist"]
+        )
+        assert build.load_rlp_ext() is None  # the pure-Python codec serves
+        err = capsys.readouterr().err
+        assert "native RLP extension unavailable" in err
+        assert "pure Python" in err

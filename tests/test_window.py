@@ -481,8 +481,8 @@ class TestDeviceMirrorCommit:
         self, chain
     ):
         """THE tentpole contract: with the mirror as commit target the
-        collect phase hauls only the per-block root digests over the
-        tunnel (32 B x blocks) — the bulk mapping fetch moved to the
+        collect phase fetches only the per-block root digests off the
+        device (32 B x blocks) — the bulk mapping fetch moved to the
         async persist stage — and the persisted chain is bit-exact."""
         from khipu_tpu.observability.profiler import D2H, LEDGER
 
