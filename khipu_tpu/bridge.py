@@ -49,6 +49,7 @@ from __future__ import annotations
 import json
 import threading
 import time
+from collections import deque
 from concurrent import futures
 from typing import List, Optional
 
@@ -68,6 +69,8 @@ from khipu_tpu.observability.trace import (
 )
 
 SERVICE = "khipu.Bridge"
+
+REPLAY_STATS_KEPT = 256  # ExecuteBlocks batches whose ReplayStats stay
 
 # gRPC metadata keys the client attaches on EVERY call (values are the
 # caller's tracer identity; the keys ship unconditionally so the wire
@@ -149,6 +152,11 @@ class BridgeServer:
         # call re-allocated the mirror in HBM and restarted the
         # controller's history every few windows
         self._driver = None
+        # the ReplayStats of the last ExecuteBlocks batches, newest
+        # last: the per-phase split of what this server just did, for
+        # whoever embeds it (replay_stats()) and, through GetMetrics,
+        # for whoever asks why one batch took long (_replay_samples)
+        self._replay_stats: deque = deque(maxlen=REPLAY_STATS_KEPT)
         self._server: Optional[grpc.Server] = None
         # the SHARD's own span ring (per-instance: two in-process
         # servers — the 2-shard tests — must not interleave rings),
@@ -163,6 +171,9 @@ class BridgeServer:
             from khipu_tpu.observability.registry import REGISTRY
             registry = REGISTRY
         self.registry = registry
+        # the newest server on a registry owns the slot, as its driver
+        # owns the pipeline gauges
+        registry.register_collector("bridge_replay", self._replay_samples)
 
     # ------------------------------------------------------------ methods
 
@@ -185,7 +196,7 @@ class BridgeServer:
                 )
             try:
                 # khipu-lint: ok KL004 the lock IS the serial-apply rule: batches execute one at a time
-                self._driver.replay(blocks)
+                self._replay_stats.append(self._driver.replay(blocks))
             except Exception as e:
                 context.abort(
                     grpc.StatusCode.FAILED_PRECONDITION,
@@ -196,6 +207,30 @@ class BridgeServer:
             for b in blocks
         ]
         return rlp_encode(out)
+
+    def replay_stats(self) -> list:
+        """``ReplayStats`` of the most recent ExecuteBlocks batches
+        (at most ``REPLAY_STATS_KEPT``), oldest first."""
+        return list(self._replay_stats)
+
+    def _replay_samples(self) -> list:
+        """``khipu_bridge_replay_*``: the kept batches' count, the last
+        batch's seconds, and the SLOWEST kept batch with its phase
+        split — an outlier batch names its phase after the fact."""
+        kept = self.replay_stats()
+        out = [("khipu_bridge_replay_batches_kept", "gauge", {},
+                len(kept))]
+        if kept:
+            slow = max(kept, key=lambda s: s.seconds)
+            out += [
+                ("khipu_bridge_replay_batch_seconds", "gauge",
+                 {"which": "last"}, kept[-1].seconds),
+                ("khipu_bridge_replay_batch_seconds", "gauge",
+                 {"which": "slowest"}, slow.seconds),
+            ]
+            out += [("khipu_bridge_replay_slowest_phase_seconds", "gauge",
+                     {"phase": p}, v) for p, v in slow.phases.items()]
+        return out
 
     def _best_block(self, request: bytes, context) -> bytes:
         n = self.blockchain.best_block_number

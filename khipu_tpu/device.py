@@ -5,6 +5,10 @@
   else, or a backend that fails to initialise, raises. No caller wraps
   this in ``except -> False``: a chip that cannot start must stop the
   program, not quietly move the hashing to the host.
+* ``named_jit()`` — what a jitted program is called on the device. A
+  profiler trace tells programs apart by ``jit_<function name>`` and
+  nothing else, so every program on a served path has a name of its
+  own (docs/observability.md, "Names on the device").
 * ``place_compile_cache()`` — where XLA's persistent compilation cache
   lives. One fused window signature costs ~30 s cold on a v5e, so every
   entry point that will touch JAX (``python -m khipu_tpu``,
@@ -39,6 +43,16 @@ def platform() -> str:
             f"{' or '.join(PLATFORMS)}"
         )
     return name
+
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` whose XLA module is ``jit_<name>``. Where the
+    program is inlined into a caller's, ``jit(<name>)`` is a component
+    of its instructions' ``op_name`` instead."""
+    import jax
+
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 def place_compile_cache() -> str:

@@ -32,6 +32,13 @@ LANES_PER_BLOCK = RATE // 8  # 17 u64 lanes absorbed per block
 _RC32 = tuple((rc & 0xFFFFFFFF, rc >> 32) for rc in ROUND_CONSTANTS)
 
 
+def class_tag(nblocks: int, message_words: int = None) -> str:
+    """How a size class reads in the names of the device programs that
+    serve it: ``nb<rate blocks>``, plus ``w<message words>`` where the
+    rows are stored unpadded and the kernel fuses the pad."""
+    return f"nb{nblocks}" + (f"w{message_words}" if message_words else "")
+
+
 def _rotl64(lo, hi, n: int):
     """Rotate-left a u64 expressed as (lo, hi) u32 halves by static n."""
     n &= 63

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from khipu_tpu.device import named_jit
 from khipu_tpu.native.keccak import keccak256_batch as _host_keccak_batch
 from khipu_tpu.observability.profiler import D2H, H2D, LEDGER
 from khipu_tpu.ops.keccak_jnp import (
@@ -32,6 +33,7 @@ from khipu_tpu.ops.keccak_jnp import (
     _round,
     LANES_PER_BLOCK,
     RATE,
+    class_tag,
     pad_batch_count,
     pad_to_words,
 )
@@ -112,8 +114,8 @@ def _build_cached(nblocks: int, interpret: bool, nwords_in):
         if nwords_in is not None
         else nblocks * 2 * LANES_PER_BLOCK
     )
+    tag = class_tag(nblocks, nwords_in)
 
-    @jax.jit
     def run(blocks):  # uint32[tiles, nwords, 8, 128]
         tiles = blocks.shape[0]
         return pl.pallas_call(
@@ -125,9 +127,13 @@ def _build_cached(nblocks: int, interpret: bool, nwords_in):
             out_specs=pl.BlockSpec((1, 8, 8, 128), lambda i: (i, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((tiles, 8, 8, 128), jnp.uint32),
             interpret=interpret,
+            # names the Mosaic custom-call instruction (else it takes
+            # the innermost jitted wrapper's name). Ends in a word:
+            # trace readers strip a trailing ".<n>" and digits
+            name=f"keccak_{tag}_sponge",
         )(blocks)
 
-    return run
+    return named_jit(f"keccak_tiles_{tag}", run)
 
 
 @functools.lru_cache(maxsize=32)
@@ -142,7 +148,6 @@ def _build_from_bytes(nblocks: int, interpret: bool):
     nwords = nblocks * 2 * LANES_PER_BLOCK
     run = _build(nblocks, interpret)
 
-    @jax.jit
     def go(padded_u8):  # uint8[N, nblocks*RATE], N % TILE == 0
         n = padded_u8.shape[0]
         tiles = n // TILE
@@ -155,7 +160,7 @@ def _build_from_bytes(nblocks: int, interpret: bool):
         d = out.transpose(0, 2, 3, 1).reshape(n, 8)
         return jax.lax.bitcast_convert_type(d, jnp.uint8).reshape(n, 32)
 
-    return go
+    return named_jit(f"keccak_from_bytes_nb{nblocks}", go)
 
 
 def _words_runner(nblocks: int, interpret: bool, nwords_in: int = None):
@@ -176,7 +181,6 @@ def _words_runner(nblocks: int, interpret: bool, nwords_in: int = None):
     )
     run = _build(nblocks, interpret, nwords_in=nwords_in)
 
-    @jax.jit
     def go(words):  # uint32[N, nwords], N % TILE == 0
         n = words.shape[0]
         tiles = n // TILE
@@ -184,7 +188,8 @@ def _words_runner(nblocks: int, interpret: bool, nwords_in: int = None):
         out = run(tiled)  # (tiles, 8, 8, 128)
         return out.transpose(0, 2, 3, 1).reshape(n, 8)  # digest words
 
-    return go
+    return named_jit(
+        f"keccak_from_words_{class_tag(nblocks, nwords_in)}", go)
 
 
 @functools.lru_cache(maxsize=32)
@@ -220,7 +225,6 @@ def _build_device_fixed(length: int, interpret: bool):
     if length % 4 == 0:
         run_words = _build_device_fixed_words(length, interpret)
 
-        @jax.jit
         def go(data_u8):  # uint8[N, length], N % TILE == 0
             n = data_u8.shape[0]
             words = jax.lax.bitcast_convert_type(
@@ -231,11 +235,10 @@ def _build_device_fixed(length: int, interpret: bool):
                 n, 32
             )
 
-        return go
+        return named_jit(f"keccak_fixed_len{length}", go)
 
     run_bytes = _build_from_bytes(nblocks, interpret)
 
-    @jax.jit
     def go(data_u8):  # uint8[N, length], N % TILE == 0
         n = data_u8.shape[0]
         tail = np.zeros(nblocks * RATE - length, dtype=np.uint8)
@@ -244,7 +247,7 @@ def _build_device_fixed(length: int, interpret: bool):
         pad = jnp.broadcast_to(jnp.asarray(tail), (n, tail.shape[0]))
         return run_bytes(jnp.concatenate([data_u8, pad], axis=1))
 
-    return go
+    return named_jit(f"keccak_fixed_len{length}", go)
 
 
 def keccak256_fixed(

@@ -42,7 +42,7 @@ import numpy as np
 from khipu_tpu.observability.profiler import D2H, H2D, LEDGER
 from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.observability.trace import span as _span
-from khipu_tpu.ops.keccak_jnp import RATE
+from khipu_tpu.ops.keccak_jnp import RATE, class_tag
 
 TILE = 8 * 128  # messages per kernel tile (keccak_pallas.TILE)
 
@@ -57,6 +57,18 @@ MIRROR_GAUGES = REGISTRY.gauge_group("khipu_mirror", {
     # whole resident tiles fetched by the bulk spill read-back
     "spilled_tiles": 0,
 }, help="device-mirror spill watermark state (storage/device_mirror.py)")
+
+def _count_hashed(nblocks: int, rows: int) -> None:
+    """Rows the mirror sent through the Keccak kernel, by rate-block
+    class: a class's filler tile, each partial tile's self-claim hash
+    at install, and every row of the class (filler included) per
+    verify. The registry hands back the same counter per class."""
+    REGISTRY.counter(
+        "khipu_mirror_hashed_rows_total",
+        help="rows hashed on the device by the mirror "
+             "(storage/device_mirror.py)",
+        labels={"nblocks": str(nblocks)},
+    ).inc(rows)
 
 
 def _pack_word_major(padded_rows: np.ndarray) -> np.ndarray:
@@ -86,12 +98,15 @@ def _class_kernels(nblocks: int, exact_len: Optional[int],
     instead of paying a fresh multi-second compile per class."""
     import jax
     import jax.numpy as jnp
-    from functools import partial
 
     from khipu_tpu import device
 
     width = exact_len if exact_len else nblocks * RATE
     nwords = width // 4
+    # the device's name for each program of this class: a trace tells
+    # programs apart by nothing else. `verify` is in the verify
+    # program's name and in no other (trace readers find it by that)
+    tag = class_tag(nblocks, nwords if exact_len else None)
 
     if device.platform() == "tpu":
         from khipu_tpu.ops.keccak_pallas import _build
@@ -108,7 +123,6 @@ def _class_kernels(nblocks: int, exact_len: Optional[int],
 
         full = nblocks * RATE
 
-        @jax.jit
         def _run_jnp(planes):  # u32[t, nwords, 8, 128]
             t = planes.shape[0]
             words = planes.transpose(0, 2, 3, 1).reshape(
@@ -130,11 +144,10 @@ def _class_kernels(nblocks: int, exact_len: Optional[int],
             )
             return dw.reshape(t, 8, 128, 8).transpose(0, 3, 1, 2)
 
-        run = _run_jnp
+        run = device.named_jit(f"mirror_hash_tiles_{tag}", _run_jnp)
 
     # donated: the admit path updates the resident buffers in place
     # instead of copying the whole mirror per tile
-    @partial(jax.jit, donate_argnums=(0, 1))
     def set_tile(resident, claimed, tile_idx, planes, digs):
         resident = jax.lax.dynamic_update_slice(
             resident, planes[None], (tile_idx, 0, 0, 0)
@@ -148,7 +161,6 @@ def _class_kernels(nblocks: int, exact_len: Optional[int],
     # on device (row-major u8, e.g. gathered from a FusedJob's output);
     # the word-major retile runs here instead of on the host, so the
     # window-commit admit path uploads ZERO node bytes
-    @partial(jax.jit, donate_argnums=(0, 1))
     def admit_device(resident, claimed, tile_idx, enc_u8, claim_u8):
         words = jax.lax.bitcast_convert_type(
             enc_u8.reshape(TILE, nwords, 4), jnp.uint32
@@ -166,13 +178,19 @@ def _class_kernels(nblocks: int, exact_len: Optional[int],
         )
         return resident, claimed
 
-    @jax.jit
     def verify(resident, claimed):
         digs = run(resident)
         bad = jnp.any(digs != claimed, axis=1)  # (tiles, 8, 128)
         return jnp.sum(bad.astype(jnp.int32))
 
-    return run, set_tile, admit_device, verify
+    return (
+        run,
+        device.named_jit(f"mirror_set_tile_{tag}", set_tile,
+                         donate_argnums=(0, 1)),
+        device.named_jit(f"mirror_admit_device_{tag}", admit_device,
+                         donate_argnums=(0, 1)),
+        device.named_jit(f"mirror_verify_{tag}", verify),
+    )
 
 
 def _filler_row_u8_for(width: int, exact_len: Optional[int]) -> np.ndarray:
@@ -203,6 +221,7 @@ def _filler_for(nblocks: int, exact_len: Optional[int],
         LEDGER.record("mirror.init", H2D, planes.nbytes)
         with LEDGER.transfer("mirror.init", D2H, TILE * 32):
             d = np.asarray(jax.device_get(run(planes)))  # (1, 8, 8, 128)
+    _count_hashed(nblocks, TILE)
     return (
         planes[0, :, 0, 0].copy().tobytes(),
         d[0, :, 0, 0].copy().tobytes(),
@@ -318,6 +337,7 @@ class _ClassMirror:
                 digs = np.asarray(
                     jax.device_get(self._run(planes))
                 )  # (1, 8, 8, 128)
+            _count_hashed(self.nblocks, TILE)
             claim_rows = (
                 digs[0].transpose(1, 2, 0).reshape(TILE, 8).copy()
             )  # row-major [row, word]
@@ -513,14 +533,17 @@ class _ClassMirror:
 
         # lock held across the dispatch: a concurrent donated install
         # would delete the very buffers we are hashing
-        with self._lock:
+        with self._lock, _span("mirror.verify_class", nblocks=self.nblocks,
+                               rows=self.count, tiles=self.tiles):
             with LEDGER.transfer("mirror.verify", D2H, 4):
-                return int(
+                bad = int(
                     # khipu-lint: ok KL004 hash must read under the install lock
                     jax.device_get(
                         self._verify(self.resident, self.claimed)
                     )
                 )
+        _count_hashed(self.nblocks, self.capacity)
+        return bad
 
 
 class DeviceNodeMirror:
@@ -552,20 +575,22 @@ class DeviceNodeMirror:
     def admit(self, items: Mapping[bytes, bytes]) -> None:
         """Stage nodes (hash -> encoding); full 1024-row tiles upload
         immediately, the remainder stays staged until flush()."""
-        for h, enc in items.items():
-            nb = len(enc) // RATE + 1
-            self._pending.setdefault(nb, []).append((h, enc))
-        for nb, pend in self._pending.items():
-            while len(pend) >= TILE:
-                self._install(nb, pend[:TILE])
-                del pend[:TILE]
+        with _span("mirror.admit", rows=len(items)):
+            for h, enc in items.items():
+                nb = len(enc) // RATE + 1
+                self._pending.setdefault(nb, []).append((h, enc))
+            for nb, pend in self._pending.items():
+                while len(pend) >= TILE:
+                    self._install(nb, pend[:TILE])
+                    del pend[:TILE]
 
     def flush(self) -> None:
         """Upload partial tiles (padded out with synthetic rows)."""
-        for nb, pend in self._pending.items():
-            if pend:
-                self._install(nb, pend)
-                pend.clear()
+        with _span("mirror.flush"):
+            for nb, pend in self._pending.items():
+                if pend:
+                    self._install(nb, pend)
+                    pend.clear()
 
     def admit_packed(self, hashes: List[bytes], rows: np.ndarray,
                      lengths: Optional[List[int]] = None,
@@ -710,6 +735,7 @@ class DeviceNodeMirror:
         """Re-hash EVERY resident node on device and count content-
         address mismatches — one dispatch per size class, zero layout
         work (the tiles already live in kernel layout)."""
-        return sum(cm.verify() for cm in list(self._classes.values()))
+        with _span("mirror.verify", classes=len(self._classes)):
+            return sum(cm.verify() for cm in list(self._classes.values()))
 
 

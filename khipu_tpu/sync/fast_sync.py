@@ -17,6 +17,7 @@ per-node JVM kec256 at KesqueNodeDataSource.scala:61-63.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Set, Tuple
 
@@ -27,6 +28,7 @@ from khipu_tpu.domain.account import (
     EMPTY_STORAGE_ROOT,
     Account,
 )
+from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.observability.trace import span
 
 # Typed node hashes (sync/package.scala:21-42).
@@ -71,8 +73,11 @@ class FastSyncStateStorage:
     def __init__(self, source):
         self.source = source
 
-    def put_sync_state(self, state: SyncState) -> None:
-        self.source.put(self.KEY, state.encode())
+    def put_sync_state(self, state: SyncState) -> int:
+        """Writes the checkpoint; returns its encoded size in bytes."""
+        raw = state.encode()
+        self.source.put(self.KEY, raw)
+        return len(raw)
 
     def get_sync_state(self) -> Optional[SyncState]:
         raw = self.source.get(self.KEY)
@@ -126,6 +131,85 @@ def _children_of(kind: int, encoded: bytes) -> List[Tuple[int, bytes]]:
     return out
 
 
+# the phases that tile ``StateSyncer.start``'s wall clock, in loop order
+SYNC_PHASES = ("queue", "fetch", "check", "parse", "store", "admit",
+               "checkpoint", "flush", "verify")
+NODE_KINDS = ("state", "storage", "code")  # the three stores
+
+
+@dataclass
+class SyncStats:
+    """What ``StateSyncer.start`` did and where its time went — kept
+    always, like ``ReplayStats.phases`` on the replay path, and served
+    as the ``khipu_fastsync_*`` families by whichever syncer is newest.
+
+    ``phases`` (seconds) tile ``loop_seconds``: ``queue`` takes the
+    batch off ``pending`` and re-queues what was missing or corrupt,
+    ``check`` matches the answers to the request and content-address
+    checks them, ``parse`` reads the children out of each node,
+    ``checkpoint`` is the resume read and every ``put_sync_state``,
+    ``flush`` and ``verify`` are the closing device-mirror pass."""
+
+    phases: Dict[str, float] = field(
+        default_factory=lambda: dict.fromkeys(SYNC_PHASES, 0.0))
+    nodes: Dict[str, int] = field(  # stored, per kind
+        default_factory=lambda: dict.fromkeys(NODE_KINDS, 0))
+    batches: int = 0
+    requested: int = 0
+    retried: int = 0  # missing or corrupt, re-queued
+    rejected: int = 0  # failed the content-address check
+    checkpoints: int = 0
+    checkpoint_bytes: int = 0
+    pending: int = 0  # queue length after the last batch
+    pending_max: int = 0
+    loop_seconds: float = 0.0
+
+    def samples(self) -> list:
+        out = [("khipu_fastsync_phase_seconds_total", "counter",
+                {"phase": p}, v) for p, v in self.phases.items()]
+        out += [("khipu_fastsync_nodes_total", "counter", {"kind": k}, n)
+                for k, n in self.nodes.items()]
+        out += [
+            ("khipu_fastsync_batches_total", "counter", {}, self.batches),
+            ("khipu_fastsync_requested_total", "counter", {},
+             self.requested),
+            ("khipu_fastsync_retried_total", "counter", {}, self.retried),
+            ("khipu_fastsync_rejected_total", "counter", {},
+             self.rejected),
+            ("khipu_fastsync_checkpoints_total", "counter", {},
+             self.checkpoints),
+            ("khipu_fastsync_checkpoint_bytes_total", "counter", {},
+             self.checkpoint_bytes),
+            ("khipu_fastsync_loop_seconds", "gauge", {},
+             self.loop_seconds),
+            ("khipu_fastsync_pending", "gauge", {}, self.pending),
+            ("khipu_fastsync_pending_max", "gauge", {}, self.pending_max),
+        ]
+        return out
+
+
+class _PhaseClock:
+    """Books one ``start()``'s wall time into ``SyncStats``:
+    ``enter(name)`` closes the open phase and opens ``name`` at one
+    clock read; after ``enter(None)`` nothing is open, and the time
+    until the next ``enter`` is in ``loop_seconds`` but in no phase."""
+
+    __slots__ = ("stats", "base", "t0", "t", "phase")
+
+    def __init__(self, stats: SyncStats, phase: Optional[str]):
+        self.stats = stats
+        self.base = stats.loop_seconds  # a resumed start() adds to it
+        self.t0 = self.t = time.perf_counter()
+        self.phase = phase
+
+    def enter(self, name: Optional[str]) -> None:
+        now = time.perf_counter()
+        if self.phase is not None:
+            self.stats.phases[self.phase] += now - self.t
+        self.stats.loop_seconds = self.base + now - self.t0
+        self.t, self.phase = now, name
+
+
 class StateSyncer:
     """Download a state trie to local storages via a fetch callback,
     with checkpoint/resume (SyncingHandler role, peers abstracted).
@@ -155,6 +239,9 @@ class StateSyncer:
         # so the post-sync whole-snapshot re-verification (config #5)
         # runs on resident tiles with zero layout work
         self.mirror = mirror
+        self.stats = SyncStats()
+        # the newest syncer owns the slot: one fast sync runs at a time
+        REGISTRY.register_collector("fastsync", self.stats.samples)
 
     def _verify(self, hashes: List[bytes], values: List[bytes]) -> List[bool]:
         with span(
@@ -170,6 +257,16 @@ class StateSyncer:
         """Begin (or resume) syncing toward target_root; runs to
         completion (the peer-request loop is the fetch callback's
         concern). Returns the final state."""
+        clock = _PhaseClock(self.stats, "checkpoint")  # the resume read
+        try:
+            return self._run(target_root, clock.enter)
+        finally:
+            # also when fetch (a peer pool) raises out of the loop: the
+            # open phase and the loop's seconds are booked either way
+            clock.enter(None)
+
+    def _run(self, target_root: bytes, enter) -> SyncState:
+        stats = self.stats
         state = self.state_storage.get_sync_state()
         if state is None or state.target_root != target_root:
             state = SyncState(
@@ -179,61 +276,95 @@ class StateSyncer:
         batches_done = 0
         seen: Set[bytes] = set()
         while state.pending:
-            batch = state.pending[: self.batch_size]
-            state.pending = state.pending[self.batch_size :]
-            want = [h for _, h in batch]
-            with span("fastsync.fetch", batch=batches_done,
-                      nodes=len(want)):
-                got = self.fetch(want)
-            missing: List[Tuple[int, bytes]] = []
-            hashes, values, kinds = [], [], []
-            for kind, h in batch:
-                v = got.get(h)
-                if v is None:
-                    missing.append((kind, h))
-                else:
-                    hashes.append(h)
-                    values.append(v)
-                    kinds.append(kind)
-            ok = self._verify(hashes, values) if hashes else []
-            node_batch: Dict[bytes, bytes] = {}
-            storage_batch: Dict[bytes, bytes] = {}
-            code_batch: Dict[bytes, bytes] = {}
-            for kind, h, v, good in zip(kinds, hashes, values, ok):
-                if not good:
-                    missing.append((kind, h))  # corrupt: retry later
-                    continue
-                if kind == STATE_NODE:
-                    node_batch[h] = v
-                elif kind == STORAGE_NODE:
-                    storage_batch[h] = v
-                else:
-                    code_batch[h] = v
-                for child in _children_of(kind, v):
-                    if child[1] not in seen:
-                        seen.add(child[1])
-                        state.pending.append(child)
-                state.downloaded_nodes += 1
-            # batched saves (saveAccountNodes :898-918)
-            if node_batch:
-                self.storages.account_node_storage.update([], node_batch)
-            if storage_batch:
-                self.storages.storage_node_storage.update([], storage_batch)
-            if code_batch:
-                self.storages.evmcode_storage.update([], code_batch)
-            if self.mirror is not None:
-                if node_batch:
-                    self.mirror.admit(node_batch)
-                if storage_batch:
-                    self.mirror.admit(storage_batch)
-            state.pending.extend(missing)
-            if missing and not (node_batch or storage_batch or code_batch):
-                raise RuntimeError(
-                    f"no progress: {len(missing)} nodes unavailable"
-                )
-            batches_done += 1
-            if batches_done % self.checkpoint_every == 0:
-                self.state_storage.put_sync_state(state)
+            with span("fastsync.batch", batch=batches_done,
+                      nodes=min(self.batch_size, len(state.pending)),
+                      pending=len(state.pending)):
+                enter("queue")
+                with span("fastsync.queue"):
+                    batch = state.pending[: self.batch_size]
+                    state.pending = state.pending[self.batch_size :]
+                    want = [h for _, h in batch]
+                enter("fetch")
+                with span("fastsync.fetch", batch=batches_done,
+                          nodes=len(want)):
+                    got = self.fetch(want)
+                enter("check")
+                missing: List[Tuple[int, bytes]] = []
+                hashes, values, kinds = [], [], []
+                for kind, h in batch:
+                    v = got.get(h)
+                    if v is None:
+                        missing.append((kind, h))
+                    else:
+                        hashes.append(h)
+                        values.append(v)
+                        kinds.append(kind)
+                ok = self._verify(hashes, values) if hashes else []
+                enter("parse")
+                node_batch: Dict[bytes, bytes] = {}
+                storage_batch: Dict[bytes, bytes] = {}
+                code_batch: Dict[bytes, bytes] = {}
+                with span("fastsync.parse", nodes=len(hashes)):
+                    for kind, h, v, good in zip(kinds, hashes, values, ok):
+                        if not good:
+                            missing.append((kind, h))  # corrupt: retry
+                            stats.rejected += 1
+                            continue
+                        if kind == STATE_NODE:
+                            node_batch[h] = v
+                        elif kind == STORAGE_NODE:
+                            storage_batch[h] = v
+                        else:
+                            code_batch[h] = v
+                        for child in _children_of(kind, v):
+                            if child[1] not in seen:
+                                seen.add(child[1])
+                                state.pending.append(child)
+                        state.downloaded_nodes += 1
+                # batched saves (saveAccountNodes :898-918)
+                enter("store")
+                with span("fastsync.store"):
+                    if node_batch:
+                        self.storages.account_node_storage.update(
+                            [], node_batch)
+                    if storage_batch:
+                        self.storages.storage_node_storage.update(
+                            [], storage_batch)
+                    if code_batch:
+                        self.storages.evmcode_storage.update(
+                            [], code_batch)
+                stats.nodes["state"] += len(node_batch)
+                stats.nodes["storage"] += len(storage_batch)
+                stats.nodes["code"] += len(code_batch)
+                if self.mirror is not None:
+                    enter("admit")  # its spans are the mirror's own
+                    if node_batch:
+                        self.mirror.admit(node_batch)
+                    if storage_batch:
+                        self.mirror.admit(storage_batch)
+                enter("queue")
+                if missing:
+                    with span("fastsync.queue", retried=len(missing)):
+                        state.pending.extend(missing)
+                        stats.retried += len(missing)
+                    if not (node_batch or storage_batch or code_batch):
+                        raise RuntimeError(
+                            f"no progress: {len(missing)} nodes unavailable"
+                        )
+                batches_done += 1
+                if batches_done % self.checkpoint_every == 0:
+                    enter("checkpoint")
+                    with span("fastsync.checkpoint",
+                              pending=len(state.pending)) as sp:
+                        nbytes = self.state_storage.put_sync_state(state)
+                        sp.set_tag("nbytes", nbytes)
+                    stats.checkpoints += 1
+                    stats.checkpoint_bytes += nbytes
+                enter(None)
+                stats.batches += 1
+                stats.requested += len(want)
+                stats.pending = len(state.pending)
+                stats.pending_max = max(stats.pending_max, stats.pending)
         if self.mirror is not None:
             # re-verification of every RESIDENT node on word-major
             # tiles: one dispatch per size class, zero layout work.
@@ -244,7 +375,9 @@ class StateSyncer:
             # covered every node either way. BEFORE purge: a failure
             # must leave the resumable checkpoint intact, not force a
             # full re-download.
+            enter("flush")
             self.mirror.flush()
+            enter("verify")
             bad = self.mirror.verify()
             if bad:
                 raise RuntimeError(
@@ -252,6 +385,7 @@ class StateSyncer:
                     f"{self.mirror.resident_count} resident nodes "
                     "failed content-address check"
                 )
+        enter("checkpoint")
         self.state_storage.purge()
         self.storages.app_state.mark_fast_sync_done()
         return state

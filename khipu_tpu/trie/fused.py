@@ -34,13 +34,15 @@ window.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from collections import OrderedDict
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from khipu_tpu.device import named_jit
 from khipu_tpu.observability.profiler import D2H, H2D, HOST, LEDGER
 from khipu_tpu.observability.recorder import compile_log
 from khipu_tpu.observability.registry import REGISTRY
@@ -57,6 +59,18 @@ FUSED_GAUGES = REGISTRY.gauge_group("khipu_fused", {
     # backend lacks copy_to_host_async) — collect() pays the fetch
     "async_copy_fallbacks": 0,
 }, help="fused-dispatch capability state (trie/fused.py)")
+
+# what the fixpoint program is asked to do, always on: one counter add
+# per class per dispatch (khipu_fused_hashed_rows_total{nblocks=}) and
+# these two. hashed rows = padded rows x rounds (every round
+# re-hashes every row of every class); live nodes = the real dirty
+# nodes those rows carry
+FUSED_LIVE_NODES = REGISTRY.counter(
+    "khipu_fused_live_nodes_total",
+    help="dirty nodes resolved by fused dispatches (trie/fused.py)")
+FUSED_DISPATCHES = REGISTRY.counter(
+    "khipu_fused_dispatches_total",
+    help="fused fixpoint dispatches (trie/fused.py)")
 
 # per-backend-platform capability: does the runtime support
 # copy_to_host_async? Probed on the FIRST dispatch, cached for the
@@ -137,6 +151,7 @@ class _CompileCache:
         self._builder = builder
         self._capacity = max(1, capacity)
         self._od: "OrderedDict[tuple, object]" = OrderedDict()
+        self._scopes: Dict[tuple, Dict] = {}  # scope_map memo
         self._lock = threading.Lock()
 
     @staticmethod
@@ -178,25 +193,90 @@ class _CompileCache:
                 return self._od[key], dt
             compile_log.record("miss", self._label(key), dt)
             self._od[key] = run
-            while len(self._od) > self._capacity:
-                old_key, _ = self._od.popitem(last=False)
-                compile_log.record("evict", self._label(old_key))
+            self._evict_over_capacity()
         return run, dt
+
+    def _evict_over_capacity(self) -> None:  # lock held
+        while len(self._od) > self._capacity:
+            old_key, _ = self._od.popitem(last=False)
+            self._scopes.pop(old_key, None)
+            compile_log.record("evict", self._label(old_key))
 
     def set_capacity(self, capacity: int) -> None:
         with self._lock:
             self._capacity = max(1, capacity)
-            while len(self._od) > self._capacity:
-                old_key, _ = self._od.popitem(last=False)
-                compile_log.record("evict", self._label(old_key))
+            self._evict_over_capacity()
 
     def clear(self) -> None:
         with self._lock:
             self._od.clear()
+            self._scopes.clear()
+
+    def scope_map(self) -> Dict[str, Dict[str, Optional[str]]]:
+        """See :func:`scope_map`."""
+        with self._lock:
+            programs = list(self._od.items())
+        out = {}
+        for key, run in programs:
+            scopes = self._scopes.get(key)
+            if scopes is None:
+                # outside the lock: the text of a Pallas-backed program
+                # is megabytes (the kernels' bodies ride in it)
+                scopes = _scopes_of(run.as_text())
+                with self._lock:
+                    if key in self._od:
+                        self._scopes[key] = scopes
+            out[self._label(key)] = scopes
+        return out
 
     def stats(self) -> dict:
         with self._lock:
             return {"size": len(self._od), "capacity": self._capacity}
+
+
+_SCOPE = re.compile(r"fused\.\w+")
+_REF = re.compile(r"%[\w.\-]+")
+
+
+def _scopes_of(hlo_text: str) -> Dict[str, Optional[str]]:
+    """{instruction name: ``fused.*`` stage, or None} for every
+    instruction in a compiled program's text. The names are the ones a
+    profiler trace prints (``%fusion.12``, here without the ``%``). An
+    instruction's stage is the first ``fused.*`` scope in its own
+    ``op_name``. The TPU compiler gives the fusions it makes of a
+    scatter no metadata, though the instructions fused into them keep
+    theirs: a fusion without metadata is the one stage found in the
+    computation it calls. Whatever else has none (the ``sort`` a
+    scatter also becomes, layout copies) stays None: nothing is
+    inferred from what an instruction reads."""
+    out: Dict[str, Optional[str]] = {}
+    inside: Dict[str, set] = {}  # computation -> stages named in it
+    comp = None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):  # a computation opens or closes
+            head = line.split()
+            comp = (head[1] if head[0] == "ENTRY" else head[0]) \
+                if line.endswith("{") and head else None
+            continue
+        name, eq, rest = line.partition(" = ")
+        if not eq:
+            continue
+        # the attributes end where backend_config starts (a Mosaic
+        # call's is megabytes: the kernel's body rides in it)
+        cut = rest.find("backend_config=")
+        rest = rest[:cut] if cut >= 0 else rest
+        at = rest.find('op_name="')
+        own = _SCOPE.search(
+            rest, at, rest.find('"', at + 9)) if at >= 0 else None
+        if own is not None:
+            scope = own.group()
+            inside.setdefault(comp, set()).add(scope)
+        else:  # callees precede their callers in the text
+            called = set().union(
+                *(inside.get(r, ()) for r in _REF.findall(rest)))
+            scope = called.pop() if len(called) == 1 else None
+        out[name.split()[-1].lstrip("%")] = scope
+    return out
 
 
 def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
@@ -254,33 +334,41 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         runners = [_build_from_bytes(nb, False) for nb, _, _, _ in sig]
     k = len(sig)
 
-    @jax.jit
-    def run(*args):
+    # named_scope puts its name into each instruction's op_name in the
+    # compiled program's text; scope_map() hands that out so that a
+    # trace reader can split the loop body's device time by stage
+    def fused_fixpoint(*args):
         encs = list(args[:k])
         subs = args[k : 4 * k]
         ext = args[4 * k]  # u8[ext_rows, 32] resolved-input tiles
         aidx = args[4 * k + 1 : 4 * k + 1 + k]  # per-class admit rows
 
         def hash_all(encs):
-            return jnp.concatenate(
-                [runners[c](encs[c]) for c in range(k)], axis=0
-            )  # [sum rows, 32] u8 — ONE output array, one host fetch
+            with jax.named_scope("fused.hash"):
+                return jnp.concatenate(
+                    [runners[c](encs[c]) for c in range(k)], axis=0
+                )  # [sum rows, 32] u8 — ONE output array, one fetch
 
         idx32 = jnp.arange(32, dtype=jnp.int32)
 
         def body(_, carry):
             encs, _ = carry
             G = hash_all(encs)
-            Gf = jnp.concatenate([G, ext], axis=0)
+            with jax.named_scope("fused.gather"):
+                Gf = jnp.concatenate([G, ext], axis=0)
             new_encs = []
             for c in range(k):
                 rows = subs[3 * c]
                 offs = subs[3 * c + 1]
                 child = subs[3 * c + 2]
-                rows32 = jnp.repeat(rows, 32)
-                cols32 = (offs[:, None] + idx32).reshape(-1)
-                vals = Gf[child].reshape(-1)  # [nsubs*32] u8
-                new_encs.append(encs[c].at[rows32, cols32].set(vals))
+                with jax.named_scope("fused.gather"):
+                    vals = Gf[child]  # [nsubs, 32] u8
+                with jax.named_scope("fused.subst"), \
+                        jax.named_scope(f"c{sig[c][0]}"):
+                    rows32 = jnp.repeat(rows, 32)
+                    cols32 = (offs[:, None] + idx32).reshape(-1)
+                    new_encs.append(
+                        encs[c].at[rows32, cols32].set(vals.reshape(-1)))
             return new_encs, G
 
         # the carried digests are write-only inside the loop (each
@@ -302,13 +390,15 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         # admit_mirror issues zero extra device work per window
         admit = []
         gbase = 0
-        for c in range(k):
-            nadmit = sig[c][3]
-            if nadmit:
-                admit.append((encs[c][aidx[c]], digs[gbase + aidx[c]]))
-            else:
-                admit.append(None)
-            gbase += sig[c][1]
+        with jax.named_scope("fused.admit"):
+            for c in range(k):
+                nadmit = sig[c][3]
+                if nadmit:
+                    admit.append(
+                        (encs[c][aidx[c]], digs[gbase + aidx[c]]))
+                else:
+                    admit.append(None)
+                gbase += sig[c][1]
         return digs, encs, admit
 
     u8 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.uint8)
@@ -318,7 +408,8 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         shapes += [i32(nsubs)] * 3
     shapes.append(u8(ext_rows, 32))
     shapes += [i32(nadmit) for _, _, _, nadmit in sig]
-    return run.lower(*shapes).compile()
+    return named_jit("fused_fixpoint", fused_fixpoint).lower(
+        *shapes).compile()
 
 
 # the bounded, instrumented successor of `lru_cache(maxsize=64)`;
@@ -326,6 +417,20 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
 # (observability.trace.apply_config calls set_capacity)
 _build_fused = _CompileCache(_build_fused_impl)
 compile_cache = _build_fused  # public handle: stats() / set_capacity()
+
+
+def scope_map() -> Dict[str, Dict[str, Optional[str]]]:
+    """For every program in the compile cache, by its signature label:
+    ``{instruction name: "fused.hash" | "fused.gather" | "fused.subst"
+    | "fused.admit" | None}`` — the stage of the fixpoint program each
+    compiled instruction came from (None: none could be told), read
+    out of the executable's own text. A profiler trace names device
+    events by instruction and carries no scope; a reader joins the two
+    by name, and tells which program a dispatch ran by the names it
+    executed. Parsed on the first call after a program was compiled
+    and kept with its cache entry; never on the compile or dispatch
+    path."""
+    return _build_fused.scope_map()
 
 
 _ROW_GATHER = None
@@ -338,9 +443,7 @@ def _take_rows(table, rows: np.ndarray):
     chip ~0.5 s per window whose row count differs from the last."""
     global _ROW_GATHER
     if _ROW_GATHER is None:
-        import jax
-
-        _ROW_GATHER = jax.jit(lambda t, r: t[r])
+        _ROW_GATHER = named_jit("row_gather", lambda t, r: t[r])
     return _ROW_GATHER(table, rows)
 
 
@@ -551,14 +654,14 @@ def fused_submit(
         ext_rows=int(ext[0].shape[0]) if ext is not None else 0,
         admit=len(admit_live) if admit_live else 0,
         backend="jnp" if use_jnp else "pallas",
-    ):
+    ) as sp:
         return _fused_submit(
-            to_resolve, deps, prefix, use_jnp, depth, ext, admit_live
+            to_resolve, deps, prefix, use_jnp, depth, ext, admit_live, sp
         )
 
 
 def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
-                  admit_live=None) -> FusedJob:
+                  admit_live, sp) -> FusedJob:
     if not to_resolve:
         return FusedJob(None, [])
     if depth is None:
@@ -626,6 +729,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         admit_bufs: List[np.ndarray] = []
         admit_meta: List = []  # per class: (keys, lengths) or None
         sig: List[Tuple[int, int, int, int]] = []
+        live_subs = 0
         for nb in class_list:
             rows = classes[nb]
             width = nb * RATE
@@ -673,6 +777,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             sub_np = np.full((nsubs, 3), (dummy_row, 0, 0), dtype=np.int32)
             if subs:
                 sub_np[: len(subs)] = subs
+            live_subs += len(subs)
             enc_bufs.append(buf)
             sub_arrays.extend(
                 [
@@ -731,6 +836,20 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         run, compile_s = _build_fused.lookup(
             tuple(sig), rounds, use_jnp, ext_rows
         )
+        # padded work against live work: what the buckets cost
+        sp.set_tag("rows_padded", total_rows)
+        sp.set_tag("rounds", rounds)
+        sp.set_tag("subs", live_subs)
+        sp.set_tag("subs_padded", sum(s[2] for s in sig))
+        for nb, nrows, _, _ in sig:
+            REGISTRY.counter(
+                "khipu_fused_hashed_rows_total",
+                help="rows x rounds the fused fixpoint program hashed "
+                     "(trie/fused.py)",
+                labels={"nblocks": str(nb)},
+            ).inc(nrows * rounds)
+        FUSED_LIVE_NODES.inc(len(phs))
+        FUSED_DISPATCHES.inc()
 
         # host->device upload = every host-built input buffer (the ext tile
         # counts only when host-built — gathered device-to-device tiles
@@ -802,7 +921,6 @@ _EXEC_VALIDATE_JIT = None
 def _exec_validate_fn():
     global _EXEC_VALIDATE_JIT
     if _EXEC_VALIDATE_JIT is None:
-        import jax
         import jax.numpy as jnp
 
         def kernel(tx_nonce, acct_nonce, bal, up):
@@ -819,7 +937,7 @@ def _exec_validate_fn():
             balance_ok = jnp.where(has_diff, first_gt, True)
             return nonce_ok & balance_ok
 
-        _EXEC_VALIDATE_JIT = jax.jit(kernel)
+        _EXEC_VALIDATE_JIT = named_jit("exec_validate", kernel)
     return _EXEC_VALIDATE_JIT
 
 

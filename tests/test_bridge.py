@@ -112,6 +112,68 @@ class TestBridge:
             client.close()
             server.stop()
 
+    def test_server_keeps_the_replay_stats_of_its_batches(self):
+        """The per-phase split of each ExecuteBlocks batch stays with
+        the server (bounded); a refused batch leaves none; a tap on
+        ``driver.replay`` (the benchmark's) still sees every batch and
+        the server keeps what the tap hands back."""
+        import dataclasses
+
+        from khipu_tpu.bridge import REPLAY_STATS_KEPT
+        from khipu_tpu.domain.block import Block
+
+        bc = Blockchain(Storages(), CFG)
+        bc.load_genesis(GenesisSpec(alloc=ALLOC))
+        server = BridgeServer(bc, CFG)
+        client = BridgeClient(f"127.0.0.1:{server.start()}")
+        try:
+            blocks = build_blocks(5)
+            assert server.replay_stats() == []
+            client.execute_blocks(blocks[:3])
+            (first,) = server.replay_stats()
+            assert first.blocks == 3 and first.seconds > 0
+            tapped = []
+            inner = server._driver.replay
+
+            def replay(batch):
+                tapped.append(inner(batch))
+                return tapped[-1]
+
+            server._driver.replay = replay
+            client.execute_blocks(blocks[3:4])
+            bad = Block(dataclasses.replace(
+                blocks[4].header, state_root=b"\x13" * 32), blocks[4].body)
+            with pytest.raises(grpc.RpcError):
+                client.execute_blocks([bad])
+            kept = server.replay_stats()
+            assert [s.blocks for s in kept] == [3, 1]
+            assert kept[0] is first and kept[1] is tapped[0]
+            assert server._replay_stats.maxlen == REPLAY_STATS_KEPT
+            # its reader: GetMetrics serves the slowest kept batch with
+            # its phase split, so an outlier names its phase afterwards
+            slow = max(kept, key=lambda s: s.seconds)
+            # (the per-block path of this config books no phases)
+            slow.phases.update(execute=0.25, collect=0.5)
+            fams = {name: {tuple(sorted(lb.items())): v
+                           for lb, v in samples}
+                    for name, (_kind, _help, samples)
+                    in client.get_metrics().items()
+                    if name.startswith("khipu_bridge_replay_")}
+            assert fams == {
+                "khipu_bridge_replay_batches_kept": {(): 2},
+                "khipu_bridge_replay_batch_seconds": {
+                    (("which", "last"),): kept[-1].seconds,
+                    (("which", "slowest"),): slow.seconds,
+                },
+                "khipu_bridge_replay_slowest_phase_seconds": {
+                    (("phase", "execute"),): 0.25,
+                    (("phase", "collect"),): 0.5,
+                },
+            }
+        finally:
+            client.close()
+            server.stop()
+
     def test_invalid_block_aborts(self, bridge):
         import dataclasses
 

@@ -236,3 +236,207 @@ class TestKnownNodes:
         assert mirror.verify() == 0
         # the mirror's resident copy of the root matches the store
         assert mirror.get(root) == target.account_node_storage.get(root)
+
+
+# ------------------------------------------------ SyncStats and spans
+
+
+def _account_trie(accounts: int, seed: int = 5):
+    """(root, {hash: encoding}) of a state trie of plain accounts."""
+    import numpy as np
+
+    from khipu_tpu.domain.account import Account, address_key
+    from khipu_tpu.trie.bulk import bulk_build, host_hasher
+
+    rng = np.random.default_rng(seed)
+    pairs = [
+        (address_key(rng.bytes(16) + i.to_bytes(4, "big")),
+         Account(nonce=i % 7, balance=10**9 + i).encode())
+        for i in range(accounts)
+    ]
+    return bulk_build(pairs, hasher=host_hasher)
+
+
+class _UnreliablePeer:
+    """Serves ``nodes``; every ``forge_every``-th hash asked for the
+    first time is answered with a forged value once, every
+    ``withhold_every``-th is left out of the answer once."""
+
+    def __init__(self, nodes, forge_every=97, withhold_every=61):
+        self.nodes = nodes
+        self.forge_every, self.withhold_every = forge_every, withhold_every
+        self.asked = set()
+        self.forged = self.withheld = self.requested = 0
+
+    def fetch(self, hashes):
+        out = {}
+        self.requested += len(hashes)
+        for h in hashes:
+            first = h not in self.asked
+            self.asked.add(h)
+            n = len(self.asked)
+            if first and n % self.forge_every == 0:
+                self.forged += 1
+                out[h] = self.nodes[h] + b"\x00"
+            elif first and n % self.withhold_every == 0:
+                self.withheld += 1
+            else:
+                out[h] = self.nodes[h]
+        return out
+
+
+class TestSyncStats:
+    def test_phases_tile_the_loop_and_counts_match_the_peer(self):
+        from khipu_tpu.sync.fast_sync import SYNC_PHASES
+
+        root, nodes = _account_trie(3000)
+        peer = _UnreliablePeer(nodes)
+        syncer = StateSyncer(
+            Storages(), FastSyncStateStorage(MemoryKeyValueDataSource()),
+            peer.fetch, batch_size=50,
+        )
+        syncer.start(root)
+        st = syncer.stats
+        assert peer.forged > 5 and peer.withheld > 5
+        assert st.rejected == peer.forged
+        assert st.retried == peer.forged + peer.withheld
+        assert st.requested == peer.requested
+        assert st.nodes == {"state": len(nodes), "storage": 0, "code": 0}
+        assert st.batches >= st.requested / 50
+        assert st.checkpoints == st.batches // 10
+        assert st.checkpoint_bytes > 32 * st.checkpoints
+        assert st.pending == 0 and st.pending_max >= 16
+        assert set(st.phases) == set(SYNC_PHASES)
+        mirror_phases = {"admit", "flush", "verify"}  # no mirror here
+        assert all((st.phases[p] > 0) == (p not in mirror_phases)
+                   for p in SYNC_PHASES)
+        covered = sum(st.phases.values())
+        assert 0.95 * st.loop_seconds <= covered <= st.loop_seconds
+
+    def test_a_loop_that_ends_by_exception_is_still_booked(self):
+        root, nodes = _account_trie(200)
+        calls = []
+
+        def fetch(hashes):
+            calls.append(len(hashes))
+            if len(calls) == 3:
+                import time
+
+                time.sleep(0.05)
+                raise ConnectionError("peer pool gave up")
+            return {h: nodes[h] for h in hashes}
+
+        syncer = StateSyncer(
+            Storages(), FastSyncStateStorage(MemoryKeyValueDataSource()),
+            fetch, batch_size=8,
+        )
+        with pytest.raises(ConnectionError):
+            syncer.start(root)
+        st = syncer.stats
+        assert st.batches == 2
+        assert st.phases["fetch"] >= 0.05  # the open phase was closed
+        assert st.loop_seconds >= sum(st.phases.values()) >= 0.05
+        first = st.loop_seconds
+        # a second start() on the same syncer adds to the same stats
+        syncer.fetch = lambda hashes: {h: nodes[h] for h in hashes}
+        syncer.start(root)
+        assert syncer.stats.loop_seconds > first
+        assert syncer.stats.nodes["state"] >= len(nodes)  # re-downloads
+
+    def test_newest_syncer_owns_the_registry_slot(self):
+        from khipu_tpu.observability.registry import REGISTRY
+
+        def served():
+            fams = REGISTRY.families()
+            return {
+                name: {tuple(lb.values()): v for lb, v in samples}
+                for name, (_k, _h, samples) in fams.items()
+                if name.startswith("khipu_fastsync_")
+            }
+
+        root, nodes = _account_trie(300)
+        fetch = lambda hashes: {h: nodes[h] for h in hashes}
+        first = StateSyncer(
+            Storages(), FastSyncStateStorage(MemoryKeyValueDataSource()),
+            fetch, batch_size=20)
+        first.start(root)
+        got = served()
+        assert got["khipu_fastsync_nodes_total"][("state",)] == len(nodes)
+        assert got["khipu_fastsync_batches_total"][()] == first.stats.batches
+        assert set(got) == {
+            "khipu_fastsync_phase_seconds_total",
+            "khipu_fastsync_nodes_total", "khipu_fastsync_batches_total",
+            "khipu_fastsync_retried_total", "khipu_fastsync_rejected_total",
+            "khipu_fastsync_checkpoint_bytes_total",
+            "khipu_fastsync_loop_seconds", "khipu_fastsync_pending",
+            "khipu_fastsync_requested_total",
+            "khipu_fastsync_checkpoints_total",
+            "khipu_fastsync_pending_max",
+        }
+        # every count SyncStats keeps is served: none is kept for a test
+        st = first.stats
+        assert got["khipu_fastsync_requested_total"][()] == st.requested
+        assert got["khipu_fastsync_checkpoints_total"][()] \
+            == st.checkpoints > 0
+        assert got["khipu_fastsync_pending_max"][()] == st.pending_max > 0
+        assert got["khipu_fastsync_phase_seconds_total"][("parse",)] \
+            == first.stats.phases["parse"]
+        second = StateSyncer(
+            Storages(), FastSyncStateStorage(MemoryKeyValueDataSource()),
+            fetch, batch_size=20)
+        got = served()  # built, not started: the slot is already its
+        assert got["khipu_fastsync_nodes_total"][("state",)] == 0
+        assert got["khipu_fastsync_loop_seconds"][()] == 0
+        assert "khipu_fastsync_pending" in REGISTRY.prometheus_text()
+        assert second.stats is not first.stats
+
+    def test_span_tree_of_a_batch_and_nothing_with_the_tracer_off(self):
+        from khipu_tpu.observability.trace import Tracer, use_tracer
+        from khipu_tpu.storage.device_mirror import DeviceNodeMirror
+
+        root, nodes = _account_trie(300)
+        fetch = lambda hashes: {h: nodes[h] for h in hashes}
+
+        def sync(tracer):
+            syncer = StateSyncer(
+                Storages(),
+                FastSyncStateStorage(MemoryKeyValueDataSource()),
+                fetch, batch_size=20, checkpoint_every=1,
+                mirror=DeviceNodeMirror(capacity_rows_per_class=1024),
+            )
+            with use_tracer(tracer):
+                syncer.start(root)
+            return tracer.snapshot(), syncer.stats
+
+        spans, stats = sync(Tracer())
+        assert spans == []  # off: the ring stays empty
+        assert all(v > 0 for v in stats.phases.values())  # mirror's too
+        assert sum(stats.phases.values()) >= 0.95 * stats.loop_seconds
+        on = Tracer()
+        on.enable()
+        spans, _ = sync(on)
+        batches = [s for s in spans if s.name == "fastsync.batch"]
+        assert len(batches) > 10
+        one = batches[3]
+        assert set(one.tags) == {"batch", "nodes", "pending"}
+        kids = [s.name for s in spans if s.parent == one.sid]
+        # one span per interval: the admit phase is the mirror's span
+        assert kids == [
+            "fastsync.queue", "fastsync.fetch", "fastsync.verify",
+            "fastsync.parse", "fastsync.store", "mirror.admit",
+            "fastsync.checkpoint",
+        ]
+        cp = next(s for s in spans if s.name == "fastsync.checkpoint")
+        assert cp.tags["nbytes"] > 0 and "pending" in cp.tags
+        by_name = {s.name: s for s in spans}
+        assert not {"fastsync.admit", "fastsync.flush",
+                    "fastsync.mirror_verify"} & set(by_name)
+        # after the loop, at the top: the closing flush and verify
+        assert by_name["mirror.flush"].parent is None
+        verify = by_name["mirror.verify"]
+        assert verify.parent is None
+        per_class = [s for s in spans if s.name == "mirror.verify_class"]
+        assert per_class and all(s.parent == verify.sid for s in per_class)
+        assert all(set(s.tags) == {"nblocks", "rows", "tiles"}
+                   for s in per_class)
+        assert sum(s.tags["rows"] for s in per_class) == len(nodes)

@@ -6,6 +6,7 @@ join-4th-mid-sync, kill-mid-stream, rejoin, cutover, retire-an-original
 under live load with zero wrong reads."""
 
 import threading
+import time
 
 import pytest
 
@@ -561,6 +562,11 @@ class TestAcceptanceLiveLoad:
                 with wlock:
                     written[k] = v
                 i += 1
+                # a bounded write rate: each stream page re-reads the
+                # whole store, so a writer that spins outruns a
+                # rebalance starved of the CPU (six xdist workers) and
+                # the test never ends
+                time.sleep(0.001)
 
         def reader():
             n = 0
