@@ -18,9 +18,24 @@ per-node JVM kec256 at KesqueNodeDataSource.scala:61-63.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Mapping, Optional, Set, Tuple
+from typing import (
+    Callable,
+    Collection,
+    Deque,
+    Dict,
+    Iterable,
+    List,
+    Mapping,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Set,
+    Tuple,
+)
 
+from khipu_tpu.base.bytes_util import big_endian_to_int, int_to_big_endian
 from khipu_tpu.base.crypto.keccak import keccak256
 from khipu_tpu.base.rlp import rlp_decode, rlp_encode
 from khipu_tpu.domain.account import (
@@ -49,7 +64,7 @@ class SyncState:
         return rlp_encode(
             [
                 self.target_root,
-                [[bytes([t]), h] for t, h in self.pending],
+                _encode_items(self.pending),
                 self.downloaded_nodes.to_bytes(8, "big"),
             ]
         )
@@ -59,32 +74,192 @@ class SyncState:
         root, pending, count = rlp_decode(data)
         return SyncState(
             target_root=root,
-            pending=[(t[0], h) for t, h in pending],
+            pending=_decode_items(pending),
             downloaded_nodes=int.from_bytes(count, "big"),
         )
 
 
+def _encode_items(items: Iterable[Tuple[int, bytes]]) -> list:
+    return [[bytes([t]), h] for t, h in items]
+
+
+def _decode_items(items: list) -> List[Tuple[int, bytes]]:
+    return [(t[0], h) for t, h in items]
+
+
+@dataclass
+class _Head:
+    """The checkpoint's small record: where in the stored records the
+    queue's front stands. Record 0 is the base, record ``i`` > 0 the
+    ``i``-th delta (the items that joined the back between two
+    checkpoints)."""
+
+    target_root: bytes
+    downloaded_nodes: int
+    tail: int  # oldest record still stored
+    first: int  # oldest record the front has not passed completely
+    skip: int  # items of record ``first`` the front has passed
+    last: int  # newest valid delta; 0: none yet
+
+    def encode(self) -> bytes:
+        return rlp_encode([self.target_root] + [
+            int_to_big_endian(n) for n in (
+                self.downloaded_nodes, self.tail, self.first, self.skip,
+                self.last)])
+
+    @staticmethod
+    def decode(data: bytes) -> "_Head":
+        root, *counts = rlp_decode(data)
+        return _Head(root, *map(big_endian_to_int, counts))
+
+
+class CheckpointWrite(NamedTuple):
+    """What one ``FastSyncStateStorage.checkpoint`` wrote."""
+
+    nbytes: int  # encoded size of the records written
+    appended: int  # items in the delta record (0: none was written)
+    removed: int  # records removed behind the front
+    full: bool  # the whole queue was written as a new base
+
+
 class FastSyncStateStorage:
     """Persist/restore/purge the SyncState
-    (FastSyncStateStorage.scala:24)."""
+    (FastSyncStateStorage.scala:24), in O(what changed) a checkpoint.
+
+    The pending queue is a FIFO, and so is its stored form
+    (docs/recovery.md, "Fast-sync checkpoint"): a base record under
+    ``KEY`` (``SyncState.encode``: what the reference keeps, and all a
+    checkpoint was before the queue was indexed), one delta record per
+    checkpoint that saw items join the back, and a head record that
+    says how far the front has moved. A checkpoint writes its delta,
+    then the head, in one ``update``; the same call removes the records
+    the head *before* this one had already passed, so every head a
+    torn write can leave behind still finds all it names."""
 
     KEY = b"fast-sync-state"
+    HEAD_KEY = KEY + b"/head"
 
     def __init__(self, source):
         self.source = source
+        # set while this object follows the stored queue (it read or
+        # wrote it last): the head as stored, made up for a base that
+        # has none, and the item count of each record from
+        # ``head.first`` to ``head.last``
+        self._head: Optional[_Head] = None
+        self._lens: Deque[int] = deque()
+
+    @classmethod
+    def _record_key(cls, index: int) -> bytes:
+        if index == 0:
+            return cls.KEY  # the base
+        return cls.KEY + b"/" + index.to_bytes(8, "big")
+
+    def _stale_deltas(self) -> List[bytes]:
+        """Every delta the stored head accounts for, and the one beyond
+        ``last`` that a write torn before its head leaves behind."""
+        head = self._head
+        if head is None:
+            raw = self.source.get(self.HEAD_KEY)
+            head = _Head.decode(raw) if raw else _Head(b"", 0, 0, 0, 0, 0)
+        return [self._record_key(i)
+                for i in range(max(head.tail, 1), head.last + 2)]
+
+    def _follow_base(self, state: SyncState) -> None:
+        self._head = _Head(state.target_root, state.downloaded_nodes,
+                           0, 0, 0, 0)
+        self._lens = deque([len(state.pending)])
 
     def put_sync_state(self, state: SyncState) -> int:
-        """Writes the checkpoint; returns its encoded size in bytes."""
+        """Writes the whole state as a new base, in place of whatever
+        checkpoint there was; returns its encoded size in bytes."""
+        # the old head goes in the same update, and before the base
+        # (every engine removes first): no head is ever read with a
+        # base it was not written for
+        stale = [self.HEAD_KEY] + self._stale_deltas()
         raw = state.encode()
-        self.source.put(self.KEY, raw)
+        self._head = None
+        self.source.update(stale, {self.KEY: raw})
+        self._follow_base(state)
         return len(raw)
 
     def get_sync_state(self) -> Optional[SyncState]:
-        raw = self.source.get(self.KEY)
-        return SyncState.decode(raw) if raw is not None else None
+        """The state as of the newest whole checkpoint: the base and
+        the live deltas, less what the front has passed."""
+        self._head = None
+        raw = self.source.get(self.HEAD_KEY)
+        if raw is None:
+            raw = self.source.get(self.KEY)
+            if raw is None:
+                return None
+            state = SyncState.decode(raw)  # a base alone
+            self._follow_base(state)
+            return state
+        head = _Head.decode(raw)
+        lens: Deque[int] = deque()
+        pending: List[Tuple[int, bytes]] = []
+        for i in range(head.first, head.last + 1):
+            raw = self.source.get(self._record_key(i))
+            if raw is None:
+                raise RuntimeError(
+                    f"fast-sync checkpoint: record {i} of "
+                    f"{head.first}..{head.last} is missing")
+            items = (_decode_items(rlp_decode(raw)) if i
+                     else SyncState.decode(raw).pending)
+            lens.append(len(items))
+            pending += items
+        del pending[: head.skip]
+        self._head, self._lens = head, lens
+        return SyncState(head.target_root, pending, head.downloaded_nodes)
+
+    def checkpoint(
+        self,
+        target_root: bytes,
+        downloaded_nodes: int,
+        pending: Collection[Tuple[int, bytes]],
+        taken: int,
+        added: Sequence[Tuple[int, bytes]],
+    ) -> CheckpointWrite:
+        """Checkpoints a queue that, since this object last read or
+        wrote it, gave ``taken`` items off its front and took ``added``
+        onto its back, and now holds ``pending``. ``pending`` is read
+        only when what is stored is not that queue's past (nothing
+        stored, another target, a write that failed): then it becomes
+        the new base."""
+        old = self._head
+        if old is None or old.target_root != target_root:
+            nbytes = self.put_sync_state(
+                SyncState(target_root, list(pending), downloaded_nodes))
+            return CheckpointWrite(nbytes, 0, 0, True)
+        records: Dict[bytes, bytes] = {}
+        last, lens = old.last, self._lens
+        if added:
+            last += 1
+            lens.append(len(added))
+            records[self._record_key(last)] = rlp_encode(
+                _encode_items(added))
+        first, skip = old.first, old.skip + taken
+        while lens and skip >= lens[0]:
+            skip -= lens.popleft()
+            first += 1
+        head = _Head(target_root, downloaded_nodes, old.first, first,
+                     skip, last)
+        records[self.HEAD_KEY] = head.encode()  # after the delta it names
+        # out go the records that ``old``, the head already stored, had
+        # passed: were this write torn after its removes, ``old`` would
+        # still find all it names
+        stale = [self._record_key(i) for i in range(old.tail, old.first)]
+        self._head = None  # until the write is through
+        self.source.update(stale, records)
+        self._head = head
+        return CheckpointWrite(sum(map(len, records.values())),
+                               len(added), len(stale), False)
 
     def purge(self) -> None:
-        self.source.remove(self.KEY)
+        """Removes every record, the head first: a purge torn after it
+        leaves a base alone, which is a whole (older) state."""
+        stale = [self.HEAD_KEY, self.KEY] + self._stale_deltas()
+        self._head = None
+        self.source.update(stale, {})
 
 
 def _children_of(kind: int, encoded: bytes) -> List[Tuple[int, bytes]]:
@@ -144,11 +319,12 @@ class SyncStats:
     as the ``khipu_fastsync_*`` families by whichever syncer is newest.
 
     ``phases`` (seconds) tile ``loop_seconds``: ``queue`` takes the
-    batch off ``pending`` and re-queues what was missing or corrupt,
-    ``check`` matches the answers to the request and content-address
-    checks them, ``parse`` reads the children out of each node,
-    ``checkpoint`` is the resume read and every ``put_sync_state``,
-    ``flush`` and ``verify`` are the closing device-mirror pass."""
+    batch off the front of the pending queue and re-queues what was
+    missing or corrupt, ``check`` matches the answers to the request
+    and content-address checks them, ``parse`` reads the children out
+    of each node, ``checkpoint`` is the resume read, every checkpoint
+    write and the closing purge, ``flush`` and ``verify`` are the
+    closing device-mirror pass."""
 
     phases: Dict[str, float] = field(
         default_factory=lambda: dict.fromkeys(SYNC_PHASES, 0.0))
@@ -160,6 +336,7 @@ class SyncStats:
     rejected: int = 0  # failed the content-address check
     checkpoints: int = 0
     checkpoint_bytes: int = 0
+    checkpoint_full: int = 0  # checkpoints that rewrote the whole queue
     pending: int = 0  # queue length after the last batch
     pending_max: int = 0
     loop_seconds: float = 0.0
@@ -180,6 +357,8 @@ class SyncStats:
              self.checkpoints),
             ("khipu_fastsync_checkpoint_bytes_total", "counter", {},
              self.checkpoint_bytes),
+            ("khipu_fastsync_checkpoint_full_total", "counter", {},
+             self.checkpoint_full),
             ("khipu_fastsync_loop_seconds", "gauge", {},
              self.loop_seconds),
             ("khipu_fastsync_pending", "gauge", {}, self.pending),
@@ -273,16 +452,25 @@ class StateSyncer:
                 target_root=target_root,
                 pending=[(STATE_NODE, target_root)],
             )
+        # the work list is a FIFO: batches leave by the front, children
+        # and retries join at the back. ``taken`` and ``added`` are what
+        # it lost and gained since the last checkpoint, which is all the
+        # next one writes
+        pending: Deque[Tuple[int, bytes]] = deque(state.pending)
+        state.pending = []
+        taken = 0
+        added: List[Tuple[int, bytes]] = []
         batches_done = 0
         seen: Set[bytes] = set()
-        while state.pending:
+        while pending:
             with span("fastsync.batch", batch=batches_done,
-                      nodes=min(self.batch_size, len(state.pending)),
-                      pending=len(state.pending)):
+                      nodes=min(self.batch_size, len(pending)),
+                      pending=len(pending)):
                 enter("queue")
                 with span("fastsync.queue"):
-                    batch = state.pending[: self.batch_size]
-                    state.pending = state.pending[self.batch_size :]
+                    batch = [pending.popleft() for _ in range(
+                        min(self.batch_size, len(pending)))]
+                    taken += len(batch)
                     want = [h for _, h in batch]
                 enter("fetch")
                 with span("fastsync.fetch", batch=batches_done,
@@ -319,7 +507,8 @@ class StateSyncer:
                         for child in _children_of(kind, v):
                             if child[1] not in seen:
                                 seen.add(child[1])
-                                state.pending.append(child)
+                                pending.append(child)
+                                added.append(child)
                         state.downloaded_nodes += 1
                 # batched saves (saveAccountNodes :898-918)
                 enter("store")
@@ -345,7 +534,8 @@ class StateSyncer:
                 enter("queue")
                 if missing:
                     with span("fastsync.queue", retried=len(missing)):
-                        state.pending.extend(missing)
+                        pending.extend(missing)
+                        added.extend(missing)
                         stats.retried += len(missing)
                     if not (node_batch or storage_batch or code_batch):
                         raise RuntimeError(
@@ -355,15 +545,21 @@ class StateSyncer:
                 if batches_done % self.checkpoint_every == 0:
                     enter("checkpoint")
                     with span("fastsync.checkpoint",
-                              pending=len(state.pending)) as sp:
-                        nbytes = self.state_storage.put_sync_state(state)
-                        sp.set_tag("nbytes", nbytes)
+                              pending=len(pending)) as sp:
+                        wrote = self.state_storage.checkpoint(
+                            target_root, state.downloaded_nodes, pending,
+                            taken, added)
+                        sp.set_tag("nbytes", wrote.nbytes)
+                        sp.set_tag("appended", wrote.appended)
+                        sp.set_tag("removed", wrote.removed)
+                    taken, added = 0, []
                     stats.checkpoints += 1
-                    stats.checkpoint_bytes += nbytes
+                    stats.checkpoint_bytes += wrote.nbytes
+                    stats.checkpoint_full += wrote.full
                 enter(None)
                 stats.batches += 1
                 stats.requested += len(want)
-                stats.pending = len(state.pending)
+                stats.pending = len(pending)
                 stats.pending_max = max(stats.pending_max, stats.pending)
         if self.mirror is not None:
             # re-verification of every RESIDENT node on word-major
