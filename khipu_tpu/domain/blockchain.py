@@ -10,26 +10,42 @@ stored genesis, with the stored-vs-computed hash check).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Mapping, Optional, Union
 
 from khipu_tpu.base.crypto.keccak import keccak256
+from khipu_tpu.base.rlp import rlp_encode
 from khipu_tpu.config import KhipuConfig
-from khipu_tpu.domain.account import Account, address_key
+from khipu_tpu.domain.account import EMPTY_CODE_HASH, Account, address_key
 from khipu_tpu.domain.block import Block, BlockBody
 from khipu_tpu.domain.block_header import EMPTY_OMMERS_HASH, BlockHeader
 from khipu_tpu.domain.receipt import Receipt, decode_receipts, encode_receipts
 from khipu_tpu.ledger.bloom import EMPTY_BLOOM
-from khipu_tpu.ledger.world import BlockWorldState
+from khipu_tpu.evm.dataword import to_minimal_bytes
+from khipu_tpu.ledger.world import BlockWorldState, TrieStorage
+from khipu_tpu.observability.trace import span
 from khipu_tpu.storage.storages import Storages
 from khipu_tpu.trie.bulk import bulk_build, device_hasher, host_hasher
 from khipu_tpu.trie.mpt import EMPTY_TRIE_HASH, MerklePatriciaTrie
 
 
 @dataclass(frozen=True)
+class GenesisAccount:
+    """One ``alloc`` entry in geth's genesis shape: a pre-deployed
+    contract (or any account that is more than a balance)."""
+
+    balance: int = 0
+    nonce: Optional[int] = None  # None: the chain's account_start_nonce
+    code: bytes = b""
+    storage: Mapping[int, int] = field(default_factory=dict)  # slot -> value
+
+
+@dataclass(frozen=True)
 class GenesisSpec:
     """Genesis parameters + alloc (GenesisDataLoader's JSON shape)."""
 
-    alloc: Dict[bytes, int] = field(default_factory=dict)  # address -> wei
+    # address -> wei, or a full account record
+    alloc: Dict[bytes, Union[int, GenesisAccount]] = field(
+        default_factory=dict)
     difficulty: int = 0x020000
     gas_limit: int = 8_000_000
     timestamp: int = 0
@@ -163,19 +179,65 @@ class Blockchain:
         self, spec: GenesisSpec, on_device: bool = False
     ) -> Block:
         """Build + persist the genesis state and block
-        (GenesisDataLoader.scala:70). The alloc trie goes through the
+        (GenesisDataLoader.scala:70). The alloc trie, and the storage
+        trie of every alloc entry that has storage, go through the
         level-synchronous bulk build — the TPU path when on_device."""
         start_nonce = self.config.blockchain.account_start_nonce
-        pairs = [
-            (
-                address_key(addr),
-                Account(nonce=start_nonce, balance=balance).encode(),
-            )
-            for addr, balance in spec.alloc.items()
-        ]
         hasher = device_hasher if on_device else host_hasher
-        state_root, nodes = bulk_build(pairs, hasher=hasher)
-        self.storages.account_node_storage.update([], nodes)
+        with span("genesis.load", accounts=len(spec.alloc)) as sp:
+            # a contract's storage trie is built as BlockWorldState
+            # stores it: key keccak(pad32(slot)), value RLP of the
+            # trimmed integer, zero values absent
+            records: Dict[bytes, Account] = {}
+            storage_nodes: Dict[bytes, bytes] = {}
+            codes: Dict[bytes, bytes] = {}
+            slots = 0
+            with span("genesis.storage_tries"):
+                for addr, entry in spec.alloc.items():
+                    if isinstance(entry, int):
+                        continue
+                    cells = [
+                        (TrieStorage.key_bytes(slot),
+                         rlp_encode(to_minimal_bytes(value)))
+                        for slot, value in entry.storage.items() if value
+                    ]
+                    # no cells: the empty trie's root and no nodes
+                    storage_root, trie_nodes = bulk_build(
+                        cells, hasher=hasher)
+                    slots += len(cells)
+                    storage_nodes.update(trie_nodes)
+                    code_hash = EMPTY_CODE_HASH
+                    if entry.code:
+                        code_hash = keccak256(entry.code)
+                        codes[code_hash] = bytes(entry.code)
+                    records[addr] = Account(
+                        nonce=start_nonce if entry.nonce is None
+                        else entry.nonce,
+                        balance=entry.balance,
+                        storage_root=storage_root,
+                        code_hash=code_hash,
+                    )
+            with span("genesis.account_trie"):
+                pairs = [
+                    (
+                        address_key(addr),
+                        (Account(nonce=start_nonce, balance=entry)
+                         if isinstance(entry, int)
+                         else records[addr]).encode(),
+                    )
+                    for addr, entry in spec.alloc.items()
+                ]
+                state_root, nodes = bulk_build(pairs, hasher=hasher)
+            with span("genesis.store"):
+                s = self.storages
+                if storage_nodes:
+                    s.storage_node_storage.update([], storage_nodes)
+                for code_hash, code in codes.items():
+                    s.evmcode_storage.put(code_hash, code)
+                s.account_node_storage.update([], nodes)
+            sp.set_tag("contracts", len(records))
+            sp.set_tag("slots", slots)
+            sp.set_tag("nodes", len(nodes) + len(storage_nodes))
 
         header = BlockHeader(
             parent_hash=b"\x00" * 32,

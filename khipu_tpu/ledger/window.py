@@ -116,7 +116,8 @@ class WindowCommitter:
                  fused: bool = False,
                  on_block_committed=None,
                  mirror=None,
-                 adaptive=None):
+                 adaptive=None,
+                 fused_held=None):
         self.storages = storages
         self.hasher = hasher
         self.fused = fused  # one-dispatch finalize (trie/fused.py)
@@ -127,6 +128,15 @@ class WindowCommitter:
         # admitted by earlier device windows stay valid). None = the
         # configured path is unconditional
         self.adaptive = adaptive
+        # the fused program's buckets as the owner's earlier windows
+        # left them (trie/fused.py HeldBuckets): the replay driver hands
+        # every committer of its node the same record; a committer
+        # built without one holds them over its own windows
+        if fused and fused_held is None:
+            from khipu_tpu.trie.fused import HeldBuckets
+
+            fused_held = HeldBuckets()
+        self.fused_held = fused_held
         # device-resident commit target (storage/device_mirror.py):
         # when set, admit_mirror() lands each sealed window's live
         # nodes in HBM straight from the fused outputs and persist()
@@ -468,6 +478,7 @@ class WindowCommitter:
                         depth=max_depth,
                         ext=ext_arg,
                         admit_live=admit_live,
+                        held=self.fused_held,
                     )
                 fj = job.fused_job
                 if fj.dpos:
@@ -588,7 +599,7 @@ class WindowCommitter:
             # d2d gathers out of the source jobs' digest tiles: only
             # the int32 row indices are uploaded
             with LEDGER.transfer("seal.alias_gather", H2D, nbytes):
-                tile, offsets = gather_ext_tile(sources)
+                tile, offsets = gather_ext_tile(sources, self.fused_held)
         for (_src, childs), base in zip(groups.values(), offsets):
             for i, c in enumerate(childs):
                 ext_pos[c] = base + i

@@ -362,9 +362,12 @@ class MetricsRegistry:
         with self._lock:
             self._collectors[key] = fn
 
-    def unregister_collector(self, key: str) -> None:
+    def unregister_collector(self, key: str, fn=None) -> None:
+        """Drop ``key``; with ``fn``, only while ``fn`` still owns it
+        (a stopped owner must not take down its successor's)."""
         with self._lock:
-            self._collectors.pop(key, None)
+            if fn is None or self._collectors.get(key) == fn:
+                self._collectors.pop(key, None)
 
     @contextmanager
     def scrape_pass(self):

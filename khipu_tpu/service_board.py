@@ -15,6 +15,7 @@ from typing import Optional
 
 from khipu_tpu.config import KhipuConfig
 from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
+from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.storage.storages import Storages
 from khipu_tpu.txpool import OmmersPool, PendingTransactionsPool
 
@@ -35,6 +36,10 @@ class ServiceBoard:
             unconfirmed_depth=config.db.unconfirmed_depth,
             cache_size=config.db.cache_size,
         )
+        # this node's stores answer for khipu_nodestore_* until it
+        # shuts down (or a newer board of the process takes over)
+        REGISTRY.register_collector(
+            "nodestore", self.storages.nodestore_samples)
         self.blockchain = Blockchain(self.storages, config)
         if self.blockchain.get_header_by_number(0) is None:
             self.blockchain.load_genesis(genesis or GenesisSpec())
@@ -448,4 +453,6 @@ class ServiceBoard:
             shutdown_exec_pool()
         except Exception:
             pass
+        REGISTRY.unregister_collector(
+            "nodestore", self.storages.nodestore_samples)
         self.storages.stop()

@@ -85,6 +85,10 @@ class FIFOCache(Generic[K, V]):
     def read_count(self) -> int:
         return self._hits + self._misses
 
+    @property
+    def hits(self) -> int:
+        return self._hits
+
     def reset_counters(self) -> None:
         with self._lock:
             self._hits = 0

@@ -644,15 +644,18 @@ class DeviceNodeMirror:
                      alias: bool = True) -> None:
         """Admit rows whose padded encodings (u8[N, nblocks*RATE]) and
         claimed digests (u8[N, 32]) already live ON DEVICE, N a
-        multiple of 1024. This is the window-commit ingest: gathers
-        from a FusedJob's outputs feed straight in, zero node bytes
-        uploaded. ``alias`` keys land in the placeholder
+        multiple of 1024; a tile without a key is skipped. This is the
+        window-commit ingest: gathers from a FusedJob's outputs feed
+        straight in, zero node bytes uploaded. ``alias`` keys land in the placeholder
         namespace until :meth:`rekey` publishes them."""
         n = enc_dev.shape[0]
         if n % TILE:
             raise ValueError("admit_device wants whole 1024-row tiles")
         cm = self._class(nblocks)
         for start in range(0, n, TILE):
+            if all(k is None for k in keys[start : start + TILE]):
+                continue  # padding only (the fused program's spare
+                # admit slots): nothing to make resident
             cm.admit_tile_device(
                 keys[start : start + TILE],
                 enc_dev[start : start + TILE],

@@ -7,6 +7,8 @@ wrapper for eth_call simulation), ArchiveNodeStorage (no prune).
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Dict, Iterable, List, Mapping, Optional
 
 from khipu_tpu.storage.cache import FIFOCache
@@ -31,19 +33,41 @@ class NodeStorage:
         # Never cached on hit: the mirror ring-evicts, and the spill
         # lands the durable copy in the host store shortly after.
         self.mirror = None
+        # where a read that missed the cache was answered from, and the
+        # wall seconds the reading thread spent in its look in the
+        # source (ring + engine, found or not; a wait for the GIL after
+        # the engine's pread is inside it). Miss path only: hits are
+        # FIFOCache's own count. Readers are the driver, persist and
+        # RPC threads, so the adds are under a lock of their own.
+        self._miss_lock = threading.Lock()
+        self.source_reads = 0
+        self.mirror_reads = 0
+        self.absent_reads = 0
+        self.source_seconds = 0.0
 
     def get(self, key: bytes) -> Optional[bytes]:
         v = self._cache.get(key)
         if v is not None:
             return v
+        t0 = time.perf_counter()
         v = self._unconfirmed.get(key)
+        dt = time.perf_counter() - t0
         if v is not None:
+            with self._miss_lock:
+                self.source_seconds += dt
+                self.source_reads += 1
             self._cache.put(key, v)
             return v
         m = self.mirror
         if m is not None:
-            return m.get(key)
-        return None
+            v = m.get(key)
+        with self._miss_lock:
+            self.source_seconds += dt
+            if v is not None:
+                self.mirror_reads += 1
+            else:
+                self.absent_reads += 1
+        return v
 
     def put(self, key: bytes, value: bytes) -> None:
         self.update([], {key: value})
@@ -84,6 +108,23 @@ class NodeStorage:
     @property
     def cache_read_count(self) -> int:
         return self._cache.read_count
+
+    def registry_samples(self, store: str) -> list:
+        """``khipu_nodestore_*`` samples of this store for a registry
+        collector (``Storages.nodestore_samples``)."""
+        out = [
+            ("khipu_nodestore_reads_total", "counter",
+             {"store": store, "from": origin}, n)
+            for origin, n in (
+                ("cache", self._cache.hits),
+                ("source", self.source_reads),
+                ("mirror", self.mirror_reads),
+                ("absent", self.absent_reads),
+            )
+        ]
+        out.append(("khipu_nodestore_source_seconds_total", "counter",
+                    {"store": store}, round(self.source_seconds, 6)))
+        return out
 
 
 class ReadOnlyNodeStorage:

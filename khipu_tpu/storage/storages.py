@@ -107,6 +107,17 @@ class Storages:
             self.evmcode_storage,
         )
 
+    def nodestore_samples(self) -> list:
+        """``khipu_nodestore_*``: where node reads were answered from,
+        per store, for a registry collector. The node that owns these
+        storages registers it (``ServiceBoard``), so a store built
+        beside it (a genesis builder's, a test's) takes no slot."""
+        out = []
+        for name, store in zip(("account", "storage", "evmcode"),
+                               self._node_storages):
+            out.extend(store.registry_samples(name))
+        return out
+
     @property
     def window_journal(self):
         """The crash-consistency WAL (lazy: sync/journal.py imports

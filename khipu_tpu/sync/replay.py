@@ -510,6 +510,15 @@ class ReplayDriver:
         # replays like the mirror: a driver that serves many batches
         # (the bridge) keeps one EWMA history and one flip count
         self._adaptive = None
+        # per-driver like the two above: the buckets the fused
+        # program's signature has settled on (trie/fused.py
+        # HeldBuckets). Every batch builds a committer of its own; the
+        # signature its windows settled on must outlive it
+        self._fused_held = None
+        if self.hasher is not None:
+            from khipu_tpu.trie.fused import HeldBuckets
+
+            self._fused_held = HeldBuckets()
 
     def recover(self):
         """Crash-recovery startup pass (sync/journal.py): settle every
@@ -689,6 +698,7 @@ class ReplayDriver:
                 ),
                 mirror=mirror,
                 adaptive=adaptive,
+                fused_held=self._fused_held,
             )
 
         committer = make_committer(parent.state_root)

@@ -135,6 +135,71 @@ def _pow2(n: int, floor: int = 1) -> int:
     return v
 
 
+# how many windows a held bucket outlives the last window that needed
+# it (HeldBuckets). Long against a compile (a signature first met costs
+# tens of seconds, ~100 windows' worth of wall), short against a node's
+# life: one outlier window pads every later one for ~10-25 minutes of
+# sync, not for good
+HOLD_WINDOWS = 1024
+# the resolved-input tile never takes fewer rows than this (256 KiB of
+# digests a dispatch): how many windows are in flight when one is packed
+# (none, one, two) is the pipeline's timing and not the workload's
+# shape, and under that many rows it moves no signature
+EXT_HELD_ROWS = 8192
+
+
+class HeldBuckets:
+    """The fused program's buckets as one owner's earlier windows left
+    them. A count of the signature (a class's rows, its substitutions,
+    the ext tile's rows) takes the largest bucket any of the owner's
+    last ``HOLD_WINDOWS`` windows needed, not its own: a count that sits
+    on a bucket's edge would otherwise flip the signature from window to
+    window, and several counts that flip independently meet new
+    combinations, each a compile of seconds, long after warm-up. Held, a
+    dimension changes only when a window outgrows everything in memory,
+    or when nothing has needed the held bucket for ``HOLD_WINDOWS``
+    windows: it then falls to the largest those windows did need (a
+    signature the owner has most likely compiled before).
+
+    The owner is whoever dispatches window after window: the replay
+    driver keeps one for its node, a ``WindowCommitter`` built without
+    one keeps its own, a lone ``fused_submit`` starts a new one. Each
+    change is served as ``khipu_fused_held_bucket{dim=}`` (the newest
+    writer's value, like the pipeline gauges)."""
+
+    def __init__(self):
+        # dim -> [held, largest asked since `held` was last needed,
+        #         askings since then]
+        self._dims: Dict[tuple, list] = {}
+        self.take(("ext",), EXT_HELD_ROWS)
+
+    def take(self, dim: tuple, bucket: int) -> int:
+        """The bucket to use in ``dim`` for a window that needs
+        ``bucket``."""
+        if dim == ("ext",):
+            bucket = max(bucket, EXT_HELD_ROWS)
+        rec = self._dims.get(dim)
+        if rec is not None and bucket < rec[0]:
+            rec[1] = max(rec[1], bucket)
+            rec[2] += 1
+            if rec[2] < HOLD_WINDOWS:
+                return rec[0]
+            bucket = rec[1]
+        if rec is None or bucket != rec[0]:
+            REGISTRY.gauge(
+                "khipu_fused_held_bucket",
+                help="rows or substitutions the fused program is "
+                     "compiled for in this dimension of its signature "
+                     "(trie/fused.py HeldBuckets)",
+                labels={"dim": ".".join(str(d) for d in dim)},
+            ).set(bucket)
+        self._dims[dim] = [bucket, 0, 0]
+        return bucket
+
+    def snapshot(self) -> Dict[tuple, int]:
+        return {dim: rec[0] for dim, rec in self._dims.items()}
+
+
 class _CompileCache:
     """Bounded LRU over compiled fixpoint programs, keyed by the full
     shape signature (per-class (nblocks, nrows, nsubs), rounds, backend,
@@ -342,6 +407,7 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         subs = args[k : 4 * k]
         ext = args[4 * k]  # u8[ext_rows, 32] resolved-input tiles
         aidx = args[4 * k + 1 : 4 * k + 1 + k]  # per-class admit rows
+        trips = args[5 * k + 1]  # i32[]: this window's DAG depth
 
         def hash_all(encs):
             with jax.named_scope("fused.hash"):
@@ -377,11 +443,11 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         # every class's unrolled sponge a second time per program
         total_rows = sum(s[1] for s in sig)
         encs, digs = jax.lax.fori_loop(
-            0, rounds, body,
+            0, jnp.minimum(trips, rounds), body,
             (encs, jnp.zeros((total_rows, 32), jnp.uint8)),
         )
-        # rounds >= depth, so both digs (= hash of the encodings after
-        # rounds-1 substitution passes) and encs (rounds passes) are at
+        # trips >= depth, so both digs (= hash of the encodings after
+        # trips-1 substitution passes) and encs (trips passes) are at
         # the fixpoint: encs carry only real child digests and
         # keccak(encs[c][r]) == digs row r of class c
         #
@@ -408,6 +474,7 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
         shapes += [i32(nsubs)] * 3
     shapes.append(u8(ext_rows, 32))
     shapes += [i32(nadmit) for _, _, _, nadmit in sig]
+    shapes.append(i32())
     return named_jit("fused_fixpoint", fused_fixpoint).lower(
         *shapes).compile()
 
@@ -447,16 +514,18 @@ def _take_rows(table, rows: np.ndarray):
     return _ROW_GATHER(table, rows)
 
 
-def gather_ext_tile(sources) -> Tuple[object, List[int]]:
+def gather_ext_tile(sources, held: HeldBuckets) -> Tuple[object, List[int]]:
     """Build the resolved-input tile for ``fused_submit`` from
     ``[(digest_table, row_indices), ...]``: each part is gathered
     device-to-device with its row list padded to a multiple of
     EXT_FLOOR (padding repeats row 0) and the whole tile to a pow-2
     row count, so the gather programs come from a small set of shapes
     and the tile's row count — part of the fused program's signature
-    — does not follow each window's exact cross-ref count. Returns
-    ``(tile u8[n, 32], part offsets)``: part ``i``'s real rows start
-    at ``offsets[i]``."""
+    — does not follow each window's exact cross-ref count, nor (it is
+    one of ``held``'s dimensions) how many windows happened to be in
+    flight.
+    Returns ``(tile u8[n, 32], part offsets)``: part ``i``'s real rows
+    start at ``offsets[i]``."""
     import jax.numpy as jnp
 
     parts, offsets, total = [], [], 0
@@ -468,7 +537,7 @@ def gather_ext_tile(sources) -> Tuple[object, List[int]]:
         parts.append(_take_rows(table, padded))
         offsets.append(total)
         total += len(padded)
-    whole = _pow2(total, floor=EXT_FLOOR)
+    whole = held.take(("ext",), _pow2(total, floor=EXT_FLOOR))
     if whole > total:
         parts.append(jnp.zeros((whole - total, 32), dtype=jnp.uint8))
     tile = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
@@ -618,6 +687,7 @@ def fused_submit(
     depth: int = None,
     ext=None,
     admit_live=None,
+    held: Optional[HeldBuckets] = None,
 ) -> FusedJob:
     """Pack + dispatch the fixpoint program that resolves placeholder ->
     real Keccak-256 hash for every entry of ``to_resolve`` (placeholder
@@ -642,6 +712,9 @@ def fused_submit(
     tiles INSIDE the dispatch (``FusedJob.admit_tiles``) — the
     device-resident commit's admit pass folded into this program so it
     costs no extra device round-trip per window.
+
+    ``held``: the caller's :class:`HeldBuckets`, kept across its
+    windows; a call without one starts a new record.
     """
     from khipu_tpu.chaos import fault_point
 
@@ -656,12 +729,13 @@ def fused_submit(
         backend="jnp" if use_jnp else "pallas",
     ) as sp:
         return _fused_submit(
-            to_resolve, deps, prefix, use_jnp, depth, ext, admit_live, sp
+            to_resolve, deps, prefix, use_jnp, depth, ext, admit_live, sp,
+            held if held is not None else HeldBuckets(),
         )
 
 
 def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
-                  admit_live, sp) -> FusedJob:
+                  admit_live, sp, held) -> FusedJob:
     if not to_resolve:
         return FusedJob(None, [])
     if depth is None:
@@ -702,10 +776,11 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             rows = classes[nb]
             # +1 guarantees at least one spare padding row for dummy subs;
             # pallas needs whole 1024-row tiles, the jnp path only pow-2
-            if use_jnp:
-                nrows_pad[nb] = _pow2(len(rows) + 1, floor=16)
-            else:
-                nrows_pad[nb] = _pallas_target_count(nb, len(rows) + 1)
+            n = len(rows) + 1
+            nrows_pad[nb] = held.take(
+                (nb, "rows"),
+                _pow2(n, floor=16) if use_jnp
+                else _pallas_target_count(nb, n))
             for r, ph in enumerate(rows):
                 dpos[ph] = base + r
             base += nrows_pad[nb]
@@ -720,8 +795,8 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         # padded out to whole 1024-row mirror tiles (the dummy points
         # at the class's guaranteed padding row — a valid multi-rate-
         # padded filler whose digest is self-consistent, so filler
-        # slots verify). Tile-count is pow-2 bucketed so window-to-
-        # window live-set jitter shares one compiled signature.
+        # slots verify). The slots are as many as the class's row
+        # bucket, so the live set's size moves no compiled signature.
         from khipu_tpu.storage.device_mirror import TILE as _MTILE
 
         enc_bufs: List[np.ndarray] = []
@@ -729,6 +804,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         admit_bufs: List[np.ndarray] = []
         admit_meta: List = []  # per class: (keys, lengths) or None
         sig: List[Tuple[int, int, int, int]] = []
+        live: List[str] = []  # sig's counts before padding, same layout
         live_subs = 0
         for nb in class_list:
             rows = classes[nb]
@@ -772,7 +848,8 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             # coarse floor: windows of similar size must land in the SAME
             # compiled signature (every distinct shape costs a fresh XLA
             # compile on the first window that hits it)
-            nsubs = _pow2(len(subs) + 1, floor=1024 if use_jnp else 4096)
+            nsubs = held.take((nb, "subs"), _pow2(
+                len(subs) + 1, floor=1024 if use_jnp else 4096))
             dummy_row = nrows_pad[nb] - 1  # guaranteed padding row
             sub_np = np.full((nsubs, 3), (dummy_row, 0, 0), dtype=np.int32)
             if subs:
@@ -795,9 +872,12 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                         aidx_list.append(r)
                         akeys.append(ph)
                         alens.append(len(to_resolve[ph]))
-            if aidx_list:
-                ntiles = _pow2(-(-len(aidx_list) // _MTILE))
-                nadmit = ntiles * _MTILE
+                # as many admit slots as the class has rows, in whole
+                # mirror tiles: the live rows fill the front, the
+                # mirror takes the tiles that hold one (admit_device),
+                # and how many of a class's rows are live is no count
+                # of the signature
+                nadmit = -(-nrows_pad[nb] // _MTILE) * _MTILE
                 aidx_np = np.full(nadmit, dummy_row, dtype=np.int32)
                 aidx_np[: len(aidx_list)] = aidx_list
                 akeys.extend([None] * (nadmit - len(aidx_list)))
@@ -809,12 +889,15 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 admit_bufs.append(np.zeros(0, dtype=np.int32))
                 admit_meta.append(None)
             sig.append((nb, nrows_pad[nb], nsubs, nadmit))
+            live.append(f"{nb}x{len(rows)}/{len(subs)}+a{len(aidx_list)}")
 
         # resolved-input tile: always an input (a dummy zero tile when the
         # window has no cross-refs) so every window shares one compiled
         # signature family regardless of pipeline depth
+        # (a tile from gather_ext_tile has taken its held size already)
         n_ext = ext_dev.shape[0] if ext_dev is not None else 0
-        ext_rows = _pow2(max(n_ext, 1), floor=EXT_FLOOR)
+        ext_rows = (_pow2(n_ext, floor=EXT_FLOOR) if ext_dev is not None
+                    else held.take(("ext",), EXT_FLOOR))
         if ext_dev is None:
             ext_buf = np.zeros((ext_rows, 32), dtype=np.uint8)
         elif n_ext != ext_rows:
@@ -828,17 +911,21 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         else:
             ext_buf = ext_dev
 
-        # coarse: depth 3 and 4 share a compile. Floor 4 (was 8): shallow
-        # windows — the common replay shape — were paying 2x the fixpoint
-        # compute for bucketing alone, and the collector stage that blocks
-        # on this program is the pipeline's critical stage
-        rounds = _pow2(depth, floor=4)
+        # the program loops `depth` times (its last input), under the
+        # one cap every window shares: a DAG one level deeper than any
+        # before is no new signature, and no round hashes for the sake
+        # of a bucket
+        rounds = depth
         run, compile_s = _build_fused.lookup(
-            tuple(sig), rounds, use_jnp, ext_rows
+            tuple(sig), MAX_DEPTH, use_jnp, ext_rows
         )
         # padded work against live work: what the buckets cost
         sp.set_tag("rows_padded", total_rows)
         sp.set_tag("rounds", rounds)
+        # the signature's counts before their buckets: how far this
+        # window sits from the edge at which it would compile anew
+        sp.set_tag("live", ",".join(live))
+        sp.set_tag("ext_live", len(ext_pos))
         sp.set_tag("subs", live_subs)
         sp.set_tag("subs_padded", sum(s[2] for s in sig))
         for nb, nrows, _, _ in sig:
@@ -871,7 +958,8 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         with LEDGER.transfer("seal.upload", H2D, up):
             # async: no host sync
             digests, final_encs, admit_out = run(
-                *[*enc_bufs, *sub_arrays, ext_buf, *admit_bufs]
+                *[*enc_bufs, *sub_arrays, ext_buf, *admit_bufs,
+                  np.int32(rounds)]
             )
     _up_s = time.perf_counter() - _up_t0
     # start the device->host copy NOW: it streams as soon as the

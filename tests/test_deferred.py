@@ -234,14 +234,20 @@ class TestFusedFinalize:
 
     def test_ext_tile_shapes_are_bucketed(self):
         """The resolved-input tile is gathered with row lists padded
-        to multiples of EXT_FLOOR and its total to a pow-2: windows
-        whose cross-ref counts differ (1298 vs 1303 on the chip) share
-        their gather programs and ONE fused ext bucket, instead of
-        compiling a handful of small programs per window."""
+        to multiples of EXT_FLOOR and its total to a pow-2 of at least
+        EXT_HELD_ROWS: windows whose cross-ref counts differ (1298 vs
+        1303 on the chip) share their gather programs and ONE fused ext
+        bucket, instead of compiling a handful of small programs per
+        window."""
         import jax.numpy as jnp
         import numpy as np
 
-        from khipu_tpu.trie.fused import EXT_FLOOR, gather_ext_tile
+        from khipu_tpu.trie.fused import (
+            EXT_FLOOR,
+            EXT_HELD_ROWS,
+            HeldBuckets,
+            gather_ext_tile,
+        )
 
         rng = np.random.default_rng(4)
         t1 = jnp.asarray(rng.integers(0, 256, (512, 32), dtype=np.uint8))
@@ -252,7 +258,7 @@ class TestFusedFinalize:
             r1 = rng.integers(0, 512, n1).astype(np.int32)
             r2 = rng.integers(0, 256, n2).astype(np.int32)
             sources = [(t1, r1)] + ([(t2, r2)] if n2 else [])
-            tile, offsets = gather_ext_tile(sources)
+            tile, offsets = gather_ext_tile(sources, HeldBuckets())
             n = tile.shape[0]
             totals[(n1, n2)] = n
             assert n >= EXT_FLOOR and n & (n - 1) == 0  # pow-2 bucket
@@ -263,10 +269,11 @@ class TestFusedFinalize:
             if n2:
                 np.testing.assert_array_equal(
                     got[offsets[1] : offsets[1] + n2], np.asarray(t2)[r2])
-        assert set(totals.values()) == {64, 128, 256}
-        # a small second part does not double the bucket
-        assert totals[(100, 5)] == totals[(120, 10)] == totals[(125, 3)] == 256
-        assert totals[(65, 0)] == 128
+        # under EXT_HELD_ROWS the cross-ref count moves no signature
+        assert set(totals.values()) == {EXT_HELD_ROWS}
+        big = rng.integers(0, 512, EXT_HELD_ROWS + 1).astype(np.int32)
+        tile, offsets = gather_ext_tile([(t1, big)], HeldBuckets())
+        assert tile.shape[0] == 2 * EXT_HELD_ROWS and offsets == [0]
 
     def test_fused_windowed_replay_equals_host(self):
         """End to end: windowed replay with the fused committer produces

@@ -137,6 +137,29 @@ def test_alias_rows_hidden_until_rekey():
     assert m.verify() == 0
 
 
+def test_admit_device_skips_tiles_that_hold_no_key():
+    """The fused program hands over as many admit slots as the class has
+    rows; the tiles past the live rows are padding and take no ring
+    tile."""
+    import jax.numpy as jnp
+
+    from khipu_tpu.storage.device_mirror import TILE
+
+    m = DeviceNodeMirror(capacity_rows_per_class=4 * TILE)
+    encs = [bytes([i + 1]) * (40 + 7 * i) for i in range(3)]
+    enc_dev, claim_dev = _device_tile(encs)
+    aliases = [b"\xaa" + i.to_bytes(31, "big") for i in range(3)]
+    m.admit_device(
+        1, aliases + [None] * (3 * TILE - 3),
+        jnp.concatenate([enc_dev] * 3), jnp.concatenate([claim_dev] * 3),
+        [len(e) for e in encs] + [0] * (3 * TILE - 3))
+    cm = m._class(1)
+    assert cm.fill == TILE  # one tile taken, two skipped
+    assert m.rekey({a: keccak256(e) for a, e in zip(aliases, encs)}) == 3
+    assert all(m.get(keccak256(e)) == e for e in encs)
+    assert m.verify() == 0
+
+
 def test_drop_aliases_forgets_unpublished_rows():
     """A torn window's aliases are dropped, never promoted: a later
     rekey with the same placeholder bytes must move nothing."""
