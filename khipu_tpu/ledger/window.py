@@ -19,9 +19,13 @@ during execution), so windows > 1 require Byzantium+ receipt semantics
 
 from __future__ import annotations
 
+import dataclasses
+import itertools
 import time
 from collections import deque
 from typing import Dict, List, Optional, Set, Tuple
+
+import numpy as np
 
 from khipu_tpu.base.crypto.keccak import keccak256
 from khipu_tpu.domain.account import address_key
@@ -37,6 +41,9 @@ from khipu_tpu.trie.deferred import (
     _substitute_bytes,
     _substitute_many,
     _PLACEHOLDER_PREFIX,
+    find_sites,
+    found_digests,
+    splice_sites,
 )
 from khipu_tpu.trie.mpt import EMPTY_TRIE_HASH
 
@@ -52,6 +59,21 @@ WINDOW_GAUGES = REGISTRY.gauge_group("khipu_window", {
     # back to the host hasher (docs/recovery.md graceful degradation)
     "fused_fallbacks": 0,
 }, help="window-commit graceful-degradation state (ledger/window.py)")
+
+# what the seal stage's one site scan met, by what each site's 32 bytes
+# turned out to be: this window's own node (a substitution of the fused
+# program), a node an earlier window resolved (spliced on the host), a
+# node of a window still in flight (a row of the ext tile: what
+# pipeline_depth costs that tile), or nothing this committer knows
+SEAL_SITES = {
+    kind: REGISTRY.counter(
+        "khipu_seal_sites_total",
+        help="placeholder sites the seal stage's scan met "
+             "(ledger/window.py)",
+        labels={"kind": kind},
+    )
+    for kind in ("local", "resolved", "ext", "opaque")
+}
 
 
 class _StagedReadThrough:
@@ -106,6 +128,43 @@ class WindowPlaceholderError(Exception):
             "range cannot be collected here)"
         )
         self.index = idx
+
+
+@dataclasses.dataclass
+class _Pack:
+    """What the seal stage's one site scan gives its two halves."""
+
+    to_resolve: Dict[bytes, bytes]  # ph -> encoding, resolved refs spliced
+    ext_refs: Dict[bytes, Tuple["WindowJob", int]]  # in-flight children
+    max_depth: int
+    met: Dict[str, int]  # sites by kind (SEAL_SITES)
+    # the sites the fused program substitutes: own children first, then
+    # refs into in-flight windows. node/child index to_resolve's order
+    node: np.ndarray
+    off: np.ndarray
+    child: np.ndarray  # of the own-child sites only
+    ext_keys: List[bytes]  # of the in-flight sites: the child's bytes
+
+    def subs(self, ext_pos: Dict[bytes, int]):
+        """``(node, off, child)`` for ``fused_submit``: an in-flight
+        child is ``len(to_resolve) + its row of the ext tile``."""
+        ext = np.fromiter(
+            map(ext_pos.__getitem__, self.ext_keys), np.int64,
+            len(self.ext_keys),
+        )
+        return (
+            self.node, self.off,
+            np.concatenate([self.child, len(self.to_resolve) + ext]),
+        )
+
+    def deps(self) -> Dict[bytes, List[bytes]]:
+        """ph -> own children, for the host hasher's level loop."""
+        phs = list(self.to_resolve)
+        deps: Dict[bytes, List[bytes]] = {ph: [] for ph in phs}
+        own = self.node[: self.child.size]
+        for parent, child in zip(own.tolist(), self.child.tolist()):
+            deps[phs[parent]].append(phs[child])
+        return deps
 
 
 class WindowCommitter:
@@ -354,83 +413,18 @@ class WindowCommitter:
 
         resolved_global = self._resolved_global
         inflight_rows = self._inflight_rows
-        to_resolve: Dict[bytes, bytes] = {}
-        deps: Dict[bytes, List[bytes]] = {}
-        depth_of: Dict[bytes, int] = {}
-        # refs into sealed-but-uncollected windows: ph -> (job, row).
-        # These stay AS placeholder bytes in the packed encodings; the
-        # device substitutes them from the resolved-input tile
-        ext_refs: Dict[bytes, Tuple["WindowJob", int]] = {}
-        max_depth = 0
-        # ONE ascending scan does substitution of prior-window hashes,
-        # child detection AND depth: placeholder indices are assigned
-        # at node creation and tries build bottom-up, so a child's
-        # index is always below its parent's — by the time a parent is
-        # scanned, every child's depth is known
         _pack_t0 = time.perf_counter() if LEDGER.enabled else 0.0
         with span("seal.pack") as pack_sp:
-            for idx in range(start, end):
-                ph = _make_placeholder(idx)
-                enc = self._staged.get(ph)
-                if enc is None:
-                    continue  # e.g. another session's counter range
-                pos = enc.find(_PLACEHOLDER_PREFIX)
-                if pos < 0:
-                    to_resolve[ph] = enc
-                    deps[ph] = []
-                    depth_of[ph] = 1
-                    if max_depth < 1:
-                        max_depth = 1
-                    continue
-                out = bytearray(enc)
-                children: List[bytes] = []
-                d = 1
-                while pos >= 0:
-                    child = bytes(out[pos : pos + 32])
-                    real = resolved_global.get(child)
-                    if real is not None:
-                        out[pos : pos + 32] = real
-                    else:
-                        cd = depth_of.get(child)
-                        if cd is not None:
-                            children.append(child)
-                            if cd >= d:
-                                d = cd + 1
-                        else:
-                            src = inflight_rows.get(child)
-                            if src is not None:
-                                ext_refs[child] = src
-                            else:
-                                # the background collector may have
-                                # resolved this window between the first
-                                # resolved_global probe and the
-                                # in-flight probe (it publishes hashes
-                                # BEFORE dropping the in-flight rows) —
-                                # re-check
-                                real = resolved_global.get(child)
-                                if real is not None:
-                                    out[pos : pos + 32] = real
-                                elif child in self._staged:
-                                    # neither this window's, nor
-                                    # resolved, nor in flight: a foreign
-                                    # session sharing the staged
-                                    # namespace — hashing would bake
-                                    # placeholder bytes into the node
-                                    raise AssertionError(
-                                        "seal(): unresolvable "
-                                        "placeholder ref (foreign "
-                                        "session sharing the staged "
-                                        "namespace?)"
-                                    )
-                    pos = out.find(_PLACEHOLDER_PREFIX, pos + 32)
-                to_resolve[ph] = bytes(out)
-                deps[ph] = children
-                depth_of[ph] = d
-                if d > max_depth:
-                    max_depth = d
+            pack = self._pack_sites(start, end)
+            to_resolve, ext_refs = pack.to_resolve, pack.ext_refs
+            max_depth = pack.max_depth
             pack_sp.set_tag("nodes", len(to_resolve))
             pack_sp.set_tag("depth", max_depth)
             pack_sp.set_tag("ext_refs", len(ext_refs))
+            pack_sp.set_tag("sites", sum(pack.met.values()))
+            for kind, n in pack.met.items():
+                pack_sp.set_tag(f"sites_{kind}", n)
+                SEAL_SITES[kind].inc(n)
         if LEDGER.enabled:
             # host-side classification event: how many encoding bytes
             # the pack step staged for dispatch (the cost model's node
@@ -473,17 +467,20 @@ class WindowCommitter:
                         job.live if self.mirror is not None else None
                     )
                     job.fused_job = fused_submit(
-                        to_resolve, deps, _PLACEHOLDER_PREFIX,
+                        to_resolve, {}, _PLACEHOLDER_PREFIX,
                         use_jnp=device.platform() != "tpu",
                         depth=max_depth,
                         ext=ext_arg,
+                        sites=pack.subs(ext_arg[1] if ext_arg else {}),
                         admit_live=admit_live,
                         held=self.fused_held,
                     )
                 fj = job.fused_job
                 if fj.dpos:
-                    for ph2, row in fj.dpos.items():
-                        inflight_rows[ph2] = (job, row)
+                    inflight_rows.update(zip(
+                        fj.dpos,
+                        zip(itertools.repeat(job), fj.dpos.values()),
+                    ))
                     # guard: a death between registration and _packed
                     # re-runs this block — never double-queue the job
                     if job not in self._inflight_jobs:
@@ -552,7 +549,7 @@ class WindowCommitter:
                 )
             mapping[child] = real
         with span("window.hash", nodes=len(to_resolve)):
-            for level in topo_levels(deps):
+            for level in topo_levels(pack.deps()):
                 encodings = [
                     _substitute_bytes(to_resolve[ph], mapping)
                     for ph in level
@@ -571,6 +568,114 @@ class WindowCommitter:
             )
         job._packed = True
 
+    def _pack_sites(self, start: int, end: int) -> "_Pack":
+        """Locate every placeholder site of the counter range
+        ``[start, end)`` ONCE (trie/deferred.py find_sites over the
+        joined staged encodings) and sort the sites by what their child
+        is. Memory-only and repeatable: a re-run after a death gives
+        the same values.
+
+        Placeholder counters are handed out at node creation and tries
+        build bottom-up, so a window's own child has a LOWER counter
+        than its parent: own children are an index range, found by
+        arithmetic, and only the other sites (refs into earlier
+        windows, opaque look-alikes) go to the dicts."""
+        staged = self._staged
+        phs = [_make_placeholder(i) for i in range(start, end)]
+        encs = list(map(staged.get, phs))
+        idx = np.arange(start, end, dtype=np.int64)
+        if None in encs:  # e.g. another session's counter range
+            have = [i for i, e in enumerate(encs) if e is not None]
+            phs = [phs[i] for i in have]
+            encs = [encs[i] for i in have]
+            idx = idx[have]
+        n = len(phs)
+        sites = find_sites(encs)
+        node, ctr = sites.node, sites.ctr
+        # own child: staged in this range, and below its parent.
+        # slot[c - start] is the node of counter c; the last slot is
+        # what every counter outside the range reads
+        slot = np.full(end - start + 1, -1, np.int64)
+        slot[idx - start] = np.arange(n)
+        k = slot[np.where((ctr >= start) & (ctr < end), ctr - start, -1)]
+        local = (k >= 0) & (k < node)
+        child = k[local]
+        parent = node[local]
+
+        # every other site goes to the dicts, once per DISTINCT child
+        # (a node an earlier window wrote is held by each version of
+        # its parent this window staged; a counter past 63 bits is
+        # told apart by nothing but its bytes, so each such site is a
+        # child of its own). The order is the collector's: it
+        # publishes a window's hashes BEFORE it drops its in-flight
+        # rows, so the in-flight probe is followed by a re-check
+        resolved_global = self._resolved_global
+        inflight_rows = self._inflight_rows
+        joined = sites.joined
+        other = np.flatnonzero(~local)
+        other_pos = sites.pos[other]
+        ids = ctr[other]
+        wide = np.flatnonzero(ids < 0)
+        ids[wide] = -1 - np.arange(wide.size)
+        _, first, which = np.unique(
+            ids, return_index=True, return_inverse=True)
+        keys = [joined[p : p + 32] for p in other_pos[first].tolist()]
+        reals = list(map(resolved_global.get, keys))
+        in_flight = np.zeros(len(keys), bool)
+        ext_refs: Dict[bytes, Tuple["WindowJob", int]] = {}
+        for j in [j for j, real in enumerate(reals) if real is None]:
+            key = keys[j]
+            src = inflight_rows.get(key)
+            if src is not None:
+                ext_refs[key] = src
+                in_flight[j] = True
+                continue
+            reals[j] = resolved_global.get(key)
+            if reals[j] is None and key in staged:
+                # neither this window's, nor resolved, nor in flight: a
+                # foreign session sharing the staged namespace —
+                # hashing would bake placeholder bytes into the node
+                raise AssertionError(
+                    "seal(): unresolvable placeholder ref (foreign "
+                    "session sharing the staged namespace?)"
+                )
+        resolved, digests = found_digests(reals)
+        hit = resolved[which]
+        to_resolve = dict(zip(phs, splice_sites(
+            sites, other_pos[hit],
+            digests[(np.cumsum(resolved) - 1)[which[hit]]],
+        )))
+        ext_at = np.flatnonzero(in_flight[which])
+
+        # depth: 1 + the deepest own child, in ONE ordered pass over
+        # the own-child sites: the scan left them in ascending parent
+        # order and a child is below its parent, so every child has its
+        # depth by the time its parent is reached. Plain ints in a
+        # Python loop, not numpy a level at a time: an array call lets
+        # go of the GIL, and beside busy threads this stage would queue
+        # for it once a level
+        depth = [1] * n
+        for p, c in zip(parent.tolist(), child.tolist()):
+            if depth[c] >= depth[p]:
+                depth[p] = depth[c] + 1
+        max_depth = max(depth, default=0)
+        n_hit, n_ext = int(hit.sum()), int(ext_at.size)
+        return _Pack(
+            to_resolve=to_resolve,
+            ext_refs=ext_refs,
+            max_depth=max_depth,
+            met={
+                "local": int(child.size),
+                "resolved": n_hit,
+                "ext": n_ext,
+                "opaque": int(other.size) - n_hit - n_ext,
+            },
+            node=np.concatenate([parent, node[other[ext_at]]]),
+            off=np.concatenate([sites.off[local], sites.off[other[ext_at]]]),
+            child=child,
+            ext_keys=[keys[j] for j in which[ext_at].tolist()],
+        )
+
     def _gather_ext(self, ext_refs) -> Tuple[object, Dict[bytes, int]]:
         """Build the resolved-input tile for ``fused_submit``: gather
         the referenced rows out of each in-flight job's device digest
@@ -579,8 +684,6 @@ class WindowCommitter:
         program only reads the tile rows AFTER its own queue position,
         by which time the source dispatch has finished — XLA's program
         order on one device is the synchronization."""
-        import numpy as np
-
         from khipu_tpu.trie.fused import gather_ext_tile
 
         groups: Dict[int, Tuple["WindowJob", List[bytes]]] = {}
@@ -706,7 +809,6 @@ class WindowCommitter:
         if fj.encs is None:
             fj.release_encs()
             return
-        import numpy as np
         import jax.numpy as jnp
 
         from khipu_tpu.ops.keccak_jnp import RATE

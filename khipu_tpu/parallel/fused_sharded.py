@@ -31,6 +31,7 @@ from khipu_tpu.trie.fused import (
     FusedUnsupported,
     MAX_DEPTH,
     _pow2,
+    _scan_sites,
     topo_levels,
 )
 
@@ -143,6 +144,15 @@ def fused_resolve_sharded(
         for r, ph in enumerate(classes[nb]):
             dpos[ph] = gpos(nb, r)
 
+    # every substitution, from the one site scan (trie/fused.py
+    # _scan_sites), grouped by the node that holds it
+    site_node, site_off, site_child = _scan_sites(to_resolve, prefix, {})
+    node_gpos = np.fromiter((dpos[ph] for ph in phs), np.int64, len(phs))
+    subs_of: Dict[bytes, List[Tuple[int, int]]] = {}
+    for i, off, cp in zip(site_node.tolist(), site_off.tolist(),
+                          node_gpos[site_child].tolist()):
+        subs_of.setdefault(phs[i], []).append((off, cp))
+
     enc_bufs: List[np.ndarray] = []
     sub_arrays: List[np.ndarray] = []
     sig: List[Tuple[int, int, int]] = []
@@ -163,13 +173,8 @@ def fused_resolve_sharded(
             buf[d, local, : len(enc)] = np.frombuffer(enc, dtype=np.uint8)
             buf[d, local, len(enc)] ^= 0x01
             buf[d, local, width - 1] ^= 0x80
-            pos = enc.find(prefix)
-            while pos >= 0:
-                child = enc[pos : pos + 32]
-                cp = dpos.get(child)
-                if cp is not None:
-                    per_dev_subs[d].append((local, pos, cp))
-                pos = enc.find(prefix, pos + 32)
+            for off, cp in subs_of.get(ph, ()):
+                per_dev_subs[d].append((local, off, cp))
         nsubs = _pow2(
             max(max((len(s) for s in per_dev_subs), default=0), 1),
             floor=256,

@@ -22,8 +22,12 @@ an inlined child.
 
 from __future__ import annotations
 
+import itertools
+import operator
 import os
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Sequence, Tuple
+
+import numpy as np
 
 from khipu_tpu.base.rlp import rlp_decode, rlp_encode
 from khipu_tpu.trie.bulk import Hasher, host_hasher
@@ -150,56 +154,126 @@ def _substitute_bytes(value: bytes, mapping: Dict[bytes, bytes]) -> bytes:
     return bytes(out)
 
 
-def _substitute_many(encs: List[bytes], lookup) -> List[bytes]:
-    """Batched :func:`_substitute_bytes` over many encodings: ONE numpy
-    scan of the joined buffer finds every placeholder-prefix occurrence
-    (18 vectorized byte-compare refinements) instead of a Python
-    ``find`` loop per node — the dominant host cost of the window
-    collect path. ``lookup(ph) -> real | None`` decides substitution;
-    an occurrence whose 32 bytes are not a known placeholder (opaque
-    data that collided with the prefix, or a foreign counter range) is
-    left untouched, exactly like the scalar path."""
-    import numpy as np
+class Sites(NamedTuple):
+    """Every placeholder-shaped site of a list of encodings, as
+    :func:`find_sites` met them: a 32-byte run that starts with the
+    prefix and lies inside one encoding."""
 
-    total = sum(map(len, encs))
-    if total < 32:
-        return [bytes(e) for e in encs]
+    joined: bytes  # the encodings end to end
+    # int64[n + 1]: encoding i is joined[starts[i]:starts[i + 1]]
+    starts: np.ndarray
+    node: np.ndarray  # int64[m]: index of the encoding that holds the site
+    off: np.ndarray  # int64[m]: the site's offset inside that encoding
+    # int64[m]: the counter after the prefix; -1 where it does not fit
+    # 63 bits (no session hands such a one out: the site's bytes decide)
+    ctr: np.ndarray
+
+    @property
+    def pos(self) -> np.ndarray:
+        """Each site's offset in ``joined``."""
+        return self.starts[self.node] + self.off
+
+
+def _runs(blob: bytes, dtype, offset: int) -> np.ndarray:
+    """``blob`` as one element of ``dtype`` per 32-byte run: element i
+    starts at byte ``i + offset``. Overlapping views of the one buffer,
+    nothing copied: indexing it gathers whole prefixes, counters or
+    refs in one call."""
+    return np.ndarray((len(blob) - 31,), dtype, blob, offset, (1,))
+
+
+def placeholder_counters(blob: bytes, at: np.ndarray, prefix_len: int
+                         ) -> np.ndarray:
+    """The big-endian counters of the 32-byte refs that start at ``at``
+    in ``blob``, as int64; -1 where one does not fit 63 bits."""
+    if not at.size:
+        return np.empty(0, np.int64)
+    low = _runs(blob, ">u8", 24)[at].astype(np.int64)  # wraps past 63
+    high = _runs(blob, f"S{24 - prefix_len}", prefix_len)[at]
+    return np.where((low >= 0) & (high == b""), low, -1)
+
+
+def find_sites(encs: Sequence[bytes], prefix: bytes = _PLACEHOLDER_PREFIX
+               ) -> Sites:
+    """ONE numpy scan of the joined encodings for every placeholder
+    site: a compare against the prefix's first byte, then one compare
+    of the whole prefix over the survivors. The scalar ``find`` loop's
+    semantics hold exactly: a match that would run past its encoding's
+    end is no site (in the joined buffer it could straddle two
+    encodings; a real placeholder never does, it was written as one
+    32-byte ref inside one node), and matches are taken left to right
+    with the next search starting 32 bytes on. What a site's 32 bytes
+    MEAN (this window's node, an earlier window's, opaque data that
+    collided with the prefix) is the caller's to decide."""
+    if not 0 < len(prefix) < 24:
+        raise ValueError("a placeholder prefix leaves 9 to 31 counter bytes")
+    n = len(encs)
+    starts = np.zeros(n + 1, np.int64)
+    np.cumsum(np.fromiter(map(len, encs), np.int64, n), out=starts[1:])
     joined = b"".join(encs)
-    buf = np.frombuffer(joined, dtype=np.uint8).copy()
-    pref = np.frombuffer(_PLACEHOLDER_PREFIX, dtype=np.uint8)
-    cand = np.flatnonzero(buf[: total - 31] == pref[0])
-    for k in range(1, len(pref)):
-        if not cand.size:
-            break
-        cand = cand[buf[cand + k] == pref[k]]
-    hits: List[int] = []
-    digs: List[bytes] = []
-    if cand.size:
-        # boundary guard: in the JOINED buffer a prefix match could
-        # straddle two adjacent encodings — a real placeholder never
-        # does (it was written as one 32-byte ref inside one node)
-        ends = np.cumsum(
-            np.fromiter(map(len, encs), np.int64, len(encs))
-        )
-        node_end = ends[np.searchsorted(ends, cand, side="right")]
-        for p, e in zip(cand.tolist(), node_end.tolist()):
-            if p + 32 > e:
-                continue
-            real = lookup(joined[p : p + 32])
-            if real is not None:
-                hits.append(p)
-                digs.append(real)
-    if hits:
-        pos = np.asarray(hits, np.int64)
-        rep = np.frombuffer(b"".join(digs), np.uint8)
-        buf[(pos[:, None] + np.arange(32)).reshape(-1)] = rep
-    blob = buf.tobytes()
-    out: List[bytes] = []
-    off = 0
-    for e in encs:
-        out.append(blob[off : off + len(e)])
-        off += len(e)
-    return out
+    if len(joined) < 32:
+        none = np.empty(0, np.int64)
+        return Sites(joined, starts, none, none, none)
+    cand = np.flatnonzero(_runs(joined, np.uint8, 0) == prefix[0])
+    cand = cand[_runs(joined, f"S{len(prefix)}", 0)[cand] == prefix]
+    node = np.searchsorted(starts, cand, side="right") - 1
+    inside = cand + 32 <= starts[node + 1]
+    cand, node = cand[inside], node[inside]
+    if cand.size > 1 and (np.diff(cand) < 32).any():
+        # overlapping matches (crafted data only: the second one's
+        # prefix lies in the first one's counter): left to right, a
+        # match inside the 32 bytes of one already taken is skipped
+        keep, free = [], 0
+        for i, p in enumerate(cand.tolist()):
+            if p >= free:
+                keep.append(i)
+                free = p + 32
+        cand, node = cand[keep], node[keep]
+    return Sites(
+        joined, starts, node, cand - starts[node],
+        placeholder_counters(joined, cand, len(prefix)),
+    )
+
+
+def splice_sites(sites: Sites, pos: np.ndarray, reals: np.ndarray
+                 ) -> List[bytes]:
+    """The encodings of ``sites`` with the 32 bytes at each ``pos``
+    (offsets in the joined buffer) replaced by the matching element of
+    ``reals`` (dtype ``V32``): one indexed assignment of whole refs,
+    then a slice per encoding."""
+    blob = sites.joined
+    if pos.size:
+        spliced = bytearray(blob)
+        _runs(spliced, "V32", 0)[pos] = reals
+        blob = bytes(spliced)
+    bounds = sites.starts.tolist()
+    return [blob[s:e] for s, e in zip(bounds, bounds[1:])]
+
+
+def found_digests(reals: Sequence) -> Tuple[np.ndarray, np.ndarray]:
+    """Which entries of ``reals`` (32-byte digests, or None where a
+    look-up found nothing) are digests, as a mask, and those digests as
+    one ``V32`` array: what :func:`splice_sites` takes."""
+    found = np.fromiter(
+        map(operator.is_not, reals, itertools.repeat(None)), bool,
+        len(reals),
+    )
+    return found, np.frombuffer(b"".join(filter(None, reals)), "V32")
+
+
+def _substitute_many(encs: List[bytes], lookup) -> List[bytes]:
+    """Batched :func:`_substitute_bytes` over many encodings, from
+    :func:`find_sites`' one scan instead of a Python ``find`` loop per
+    node. ``lookup(ph) -> real | None`` decides substitution; a site
+    whose 32 bytes are not a known placeholder (opaque data that
+    collided with the prefix, or a foreign counter range) is left
+    untouched, exactly like the scalar path."""
+    sites = find_sites(encs)
+    joined = sites.joined
+    pos = sites.pos
+    known, digests = found_digests(
+        [lookup(joined[p : p + 32]) for p in pos.tolist()])
+    return splice_sites(sites, pos[known], digests)
 
 
 def _substitute(structure, mapping: Dict[bytes, bytes]):

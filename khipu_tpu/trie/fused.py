@@ -688,6 +688,7 @@ def fused_submit(
     ext=None,
     admit_live=None,
     held: Optional[HeldBuckets] = None,
+    sites=None,
 ) -> FusedJob:
     """Pack + dispatch the fixpoint program that resolves placeholder ->
     real Keccak-256 hash for every entry of ``to_resolve`` (placeholder
@@ -715,6 +716,14 @@ def fused_submit(
 
     ``held``: the caller's :class:`HeldBuckets`, kept across its
     windows; a call without one starts a new record.
+
+    ``sites``: optional ``(node, off, child)`` arrays, one entry per
+    substitution: the 32 bytes at ``off`` of node ``node`` take the
+    digest of node ``child`` (both index ``to_resolve``'s order), or,
+    for ``child >= len(to_resolve)``, of row ``child -
+    len(to_resolve)`` of the ext tile. The window committer's pack has
+    scanned its encodings already and hands what it found; a call
+    without them scans here (:func:`_scan_sites`).
     """
     from khipu_tpu.chaos import fault_point
 
@@ -730,12 +739,50 @@ def fused_submit(
     ) as sp:
         return _fused_submit(
             to_resolve, deps, prefix, use_jnp, depth, ext, admit_live, sp,
-            held if held is not None else HeldBuckets(),
+            held if held is not None else HeldBuckets(), sites,
         )
 
 
+def _scan_sites(to_resolve, prefix, ext_pos):
+    """``fused_submit``'s ``sites`` for a caller that hands none: one
+    :func:`find_sites` scan, and own children matched by counter, all
+    sites at once against the keys' sorted counters. What that leaves
+    (a ref into the ext tile, a counter past 63 bits) is matched by its
+    bytes, as every site once was."""
+    from khipu_tpu.trie.deferred import find_sites, placeholder_counters
+
+    phs = list(to_resolve)
+    n = len(phs)
+    found = find_sites(list(to_resolve.values()), prefix)
+    # a key that is no 32-byte ref of this prefix is no site's child
+    refs = [i for i, ph in enumerate(phs)
+            if len(ph) == 32 and ph.startswith(prefix)]
+    key_ctr = np.full(n, -1, np.int64)
+    key_ctr[refs] = placeholder_counters(
+        b"".join(phs[i] for i in refs), np.arange(0, 32 * len(refs), 32),
+        len(prefix))
+    order = np.argsort(key_ctr, kind="stable")
+    ranked = key_ctr[order]
+    k = np.minimum(np.searchsorted(ranked, found.ctr), n - 1)
+    child = np.where(
+        (found.ctr >= 0) & (ranked[k] == found.ctr), order[k], -1)
+    rest = np.flatnonzero(child < 0)
+    if rest.size:
+        index = dict(zip(phs, range(n)))
+        joined = found.joined
+        for i, p in zip(rest.tolist(), found.pos[rest].tolist()):
+            key = joined[p : p + 32]
+            c = index.get(key)
+            if c is None and key in ext_pos:
+                c = n + ext_pos[key]
+            if c is not None:
+                child[i] = c
+    keep = child >= 0
+    return found.node[keep], found.off[keep], child[keep]
+
+
 def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
-                  admit_live, sp, held) -> FusedJob:
+                  admit_live, sp, held, sites) -> FusedJob:
     if not to_resolve:
         return FusedJob(None, [])
     if depth is None:
@@ -751,6 +798,10 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
     _build_t0 = time.perf_counter() if LEDGER.enabled else 0.0
     with _span("seal.dispatch_build", nodes=len(to_resolve)):
         phs = list(to_resolve)
+        n_nodes = len(phs)
+        enc_len = np.fromiter(
+            map(len, to_resolve.values()), np.int64, n_nodes)
+        node_nb = enc_len // RATE + 1
 
         # bucket rows by rate-block class; the class set is pinned to a
         # CANONICAL {1..4} (a state-trie node never exceeds 4 rate blocks:
@@ -758,22 +809,26 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         # windows whose organic class sets differ would otherwise each pay a
         # fresh multi-second XLA compile. Larger classes appear only for
         # exotic long-value tries and extend the signature organically.
-        classes: Dict[int, List[bytes]] = {c: [] for c in (1, 2, 3, 4)}
-        for ph in phs:
-            nb = len(to_resolve[ph]) // RATE + 1
-            classes.setdefault(nb, []).append(ph)
-        class_list = sorted(classes)
+        class_list = sorted({1, 2, 3, 4, *np.unique(node_nb).tolist()})
         if not use_jnp and class_list[-1] > MAX_PALLAS_BLOCKS:
             raise FusedUnsupported(
                 f"rate class {class_list[-1]} exceeds the Pallas bound"
             )
 
-        # global digest index = class-major position (class order, row order)
+        # global digest index = class-major position (class order, row
+        # order); a node's row in its class is its rank among the
+        # class's nodes in to_resolve's order
+        members: Dict[int, np.ndarray] = {}
+        classes: Dict[int, List[bytes]] = {}
+        node_row = np.empty(n_nodes, np.int64)
+        node_gpos = np.empty(n_nodes, np.int64)
         dpos: Dict[bytes, int] = {}
         base = 0
         nrows_pad: Dict[int, int] = {}
         for nb in class_list:
-            rows = classes[nb]
+            of_class = np.flatnonzero(node_nb == nb)
+            members[nb] = of_class
+            rows = classes[nb] = [phs[i] for i in of_class.tolist()]
             # +1 guarantees at least one spare padding row for dummy subs;
             # pallas needs whole 1024-row tiles, the jnp path only pow-2
             n = len(rows) + 1
@@ -781,8 +836,9 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 (nb, "rows"),
                 _pow2(n, floor=16) if use_jnp
                 else _pallas_target_count(nb, n))
-            for r, ph in enumerate(rows):
-                dpos[ph] = base + r
+            node_row[of_class] = np.arange(len(rows))
+            node_gpos[of_class] = base + node_row[of_class]
+            dpos.update(zip(rows, range(base, base + len(rows))))
             base += nrows_pad[nb]
 
         total_rows = base  # ext tiles are indexed past this window's rows
@@ -790,6 +846,20 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
         ext_dev = None
         if ext is not None:
             ext_dev, ext_pos = ext
+
+        # every substitution of the window as (node, off, child), then
+        # each class's (row, off, child_gpos) by index mapping: no
+        # per-site look-up. A child past the nodes is an ext tile row
+        if sites is None:
+            sites = _scan_sites(to_resolve, prefix, ext_pos)
+        site_node, site_off, site_child = sites
+        site_nb = node_nb[site_node]
+        site_row = node_row[site_node]
+        own = site_child < n_nodes
+        site_gpos = np.where(
+            own, node_gpos[np.where(own, site_child, 0)],
+            total_rows + site_child - n_nodes,
+        )
 
         # mirror-admit fold: per class, the row indices of live nodes
         # padded out to whole 1024-row mirror tiles (the dummy points
@@ -813,31 +883,17 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             # ONE joined buffer + frombuffer instead of a numpy row-
             # assignment per node (the row loop was the dominant host cost
             # of seal); the multi-rate pad bits apply as two vector xors
-            zero = bytes(width)
-            parts: List[bytes] = []
-            lens = np.empty(npad, dtype=np.int64)
-            subs: List[Tuple[int, int, int]] = []  # (row, off, child_gpos)
-            for r, ph in enumerate(rows):
-                enc = to_resolve[ph]
-                parts.append(enc)
-                parts.append(zero[: width - len(enc)])
-                lens[r] = len(enc)
-                pos = enc.find(prefix)
-                while pos >= 0:
-                    child = enc[pos : pos + 32]
-                    cp = dpos.get(child)
-                    if cp is None and ext_pos:
-                        ep = ext_pos.get(child)
-                        if ep is not None:
-                            cp = total_rows + ep  # resolved-input tile row
-                    if cp is not None:
-                        subs.append((r, pos, cp))
-                    pos = enc.find(prefix, pos + 32)
+            parts = [to_resolve[ph].ljust(width, b"\0") for ph in rows]
+            lens = np.zeros(npad, dtype=np.int64)
+            lens[: len(rows)] = enc_len[members[nb]]
+            in_class = site_nb == nb
+            subs = np.stack(
+                [site_row[in_class], site_off[in_class],
+                 site_gpos[in_class]], axis=1)  # (row, off, child_gpos)
             # padding rows still need valid keccak padding (their digests
-            # are discarded, but the kernel hashes them)
-            lens[len(rows):] = 0
+            # are discarded, but the kernel hashes them): lens 0
             if npad > len(rows):
-                parts.append(zero * (npad - len(rows)))
+                parts.append(bytes(width * (npad - len(rows))))
             buf = (
                 np.frombuffer(b"".join(parts), dtype=np.uint8)
                 .reshape(npad, width)
@@ -852,8 +908,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 len(subs) + 1, floor=1024 if use_jnp else 4096))
             dummy_row = nrows_pad[nb] - 1  # guaranteed padding row
             sub_np = np.full((nsubs, 3), (dummy_row, 0, 0), dtype=np.int32)
-            if subs:
-                sub_np[: len(subs)] = subs
+            sub_np[: len(subs)] = subs
             live_subs += len(subs)
             enc_bufs.append(buf)
             sub_arrays.extend(
@@ -863,15 +918,13 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                     np.ascontiguousarray(sub_np[:, 2]),
                 ]
             )
-            aidx_list: List[int] = []
-            akeys: List = []
-            alens: List[int] = []
+            n_live = 0
             if admit_live:
-                for r, ph in enumerate(rows):
-                    if ph in admit_live:
-                        aidx_list.append(r)
-                        akeys.append(ph)
-                        alens.append(len(to_resolve[ph]))
+                aidx = np.flatnonzero(np.fromiter(
+                    map(admit_live.__contains__, rows), bool, len(rows)))
+                n_live = aidx.size
+                akeys: List = [rows[r] for r in aidx.tolist()]
+                alens: List[int] = enc_len[members[nb]][aidx].tolist()
                 # as many admit slots as the class has rows, in whole
                 # mirror tiles: the live rows fill the front, the
                 # mirror takes the tiles that hold one (admit_device),
@@ -879,9 +932,9 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 # of the signature
                 nadmit = -(-nrows_pad[nb] // _MTILE) * _MTILE
                 aidx_np = np.full(nadmit, dummy_row, dtype=np.int32)
-                aidx_np[: len(aidx_list)] = aidx_list
-                akeys.extend([None] * (nadmit - len(aidx_list)))
-                alens.extend([0] * (nadmit - len(aidx_list)))
+                aidx_np[:n_live] = aidx
+                akeys.extend([None] * (nadmit - n_live))
+                alens.extend([0] * (nadmit - n_live))
                 admit_bufs.append(aidx_np)
                 admit_meta.append((akeys, alens))
             else:
@@ -889,7 +942,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 admit_bufs.append(np.zeros(0, dtype=np.int32))
                 admit_meta.append(None)
             sig.append((nb, nrows_pad[nb], nsubs, nadmit))
-            live.append(f"{nb}x{len(rows)}/{len(subs)}+a{len(aidx_list)}")
+            live.append(f"{nb}x{len(rows)}/{len(subs)}+a{n_live}")
 
         # resolved-input tile: always an input (a dummy zero tile when the
         # window has no cross-refs) so every window shares one compiled
