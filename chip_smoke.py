@@ -289,11 +289,53 @@ def smoke_config(data_dir: str, adaptive_commit: bool,
     )
 
 
+# ERC-20 transfer(to, amount) with REAL keccak mapping slots: balances
+# live at keccak(pad32(holder) ++ pad32(0)) — sender slot debits by the
+# amount word, recipient slot credits. Calldata is the raw two words
+# (no ABI selector), so arg0 = recipient, arg1 = amount. Straight-line
+# and fully whitelisted for the purity scan (const memory offsets, const
+# SHA3 size), which is what lets the learner derive ("map_caller", 0) /
+# ("map_arg", 0, 0) write rules and trust the code after confirmation.
+_ERC20_RUNTIME = bytes([
+    0x33,                    # CALLER
+    0x60, 0x00, 0x52,        # PUSH1 0  MSTORE   mem[0:32] = caller
+    0x60, 0x00,              # PUSH1 0  (mapping base slot)
+    0x60, 0x20, 0x52,        # PUSH1 32 MSTORE   mem[32:64] = 0
+    0x60, 0x40, 0x60, 0x00,  # PUSH1 64 PUSH1 0
+    0x20,                    # SHA3              sender slot
+    0x80, 0x54,              # DUP1 SLOAD        sender balance
+    0x60, 0x20, 0x35,        # PUSH1 32 CALLDATALOAD   amount
+    0x90, 0x03,              # SWAP1 SUB         bal - amount
+    0x90, 0x55,              # SWAP1 SSTORE      debit sender
+    0x60, 0x00, 0x35,        # PUSH1 0 CALLDATALOAD    recipient
+    0x60, 0x00, 0x52,        # PUSH1 0  MSTORE   mem[0:32] = recipient
+    0x60, 0x40, 0x60, 0x00,  # PUSH1 64 PUSH1 0  (mem[32:64] still 0)
+    0x20,                    # SHA3              recipient slot
+    0x80, 0x54,              # DUP1 SLOAD        recipient balance
+    0x60, 0x20, 0x35,        # PUSH1 32 CALLDATALOAD   amount
+    0x01,                    # ADD               bal + amount
+    0x90, 0x55,              # SWAP1 SSTORE      credit recipient
+    0x00,                    # STOP
+])
+
+# the runtime is wider than one word, so the constructor CODECOPYs it
+# out of the init code
+_ERC20_INIT = bytes([
+    0x60, len(_ERC20_RUNTIME),  # PUSH1 len
+    0x60, 0x0C,                 # PUSH1 12 (runtime offset in init code)
+    0x60, 0x00,                 # PUSH1 0
+    0x39,                       # CODECOPY
+    0x60, len(_ERC20_RUNTIME),  # PUSH1 len
+    0x60, 0x00,                 # PUSH1 0
+    0xF3,                       # RETURN
+]) + _ERC20_RUNTIME
+
+
 def make_alloc(accounts: int, senders: int):
     """Genesis alloc: ``senders`` funded key-holders plus plain
     accounts up to ``accounts``. Returns (keys, sender_addrs, others,
     alloc)."""
-    from bench import _replay_keys
+    from scenarios import _replay_keys
 
     keys, addrs = _replay_keys(senders, seed_base=2101)
     others = [
@@ -347,12 +389,11 @@ def leg_state(alloc: dict, data_dir: str, cfg) -> dict:
 def build_chain(spec, keys, senders, others, blocks: int,
                 txs_per_block: int, seed: int):
     """BASELINE config #4's shape on the host: block 1 deploys the
-    ERC-20 fixture (bench.py) beside plain transfers; every later block
+    ERC-20 fixture (``_ERC20_INIT``) beside plain transfers; every later block
     is half ERC-20 ``transfer`` calls, half plain transfers, one tx per
     sender, receivers drawn across the whole alloc. Built by
     ChainBuilder with the host hasher — the roots the replay must hit.
     Returns (builder_chain, blocks, token, touched addresses)."""
-    from bench import _ERC20_INIT
     from khipu_tpu.config import fixture_config
     from khipu_tpu.domain.block import Block
     from khipu_tpu.domain.blockchain import Blockchain
@@ -618,7 +659,7 @@ def leg_serve(node: Node, gauges: dict, seed: int = 5) -> dict:
         if got != (acc.balance if acc else 0):
             raise AssertionError(f"eth_getBalance({addr.hex()}) differs")
 
-    # the fixture token (bench.py _ERC20_RUNTIME) is transfer-only — it
+    # the fixture token (_ERC20_RUNTIME) is transfer-only — it
     # has no balanceOf getter — so a holder's balance is read where
     # balanceOf would read it: mapping slot keccak(pad32(holder) ++ 0)
     world = chain.get_world_state(head.state_root)

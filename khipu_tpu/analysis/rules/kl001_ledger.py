@@ -1,11 +1,11 @@
 """KL001 — unledgered host<->device crossings.
 
-PR-7's TransferLedger is the bytes-budget instrument: the
-``bench.py --compare`` gate and the per-window movement report are only
+PR-7's TransferLedger is the bytes-budget instrument: the benchmark's
+transfer metrics and the per-window movement report are only
 honest if EVERY ``jax.device_get`` / ``jax.device_put`` /
 ``.block_until_ready()`` site is metered. A crossing added outside the
 ledger silently disappears from ``khipu_device_transfer_*`` and the
-gate's bytes/block ratio — the budget then lies exactly when it is
+bytes/block figures — the budget then lies exactly when it is
 supposed to catch a regression.
 
 A crossing counts as metered when it is lexically inside a

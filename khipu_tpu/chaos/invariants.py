@@ -4,7 +4,7 @@ The reusable half of the gameday harness: every checker takes live
 objects (blockchains, replicas, the fleet router, a rebalancer) plus
 the run's observations and returns an ``InvariantResult`` — named,
 machine-checkable, and identical whether it gates the headline
-``bench.py --gameday`` run, one cell of the pairwise hazard matrix
+``scenarios.py gameday`` run, one cell of the pairwise hazard matrix
 (tests/test_gameday.py), or an ad-hoc chaos experiment.
 
 The invariant set is the paper's operational contract under
@@ -63,7 +63,7 @@ class InvariantResult:
 
 class InvariantReport:
     """Collects results; ``ok`` only when every check passed. ``raise_
-    if_failed`` is the gate half (bench exits non-zero), ``failures``
+    if_failed`` is the gate half (the scenario exits non-zero), ``failures``
     the test half (assert not report.failures)."""
 
     def __init__(self):

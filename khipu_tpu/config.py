@@ -119,15 +119,6 @@ class SyncConfig:
     sender_prefetch: bool = True
     sender_prefetch_depth: int = 8  # blocks buffered ahead of driver
     sender_cache_entries: int = 65536  # LRU cap (~100 B/entry)
-    # batch the per-tx signing-hash keccaks through ops.keccak when a
-    # TPU backend is up (one device call per block instead of N host
-    # hashes); on the CPU backend this knob is a no-op. OFF by default:
-    # on an attached v5e one 200-tx block's pre-images take 4.0 ms
-    # through the Pallas path (pad, upload a 1024-row tile, dispatch,
-    # fetch) against 0.53 ms on the host's native keccak (my chip run,
-    # PR 21, medians of 30) — a loss at the widest blocks the replay
-    # sees. Still available; ROADMAP Queue 3 item 4 keeps the question
-    sender_batch_hash: bool = False
     # fast-sync pivot choice (FastSyncService.scala:184-273 role)
     min_peers_to_choose_pivot: int = 5
     pivot_block_offset: int = 500  # pivot = median(best) - offset
@@ -177,15 +168,6 @@ class SyncConfig:
     # EWMA flip if the windows prove it wrong)
     adaptive_probe: bool = True
     adaptive_d2d_margin: float = 1.5
-    # execute-stage device dispatch (ISSUE 17): ship the gathered
-    # account-row tiles of a window's fast-path batches through the
-    # fused device validation kernel (trie/fused.py, exec.batch_device
-    # ledger site). Opt-in CAP like device_mirror_commit — even when
-    # True the dispatch engages only where the adaptive probe shows
-    # real device memory (d2d beats memcpy by adaptive_d2d_margin);
-    # the host numpy pass stays the default and the bit-exactness
-    # oracle either way
-    exec_device: bool = False
     # EWMA smoothing over per-window per-hash seal cost observations
     adaptive_ewma_alpha: float = 0.4
     # Schmitt trigger: flip device -> host when the device EWMA
@@ -229,8 +211,8 @@ class ObservabilityConfig:
     # fixpoint programs retained before LRU eviction; evictions/misses
     # are counted in the compile-event log
     compile_cache_capacity: int = 64
-    # when set, bench --trace / ServiceBoard dump Chrome trace_event
-    # JSON (perfetto-loadable) here on demand
+    # a path for Chrome trace_event JSON dumps; nothing reads it
+    # (ROADMAP Queue 3 item 14)
     chrome_trace_path: Optional[str] = None
     # per-transaction lineage plane (observability/journey.py — the
     # "tx passport"): bounded per-tx lifecycle event records keyed by

@@ -12,7 +12,7 @@
 * ``place_compile_cache()`` — where XLA's persistent compilation cache
   lives. One fused window signature costs ~30 s cold on a v5e, so every
   entry point that will touch JAX (``python -m khipu_tpu``,
-  ``ServiceBoard``, ``bench.py``, ``chip_smoke.py``,
+  ``ServiceBoard``, ``scenarios.py``, ``chip_smoke.py``,
   ``__graft_entry__.py``) calls this before its first compile.
 """
 

@@ -64,7 +64,6 @@ from khipu_tpu.observability.journey import JOURNEY
 
 def execute_call_batch(
     config, world, items: Sequence[Tuple[int, object, bytes, bytes, object]],
-    device_validate=None,
 ) -> List["TxResult"]:
     """Execute one disjoint batch of trusted templated calls against
     ``world`` (the block's merged world — mutated in place). ``items``
@@ -118,9 +117,8 @@ def execute_call_batch(
         rows.append((index, stx, sender, tx.gas_limit * tx.gas_price))
         staged.append((gas_used, writes))
 
-    # ---- validate: one vectorized nonce/balance pass (host numpy or,
-    # behind the adaptive probe, the fused device kernel)
-    gather_validate_rows(world, rows, device_validate=device_validate)
+    # ---- validate: one vectorized nonce/balance pass
+    gather_validate_rows(world, rows)
 
     # ---- scatter: per-row commutative deltas + net storage writes
     # (exact interpreter net effect: nonce+1, sender -gas_used*price,

@@ -9,8 +9,7 @@ rows instead of N trips through the interpreter:
   stay RECORDED reads, same as the interpreter's validation probe);
 * validate — one vectorized numpy pass: nonce equality plus a 256-bit
   limb-lexicographic balance >= upfront compare across the whole batch
-  (uint64×4 big-endian limbs — the same row shape the fused device
-  dispatch uses, so this host path can be absorbed by it later);
+  (uint64×4 big-endian limbs);
 * scatter  — per-row deltas applied through the world's commutative
   API (increase_nonce / add_balance), preserving the exact write-log,
   delta, and creation-mark bookkeeping the serial interpreter produces.
@@ -100,17 +99,13 @@ def check_tx_scalars(config, index: int, stx, intrinsic: int) -> None:
         )
 
 
-def gather_validate_rows(world, rows, device_validate=None) -> None:
+def gather_validate_rows(world, rows) -> None:
     """Gather every sender's nonce/balance row out of ``world``
     (recorded reads, same as the interpreter's validation probe) and
     validate the whole batch in one vectorized pass: nonce equality
     plus the 256-bit limb-lexicographic balance >= upfront compare.
 
-    ``rows`` is [(tx_index, stx, sender, upfront), ...]. When
-    ``device_validate`` is given (the trie/fused.py exec-validate
-    kernel, gated by the adaptive probe), the compare runs on device;
-    it may raise FusedUnsupported to decline, and the host numpy pass
-    is the authoritative fallback either way.
+    ``rows`` is [(tx_index, stx, sender, upfront), ...].
     """
     from khipu_tpu.ledger.ledger import TxValidationError
 
@@ -130,20 +125,11 @@ def gather_validate_rows(world, rows, device_validate=None) -> None:
         balances.append(balance)
         upfronts.append(upfront)
 
-    ok = None
-    if device_validate is not None:
-        try:
-            ok = np.asarray(device_validate(
-                tx_nonces, acct_nonces, balances, upfronts
-            ), dtype=bool)
-        except Exception:
-            ok = None  # device declined — host path is authoritative
-    if ok is None:
-        nonce_ok = np.array(tx_nonces, dtype=np.uint64) == np.array(
-            acct_nonces, dtype=np.uint64
-        )
-        balance_ok = _ge_limbs(_limbs(balances), _limbs(upfronts))
-        ok = nonce_ok & balance_ok
+    nonce_ok = np.array(tx_nonces, dtype=np.uint64) == np.array(
+        acct_nonces, dtype=np.uint64
+    )
+    balance_ok = _ge_limbs(_limbs(balances), _limbs(upfronts))
+    ok = nonce_ok & balance_ok
     if not bool(ok.all()):
         i = int(np.argmin(ok))
         index, stx, _, _ = rows[i]
@@ -160,7 +146,6 @@ def gather_validate_rows(world, rows, device_validate=None) -> None:
 
 def execute_fast_batch(
     config, world, items: Sequence[Tuple[int, object, bytes]],
-    device_validate=None,
 ) -> List["TxResult"]:
     """Execute one disjoint batch of plain transfers against ``world``
     (the block's merged world — mutated in place). ``items`` is
@@ -185,7 +170,7 @@ def execute_fast_batch(
         (index, stx, sender,
          stx.tx.gas_limit * stx.tx.gas_price + stx.tx.value)
         for index, stx, sender in items
-    ], device_validate=device_validate)
+    ])
 
     # ---- scatter: per-row commutative deltas (exact interpreter net
     # effect: nonce+1, sender -(value + gas*price), recipient +value)

@@ -410,7 +410,7 @@ def execute_block(
                         _execute_scheduled(
                             config, block_env, txs, senders,
                             parent_state_root, make_world, header,
-                            stats, khipu_config.sync,
+                            stats,
                         )
                     )
                     if trusted_used and validate:
@@ -526,7 +526,7 @@ def _execute_sequential(
 
 def _execute_scheduled(
     config, block_env, txs, senders, parent_root, make_world, header,
-    stats: Stats, sync_cfg=None,
+    stats: Stats,
 ):
     """Conflict-aware scheduled execution (schedule.plan_block) on ONE
     merged world — zero merge conflicts by construction.
@@ -574,19 +574,6 @@ def _execute_scheduled(
     )
     stats.conflict_count += plan.conflicted
     trusted_used: Set[bytes] = set()
-
-    # fused device validation for the gathered row tiles — only when
-    # the sync config opts in AND the PR 13 adaptive probe agrees the
-    # device round-trip pays for itself (host numpy is the default and
-    # the authoritative fallback either way)
-    device_validate = None
-    if sync_cfg is not None and getattr(sync_cfg, "exec_device", False):
-        from khipu_tpu.sync.adaptive import exec_device_allowed
-
-        if exec_device_allowed(sync_cfg):
-            from khipu_tpu.trie.fused import fused_exec_validate
-
-            device_validate = fused_exec_validate
 
     receipts: List[Receipt] = []
     outcomes: List[Optional[TxResult]] = [None] * len(txs)
@@ -652,7 +639,7 @@ def _execute_scheduled(
             _t0 = time.perf_counter()
             captured = run_captured(i, accumulated_gas)
             # host-side classification event: per-tx interpreter time,
-            # so bench --diff attributes execute-phase movement to the
+            # so the cost model attributes execute-phase time to the
             # residue vs the vectorized batches
             LEDGER.record(
                 "exec.residue", HOST, 0,
@@ -750,10 +737,7 @@ def _execute_scheduled(
                 fast_items.append((i, txs[i], senders[i]))
         if call_items:
             _t0 = time.perf_counter()
-            results = execute_call_batch(
-                config, merged, call_items,
-                device_validate=device_validate,
-            )
+            results = execute_call_batch(config, merged, call_items)
             # vectorized templated-call time joins the transfer batch
             # in the exec.batch cost bucket
             LEDGER.record(
@@ -768,10 +752,7 @@ def _execute_scheduled(
             EXEC_GAUGES["vector_call_txs"] += len(call_items)
         if fast_items:
             _t0 = time.perf_counter()
-            results = execute_fast_batch(
-                config, merged, fast_items,
-                device_validate=device_validate,
-            )
+            results = execute_fast_batch(config, merged, fast_items)
             # host-side classification event: vectorized fast-path
             # time per batch (joins with exec.residue for the execute
             # cost-model breakdown)

@@ -790,7 +790,7 @@ class WindowCommitter:
         # fast path: the dispatch itself already gathered the live
         # rows (trie/fused.py admit_live) — the tiles land straight in
         # the mirror with zero extra device round-trips. The span
-        # keeps the seal.alias_gather name so bench --diff attributes
+        # keeps the seal.alias_gather name so the cost model bills
         # the eliminated gather to the same site
         tiles = fj.admit_tiles
         if tiles is not None:

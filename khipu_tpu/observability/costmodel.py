@@ -1,7 +1,7 @@
 """Per-window roofline cost model: attainable vs achieved per seal
 sub-phase.
 
-BENCH_r06 billed 34.9 s/window to one opaque ``seal`` span. The
+A round-6 CPU capture billed 34.9 s/window to one opaque ``seal`` span. The
 sub-phase instrumentation (seal.pack / seal.alias_gather /
 seal.dispatch_build / seal.upload / seal.rootcheck / seal.journal)
 splits that wall into named steps; this module answers the NEXT

@@ -418,8 +418,8 @@ JOURNEY = JourneyBoard()
 
 def apply_config(cfg) -> None:
     """Wire an ObservabilityConfig's journey_* knobs. Idempotent; an
-    explicit disabled config does NOT stomp a manual enable() (bench
-    flips the board on over a default config)."""
+    explicit disabled config does NOT stomp a manual enable() (the
+    scenarios flip the board on over a default config)."""
     if cfg is None:
         return
     if getattr(cfg, "journey_enabled", False) and not JOURNEY.enabled:

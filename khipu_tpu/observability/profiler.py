@@ -88,12 +88,9 @@ KNOWN_SITES = frozenset({
     "seal.pack", "seal.alias_gather", "seal.dispatch_build",
     "seal.upload", "seal.rootcheck", "seal.journal",
     # execute-stage sites (ISSUE 14 conflict-aware scheduler): the
-    # vectorized fast-path batches vs the per-tx EVM residue, so
-    # ``bench --diff`` attributes execute-phase movement by site;
-    # exec.batch_device is the fused device validation of gathered
-    # account-row tiles (trie/fused.py, behind sync.exec_device + the
-    # adaptive probe)
-    "exec.batch", "exec.residue", "exec.batch_device",
+    # vectorized fast-path batches vs the per-tx EVM residue, so the
+    # cost model attributes execute-phase time by site
+    "exec.batch", "exec.residue",
     # sharded multi-device paths (parallel/)
     "shard.dispatch", "shard.gather", "shard.keccak", "shard.verify",
     # raw keccak ops (ops/)
@@ -107,7 +104,7 @@ KNOWN_SITES = frozenset({
     # decision — chaos seams first (the kill sweep in test_fleet.py
     # drives them), ledger sites if the tail ever meters bulk bytes
     "replica.tail", "fleet.route",
-    # bench/metrics self-checks
+    # scenarios.py's metrics self-check
     "bench.smoke",
 })
 
@@ -515,7 +512,7 @@ LEDGER = TransferLedger()
 def apply_config(cfg) -> None:
     """Wire ObservabilityConfig.ledger_enabled/ledger_capacity.
     Idempotent; an explicit disabled config does not stomp a manual
-    enable (bench --trace flips the ledger on over a default config)."""
+    enable (a caller may flip the ledger on over a default config)."""
     if cfg is None:
         return
     if getattr(cfg, "ledger_enabled", False) and not LEDGER.enabled:

@@ -189,7 +189,7 @@ def phase_percentiles(spans: Sequence[Span]) -> Dict[str, dict]:
 
 def phase_breakdown(spans: Sequence[Span]) -> Dict[str, float]:
     """Top-level wall seconds per canonical phase (driver + collector),
-    the split ``bench.py --trace`` prints next to blocks/s. Only
+    the split a recorded replay is read by. Only
     canonical-phase spans count — nested sub-spans (fused.dispatch
     inside window.seal, etc.) would double-bill their parents."""
     out: Dict[str, float] = {}

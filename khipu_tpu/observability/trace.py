@@ -448,7 +448,7 @@ def apply_config(cfg, tracer_: Optional[Tracer] = None) -> None:
         t.enable(cfg.ring_capacity)
     elif not cfg.enabled and t.enabled:
         # an explicit disabled config does NOT stomp a manual enable()
-        # (bench --trace flips the tracer on over a default config)
+        # (a caller may flip the tracer on over a default config)
         pass
     try:
         from khipu_tpu.trie.fused import compile_cache

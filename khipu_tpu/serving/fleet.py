@@ -7,7 +7,7 @@ and consistent-read tokens (serving/router.py) make read-your-writes
 hold across replica failover AND across a PR 15 reorg. The router is
 transport-agnostic: ``handle(request)`` speaks the same dict protocol
 ``JsonRpcServer.handle`` does, and ``start_http`` mounts it on the
-real keep-alive HTTP front so ``bench.py --serve --http`` drives the
+real keep-alive HTTP front so ``scenarios.py serve-http`` drives the
 whole path over sockets.
 
 Consistency plumbing that is easy to miss:

@@ -133,6 +133,12 @@ class ReadView:
         bc = self.blockchain
         best = bc.best_block_number
         header = bc.get_header_by_number(best)
+        while header is None and bc.best_block_number != best:
+            # a reorg's rollback took the tip between the two reads; it
+            # lowers best BEFORE it removes a block (sync/reorg.py
+            # _rollback), so the best read now names a header that stays
+            best = bc.best_block_number
+            header = bc.get_header_by_number(best)
         return best, bc.get_account(addr, header.state_root)
 
     def snapshot(self) -> dict:

@@ -15,8 +15,8 @@ full Keccak path and the kernel bound):
   * :meth:`verify` — re-hash every resident node and compare against
     its claimed hash (the fast-sync snapshot verification, BASELINE
     config #5) in ONE dispatch per size class;
-  * the #2 primary microbench (bench.py) — sustained content-address
-    hashing over the resident tiles.
+  * sustained content-address hashing over the resident tiles
+    (BASELINE config #2; ``chip_smoke.py``'s kernel leg).
 
 The layout cost is paid once at ADMIT (write) time on the host, which
 is the store-ingest side where the reference also pays its layout

@@ -442,7 +442,7 @@ class KesqueStore:
     @property
     def read_amplification(self) -> float:
         """Disk bytes fetched per value byte served — the serving-load
-        number ``bench --ingest`` reports (frame headers + record tags
+        number ``scenarios.py ingest`` reports (frame headers + record tags
         are the only overhead of a positional Kesque read)."""
         if self.value_bytes_returned == 0:
             return 0.0

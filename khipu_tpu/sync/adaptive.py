@@ -3,7 +3,7 @@ actually fast at (docs/roofline.md "The adaptive commit rule").
 
 The device-resident commit (storage/device_mirror.py) is a bet: that
 d2d gathers and the fused fixpoint beat the host memcpy + scalar keccak
-they replaced. On the CPU backend the bet loses ~20x (BENCH_r07) —
+they replaced. On the CPU backend the bet loses ~20x (a round-7 CPU capture) —
 there "device" memory IS host RAM, so every d2d gather is a memcpy with
 dispatch overhead on top, and the fused fixpoint re-hashes
 ``rounds x padded_rows`` where the host path hashes each node once.
@@ -177,21 +177,6 @@ def probe_backend(margin: float = 1.5) -> ProbeResult:
         result.memcpy_bytes_per_s
     )
     return result
-
-
-def exec_device_allowed(sync_cfg) -> bool:
-    """Gate for the execute-stage device dispatch (ledger/batch_*.py
-    -> trie/fused.fused_exec_validate): the sync config must opt in
-    (``exec_device``) AND the one-shot backend probe must show real
-    device memory — d2d beating host memcpy by the same margin the
-    adaptive commit controller demands. Where device memory is host
-    RAM (CPU jax), shipping row tiles out just adds dispatch overhead
-    to a numpy pass, so the probe keeps the host path authoritative."""
-    if not getattr(sync_cfg, "exec_device", False):
-        return False
-    if not getattr(sync_cfg, "adaptive_probe", True):
-        return True  # explicit cap with probing disabled: honor it
-    return probe_backend(sync_cfg.adaptive_d2d_margin).device_ok
 
 
 def _calibrate_host_hash_s(samples: int = 256) -> float:

@@ -597,8 +597,12 @@ def _settle_reorg(blockchain, rec: ReorgRecord, journal, report,
     top = max(rec.old_top, rec.new_top,
               s.app_state.best_block_number,
               max(0, s.best_block_number))
-    removed = _remove_above(blockchain, anc, top)
+    # best drops BEFORE any removal, as in sync/reorg.py _rollback: a
+    # reader resolves state through the best header, and the
+    # ancestor's is the one that stays (nothing below reads best again
+    # before it is set, and a kill here is the torn switch re-entered)
     s.app_state.best_block_number = anc
+    removed = _remove_above(blockchain, anc, top)
     report.blocks_removed += removed
 
     blocks = journal.staged_blocks(rec)

@@ -2,7 +2,7 @@
 
 ``recover_senders`` is a pure function of tx bytes (signing preimage +
 v/r/s), so nothing forces it onto the block's critical path — yet the
-driver paid it per block, and BENCH_r08 measured it at 0.444 of
+driver paid it per block, and a round-8 CPU capture measured it at 0.444 of
 foreground window time (native ECDSA recovery is ~230 us/signature;
 it dwarfs everything else in the phase). Two independent fixes:
 
@@ -80,19 +80,7 @@ def _signing_preimage(stx, chain_id: Optional[int]) -> bytes:
     return rlp_encode(fields)
 
 
-def _batch_hash_wanted(flag: bool) -> bool:
-    """Device-batched signing hashes only pay where the device wins:
-    host keccak is native C, so the CPU backend always hashes scalar."""
-    if not flag:
-        return False
-    from khipu_tpu import device
-
-    return device.platform() == "tpu"
-
-
-def recover_block_senders(
-    stxs, cache_entries: int = 65536, batch_hash: bool = False,
-) -> None:
+def recover_block_senders(stxs, cache_entries: int = 65536) -> None:
     """recover_senders with the process-wide cache in front: fill the
     per-object ``sender`` memo for every tx of a block, paying native
     recovery only for cache misses (one batched native call)."""
@@ -117,12 +105,7 @@ def recover_block_senders(
             todo.append((stx, key, recid))
             misses += 1
     if todo:
-        if _batch_hash_wanted(batch_hash):
-            from khipu_tpu.ops.keccak import keccak256_batch
-
-            hashes = keccak256_batch([key[0] for _, key, _ in todo])
-        else:
-            hashes = [keccak256(key[0]) for _, key, _ in todo]
+        hashes = [keccak256(key[0]) for _, key, _ in todo]
         pubs = ecdsa_recover_batch([
             (h, recid, stx.r, stx.s)
             for h, (stx, _, recid) in zip(hashes, todo)
@@ -167,11 +150,9 @@ class SenderPrefetcher:
         blocks: Iterable,
         depth: int = 8,
         cache_entries: int = 65536,
-        batch_hash: bool = False,
     ):
         self._source = iter(blocks)
         self._cache_entries = cache_entries
-        self._batch_hash = batch_hash
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._closed = threading.Event()
         self.busy_seconds = 0.0
@@ -187,9 +168,7 @@ class SenderPrefetcher:
                     return
                 t0 = time.perf_counter()
                 recover_block_senders(
-                    block.body.transactions,
-                    self._cache_entries,
-                    self._batch_hash,
+                    block.body.transactions, self._cache_entries
                 )
                 self.busy_seconds += time.perf_counter() - t0
                 if not self._put(block):

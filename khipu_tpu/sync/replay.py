@@ -109,8 +109,7 @@ class ReplayStats:
     pipeline_occupancy: float = 0.0
     # persist-stage store traffic (WindowCommitter always-on counters):
     # node bytes + keys landed in the host store and the seconds the
-    # store writes took — bench.py derives persist_bytes_per_sec from
-    # these on every replay metric line
+    # store writes took — persist bytes/s is their quotient
     persist_bytes: int = 0
     persist_store_seconds: float = 0.0
 
@@ -143,7 +142,7 @@ def _timed_prefetch_pull(prefetcher, ph):
     part of sender recovery the background thread failed to hide, so
     without it the driver phases would no longer tile the wall clock
     (pipeline.stall is a DRIVER_PHASES member; ph["senders"] keeps the
-    bench attribution honest)."""
+    phase attribution honest)."""
     it = iter(prefetcher)
     while True:
         t0 = time.perf_counter()
@@ -545,7 +544,6 @@ class ReplayDriver:
                 blocks,
                 depth=sync.sender_prefetch_depth,
                 cache_entries=sync.sender_cache_entries,
-                batch_hash=sync.sender_batch_hash,
             )
             blocks = prefetcher
         try:
@@ -611,13 +609,12 @@ class ReplayDriver:
                 blocks,
                 depth=sync.sender_prefetch_depth,
                 cache_entries=sync.sender_cache_entries,
-                batch_hash=sync.sender_batch_hash,
             )
             # the driver's wait on the prefetch queue is sender
             # recovery leaking back onto the critical path (the
             # thread can't keep ahead) — bill it to pipeline.stall so
             # the driver phases still tile the wall clock, and to the
-            # senders phase so the bench attributes it honestly
+            # senders phase so the phase split attributes it honestly
             blocks = _timed_prefetch_pull(prefetcher, ph)
         blocks = iter(blocks)
         try:
@@ -671,8 +668,8 @@ class ReplayDriver:
                 )
 
                 # the probe's calibration upload is seal-path
-                # machinery — bill it to the seal phase so bench
-                # --diff attributes it there instead of to an
+                # machinery — bill it to the seal phase so the cost
+                # model attributes it there instead of to an
                 # unattributed "?" row
                 with LEDGER.context(window=0, phase="seal"):
                     adaptive = self._adaptive = AdaptiveCommitController(
@@ -805,8 +802,8 @@ class ReplayDriver:
                 # and pack mutates memory only, so the crash contract
                 # is unchanged: persist is still the first durable
                 # mutation. The LEDGER phase stays "seal" so the
-                # per-window cost model and bench --diff keep
-                # attributing the sub-phases to the seal family.
+                # per-window cost model keeps attributing the
+                # sub-phases to the seal family.
                 with use_tracer(tr):
                     fault_point("collector.seal")
                     t0 = time.perf_counter()
@@ -1021,7 +1018,6 @@ class ReplayDriver:
                         recover_block_senders(
                             block.body.transactions,
                             sync.sender_cache_entries,
-                            sync.sender_batch_hash,
                         )
                     ph["senders"] += time.perf_counter() - t0
                     if JOURNEY.enabled:

@@ -1040,84 +1040,3 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
     job.upload_seconds = _up_s
     job.compile_seconds = compile_s
     return job
-
-
-# ------------------------------------------- execute-stage device hook
-#
-# ISSUE 17(c): the gathered account-row tiles of a window's fast-path
-# batches (ledger/batch_exec.py + batch_call.py) validate as ONE fused
-# device computation next to the hash dispatch. The rows are already
-# the device shape the host numpy pass uses — 256-bit big-endian limbs
-# — just u32 instead of u64 (the TPU VPU has no 64-bit lanes; same
-# (hi, lo) emulation as ops/keccak_jnp.py). Padding rows are all-zero
-# (0 == 0 and 0 >= 0 both pass) and sliced off after the fetch, so a
-# handful of pow-2 shapes serves every batch. Only reachable behind
-# sync.exec_device + the adaptive probe (adaptive.exec_device_allowed):
-# where device memory is host RAM this is pure dispatch overhead, and
-# the host numpy pass stays the authoritative default.
-
-_EXEC_VALIDATE_JIT = None
-
-
-def _exec_validate_fn():
-    global _EXEC_VALIDATE_JIT
-    if _EXEC_VALIDATE_JIT is None:
-        import jax.numpy as jnp
-
-        def kernel(tx_nonce, acct_nonce, bal, up):
-            # nonce: exact u64 equality over (hi, lo) u32 pairs
-            nonce_ok = jnp.all(tx_nonce == acct_nonce, axis=1)
-            # balance >= upfront: lexicographic over 8 big-endian u32
-            # limbs — the first differing limb decides, all-equal is >=
-            neq = bal != up
-            has_diff = jnp.any(neq, axis=1)
-            first = jnp.argmax(neq, axis=1)  # index of first difference
-            first_gt = jnp.take_along_axis(
-                bal > up, first[:, None], axis=1
-            )[:, 0]
-            balance_ok = jnp.where(has_diff, first_gt, True)
-            return nonce_ok & balance_ok
-
-        _EXEC_VALIDATE_JIT = named_jit("exec_validate", kernel)
-    return _EXEC_VALIDATE_JIT
-
-
-def _u32_rows(values, limbs: int) -> np.ndarray:
-    """(n, limbs) uint32 big-endian limb rows of unsigned ints."""
-    out = np.zeros((len(values), limbs), dtype=np.uint32)
-    for i, v in enumerate(values):
-        for j in range(limbs):
-            out[i, j] = (v >> (32 * (limbs - 1 - j))) & 0xFFFFFFFF
-    return out
-
-
-def fused_exec_validate(tx_nonces, acct_nonces, balances, upfronts):
-    """Validate one gathered batch of account rows on device: returns
-    a bool row mask (nonce matches AND balance covers upfront), exactly
-    the host pass in ledger/batch_exec.gather_validate_rows."""
-    import jax.numpy as jnp
-
-    fn = _exec_validate_fn()
-    n = len(tx_nonces)
-    npad = _pow2(n, floor=8)
-
-    def rows(vals, limbs):
-        arr = _u32_rows(vals, limbs)
-        if npad > n:
-            arr = np.vstack(
-                [arr, np.zeros((npad - n, limbs), dtype=np.uint32)]
-            )
-        return arr
-
-    tn = rows(tx_nonces, 2)
-    an = rows(acct_nonces, 2)
-    bl = rows(balances, 8)
-    uf = rows(upfronts, 8)
-    nbytes = tn.nbytes + an.nbytes + bl.nbytes + uf.nbytes
-    with LEDGER.transfer("exec.batch_device", H2D, nbytes):
-        dt, da, db, du = (jnp.asarray(x) for x in (tn, an, bl, uf))
-    # khipu-lint: ok KL001 device-resident compare, no host<->device bytes
-    out = fn(dt, da, db, du)
-    with LEDGER.transfer("exec.batch_device", D2H, npad):
-        mask = np.asarray(out)
-    return mask[:n]

@@ -1,66 +1,37 @@
 #!/usr/bin/env python
-"""Driver benchmark, one JSON line per BASELINE config (primary last).
+"""Scripted scenario gates: each sub-command builds a fixture chain,
+drives one operator's scenario against the real planes and gates on
+its invariants (exit 1 / AssertionError on a breach), printing one JSON
+line per result.
 
-Configs (BASELINE.md):
-  #1 regular-sync replay, early-era-shaped fixture chain (~3 tx/block),
-     full validation + device trie commit          -> blocks/s
-  #3 100k-account MPT bulk build (one root on device, host/device split)
-  #4 parallel-commit replay, ERC-20-era-shaped blocks (~50 tx/block,
-     optimistic parallel execution + merge)        -> blocks/s, par %
-  #5 snapshot verify: content-address re-hash of 1M 576B nodes (chip-
-     resident; the 10M-node config sharded across a pod runs the same
-     kernel via parallel.keccak_sharded)           -> nodes/s/chip
-  #2 Keccak-256 microbench: 1M x 576B nodes, batched Pallas kernel
-     -> hashes/s/chip (PRIMARY — printed last; the driver records the
-     final line)
+  serve       JSON-RPC under mid-sync load, overload shed (docs/serving.md)
+  serve-http  the same over the HTTP fleet and its router
+  rebalance   elastic shard join + retire under a hard deadline
+  reorg       replay, fork, reorg and crash recovery (docs/recovery.md)
+  ingest      Kesque ingest and recovery (docs/kesque.md)
+  getlogs     eth_getLogs against the bloom index
+  gameday     seeded fault schedule over all of it (docs/gameday.md)
 
-vs_baseline for #2 compares against optimized *scalar* CPU Keccak
-measured live (hashlib.sha3_256 — same f[1600] permutation, OpenSSL C),
-standing in for the reference's per-node JVM sponge
-(khipu-base/.../crypto/hash/KeccakCore.scala). Device work stays
-resident.
-
-Mainnet block data is unreachable from this environment (zero egress),
-so #1/#4 replay ChainBuilder fixture chains shaped like their eras;
-state roots are still fully validated per block (the same
-validateBlockAfterExecution gate mainnet replay would use).
+These are invariants, never rates: a time printed here is a CPU time
+of this host. Speed is measured by ``benchmark/run.py`` on the chip.
 """
 
-import hashlib
+import argparse
 import json
 import sys
 import time
 
 
-# every emitted line, in order — the --compare gate diffs these against
-# a captured baseline without re-parsing our own stdout
-_EMITTED = []
-
-# --compare context: while a baseline is loaded, emit() fills
-# vs_baseline with the REAL ratio against the captured line (host-speed
-# normalized for rate units) instead of the historical 0.0 placeholder
-_BASELINE_CTX = {"map": None, "speed_adjust": None}
-
-
-def emit(metric, value, unit, vs_baseline=0.0, **extra):
-    if vs_baseline == 0.0 and _BASELINE_CTX["map"] is not None:
-        base = _BASELINE_CTX["map"].get(metric)
-        bval = base.get("value") if isinstance(base, dict) else None
-        if isinstance(bval, (int, float)) and bval:
-            # rate metrics ("/s") compare host-speed-adjusted, the same
-            # normalization _compare_line gates on; durations/fractions
-            # compare raw (the ratio is the trajectory, not a gate)
-            adj = ((_BASELINE_CTX["speed_adjust"] or 1.0)
-                   if "/s" in str(unit) else 1.0)
-            vs_baseline = round(value * adj / bval, 3)
+def emit(metric, value, unit, **extra):
+    # "vs_baseline" is a constant of the line format since the compare
+    # gate went; consumers of the lines see what they always saw
     line = {
         "metric": metric,
         "value": value,
         "unit": unit,
-        "vs_baseline": vs_baseline,
+        "vs_baseline": 0.0,
     }
     line.update(extra)
-    _EMITTED.append(line)
     print(json.dumps(line), flush=True)
 
 
@@ -79,47 +50,6 @@ def _p99(vals):
     return _quantile(vals, 0.99)
 
 
-def cpu_scalar_baseline(length: int = 576, iters: int = 20000) -> float:
-    blob = b"\xa5" * length
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        hashlib.sha3_256(blob).digest()
-    return iters / (time.perf_counter() - t0)
-
-
-def host_speed_score(batches: int = 40, rows: int = 64,
-                     length: int = 576) -> float:
-    """Keccak microworkload score (hashes/s, best of 3) — a scalar
-    proxy for how fast THIS host runs the bench's dominant compute
-    (sender recovery, trie hashing, and mapping-slot derivation all
-    bottom out in keccak). --capture stamps it into the baseline and
-    --compare re-measures it, normalizing every blocks/s ratio by
-    score_base / score_now, so a slower re-run host (the r09 -> r10
-    incident, where the headline drop was pure host variance) reads as
-    host speed instead of a code regression. Best-of-3 because the
-    score must track the host's ceiling, not a scheduler hiccup inside
-    one sample. Uses the native batch keccak when it is importable —
-    that is the primitive the replay hot path actually pays for — with
-    the hashlib scalar as the stand-in everywhere else."""
-    blobs = [b"\xa5" * length] * rows
-    try:
-        from khipu_tpu.native.keccak import keccak256_batch
-
-        def work():
-            for _ in range(batches):
-                keccak256_batch(blobs)
-    except Exception:  # native lib unavailable: scalar stand-in
-        def work():
-            for _ in range(batches * rows):
-                hashlib.sha3_256(blobs[0]).digest()
-    best = 0.0
-    for _ in range(3):
-        t0 = time.perf_counter()
-        work()
-        best = max(best, batches * rows / (time.perf_counter() - t0))
-    return round(best, 1)
-
-
 def _replay_keys(nsenders, seed_base=1):
     from khipu_tpu.base.crypto.secp256k1 import (
         privkey_to_pubkey,
@@ -129,1657 +59,6 @@ def _replay_keys(nsenders, seed_base=1):
     keys = [(i + seed_base).to_bytes(32, "big") for i in range(nsenders)]
     addrs = [pubkey_to_address(privkey_to_pubkey(k)) for k in keys]
     return keys, addrs
-
-
-def _replay_fixture(parallel, window, alloc, build_blocks, device_commit,
-                    pipeline_depth=2, trace=False):
-    """Shared replay-bench scaffolding: build a fixture chain through the
-    ChainBuilder, round-trip through wire RLP (replay must pay sender
-    recovery + parse like a real sync), then replay into a fresh chain
-    DB. ``build_blocks(builder)`` returns the block list.
-
-    Device mode warms the fused-finalize XLA compile with a one-window
-    throwaway replay first (every later window/epoch reuses the compiled
-    shapes — steady state is the representative number, same convention
-    as bench_bulk_build's cold/steady split)."""
-    import dataclasses
-
-    from khipu_tpu.config import SyncConfig, fixture_config
-    from khipu_tpu.domain.block import Block as _Block
-    from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
-    from khipu_tpu.storage.storages import Storages
-    from khipu_tpu.sync.chain_builder import ChainBuilder
-    from khipu_tpu.sync.replay import ReplayDriver
-
-    cfg = fixture_config(chain_id=1)
-    cfg = dataclasses.replace(
-        cfg,
-        sync=SyncConfig(
-            parallel_tx=parallel, tx_workers=8,
-            commit_window_blocks=window,
-            pipeline_depth=pipeline_depth,
-        ),
-    )
-    builder = ChainBuilder(
-        Blockchain(Storages(), cfg), cfg, GenesisSpec(alloc=alloc)
-    )
-    blocks = [_Block.decode(b.encode()) for b in build_blocks(builder)]
-    if device_commit:
-        # warm-up replays the WHOLE chain: later windows can land in
-        # different compiled shape buckets than the first (the trie
-        # grows), and a cold XLA compile inside the timed region would
-        # swamp the steady-state number the bench reports
-        warm = Blockchain(Storages(), cfg)
-        warm.load_genesis(GenesisSpec(alloc=alloc))
-        # fresh decodes: the warm-up must not pre-populate the cached
-        # senders on the BLOCK OBJECTS the timed replay will measure
-        # (the per-object memo dies with the decode). The PROCESS-WIDE
-        # sender cache (sync/prefetch.py) deliberately stays warm: the
-        # warm-up is the first import, the timed replay a re-import —
-        # exactly the scenario the cache exists for, and what the
-        # "senders" phase-share ceiling assumes. Benches that want a
-        # deliberately cold recovery pass call flush_sender_cache().
-        ReplayDriver(warm, cfg, device_commit=True).replay(
-            [_Block.decode(b.encode()) for b in blocks]
-        )
-    target = Blockchain(Storages(), cfg)
-    target.load_genesis(GenesisSpec(alloc=alloc))
-    if trace:
-        # drop chain-build/warm-up spans AND transfer events: the
-        # breakdown must cover exactly the timed replay below
-        from khipu_tpu.observability.profiler import LEDGER
-        from khipu_tpu.observability.trace import tracer
-
-        tracer.reset()
-        LEDGER.reset()
-    driver = ReplayDriver(target, cfg, device_commit=device_commit)
-    return driver.replay(blocks)
-
-
-def _trace_report(stats):
-    """Per-phase breakdown of the spans the timed replay recorded, the
-    split ``--trace`` prints next to blocks/s. driver_total_s is the
-    sum of top-level DRIVER phases — those tile the driver's wall clock
-    (collector phases overlap them on the background thread), so it
-    must land within a few percent of stats.seconds; the smoke test
-    asserts exactly that."""
-    from khipu_tpu.observability import recorder
-    from khipu_tpu.observability.profiler import LEDGER
-    from khipu_tpu.observability.registry import REGISTRY
-    from khipu_tpu.observability.trace import tracer
-
-    spans = tracer.snapshot()
-    breakdown = recorder.phase_breakdown(spans)
-    log = recorder.compile_log.snapshot()
-    # data-movement ledger: which bytes crossed the host<->device
-    # boundary, per pipeline phase, normalized per block — the
-    # companion number to the collect-share split (that says
-    # collect dominates; this says WHICH bytes it moved)
-    movement = {}
-    if LEDGER.enabled and LEDGER.blocks:
-        by_phase = LEDGER.phase_bytes_per_block()
-        movement = {
-            "bytes_per_block_by_phase": by_phase,
-            # the device-resident commit's headline number: collect
-            # must fetch only the 32 B/block root digests — anything
-            # bigger means node bytes crossed d2h on the critical path
-            "collect_d2h_bytes_per_block": (
-                by_phase.get("collect", {}).get("d2h", 0)
-            ),
-            "device_bytes_total": LEDGER.direction_totals(),
-            "ledger_blocks": LEDGER.blocks,
-            "transfer_events": LEDGER.recorded,
-            # seal-wall microscope: bytes/block attributed to each
-            # seal sub-phase SITE (seal.upload is the one to watch —
-            # the r05->r06 regression was +252 KB/block right here)
-            "bytes_per_block_by_subphase": (
-                LEDGER.subphase_bytes_per_block()
-            ),
-        }
-        # bulk-tile spill throughput: all persist-phase ledger bytes
-        # (mirror.spill tiles + window.store host writes) over the
-        # persist stage's wall seconds — the number the one-slice-per-
-        # tile spill is supposed to move, pinned in BENCH captures
-        persist_bpb = sum(by_phase.get("persist", {}).values())
-        persist_s = breakdown.get("window.persist", 0.0)
-        movement["persist_bytes_per_sec"] = (
-            round(persist_bpb * LEDGER.blocks / persist_s)
-            if persist_s > 0 else 0
-        )
-    # the seal-wall decomposition --trace prints: every seal.* span
-    # plus the in-seal subset whose summed seconds must cover the
-    # monolithic window.seal bar (the acceptance pin)
-    decomp = recorder.seal_decomposition(spans)
-    return {
-        "phase_seconds": breakdown,
-        "seal_subphases": {
-            k: v["seconds"] for k, v in decomp["all"].items()
-        },
-        "seal_decomposition": {
-            "seal_s": decomp["seal_s"],
-            "subphase_in_seal_s": decomp["subphase_in_seal_s"],
-            "cover": decomp["cover"],
-            "in_seal": decomp["in_seal"],
-        },
-        "driver_total_s": round(
-            sum(v for k, v in breakdown.items()
-                if k in recorder.DRIVER_PHASES), 4
-        ),
-        "wall_s": round(stats.seconds, 4),
-        "occupancy_spans": round(recorder.occupancy(spans), 4),
-        "occupancy_gauge": round(stats.pipeline_occupancy, 4),
-        "spans": len(spans),
-        "dropped": tracer.dropped,
-        "compile_cache": {
-            k: log[k] for k in ("hits", "misses", "evictions")
-        },
-        # the unified-registry view of the same run: family count plus
-        # the recorder-fed phase-latency histogram totals — the smoke
-        # test cross-checks these against the text exposition
-        "registry_families": len(REGISTRY.snapshot()),
-        "phase_observations": {
-            k: h.value["count"]
-            for k, h in recorder.PHASE_HISTOGRAMS.items()
-            if h.value["count"]
-        },
-        **({"movement": movement} if movement else {}),
-    }
-
-
-def run_traced_replay(n_blocks=32, txs_per_block=50, window=4,
-                      pipeline_depth=4, device_commit=True,
-                      chrome_out=None):
-    """The pipelined-replay bench with the flight recorder ON: returns
-    (stats, report) where report is _trace_report's breakdown. The
-    --trace CLI wraps this with device_commit=True; the smoke test
-    calls it with a tiny chain and device_commit=False (host hasher —
-    no multi-second XLA compile inside a 'not slow' test)."""
-    from khipu_tpu.observability.profiler import LEDGER
-    from khipu_tpu.observability.trace import tracer
-
-    tracer.enable()
-    LEDGER.enable()
-    try:
-        stats = _bench_replay_stats(
-            n_blocks, txs_per_block, parallel=True, window=window,
-            pipeline_depth=pipeline_depth, device_commit=device_commit,
-            trace=True,
-        )
-        report = _trace_report(stats)
-        if chrome_out:
-            from khipu_tpu.observability import export
-
-            export.dump_chrome_trace(chrome_out)
-            report["chrome_trace"] = chrome_out
-    finally:
-        tracer.disable()
-        LEDGER.disable()
-    return stats, report
-
-
-def _bench_replay_stats(n_blocks, txs_per_block, parallel, window,
-                        pipeline_depth=2, device_commit=True,
-                        trace=False):
-    """Disjoint-transfer replay shape shared by bench_replay and
-    run_traced_replay; returns the ReplayStats."""
-    from khipu_tpu.domain.transaction import Transaction, sign_transaction
-
-    nsenders = min(max(txs_per_block, 2), 64)
-    keys, addrs = _replay_keys(nsenders)
-    # receivers are a DISJOINT address pool: typical blocks pay
-    # addresses that are not also senders in the same block, which is
-    # what makes the reference's ~80% parallel rate achievable
-    receivers = [
-        bytes.fromhex("%040x" % (0xBEEF0000 + i)) for i in range(256)
-    ]
-
-    def build(builder):
-        blocks = []
-        nonces = [0] * nsenders
-        for n in range(n_blocks):
-            txs = []
-            for j in range(txs_per_block):
-                i = j % nsenders
-                txs.append(
-                    sign_transaction(
-                        Transaction(
-                            nonces[i], 10**9, 21_000,
-                            receivers[(j * 7 + n) % len(receivers)],
-                            1_000 + n,
-                        ),
-                        keys[i],
-                        chain_id=1,
-                    )
-                )
-                nonces[i] += 1
-            blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-        return blocks
-
-    return _replay_fixture(
-        parallel, window, {a: 10**24 for a in addrs}, build,
-        device_commit=device_commit, pipeline_depth=pipeline_depth,
-        trace=trace,
-    )
-
-
-def _exec_metrics(stats):
-    """Scheduler- and storage-era numbers every replay metric line
-    carries: fraction of txs the vectorized fast path executed,
-    execute-phase throughput (txs over the foreground "execute" phase
-    seconds — the number the conflict-aware scheduler is supposed to
-    move), and persist-stage store throughput (bytes landed per
-    store-write second — the number the Kesque segment log moves)."""
-    ex = stats.phases.get("execute", 0.0)
-    return {
-        "fast_path_coverage": round(stats.fast_path_coverage, 4),
-        "execute_txs_per_sec": (
-            round(stats.txs / ex) if ex > 0 else 0
-        ),
-        "residue_txs": stats.residue_txs,
-        "mispredictions": stats.mispredictions,
-        "persist_bytes_per_sec": round(stats.persist_bytes_per_sec),
-        "persist_bytes": stats.persist_bytes,
-    }
-
-
-def bench_replay(n_blocks, txs_per_block, metric, parallel, window=1,
-                 note=None, pipeline_depth=2):
-    """Configs #1/#4: build a fixture chain, then time a validated
-    replay into a fresh chain DB with device trie commits (windowed:
-    one batched device pass per `window` blocks, up to
-    ``pipeline_depth`` windows sealed-but-uncollected in flight)."""
-    stats = _bench_replay_stats(
-        n_blocks, txs_per_block, parallel, window,
-        pipeline_depth=pipeline_depth,
-    )
-    emit(
-        metric,
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        parallel_pct=round(
-            100 * stats.parallel_txs / stats.txs if stats.txs else 0
-        ),
-        conflicts=stats.conflicts,
-        window=window,
-        pipeline_depth=pipeline_depth,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        phases=stats.phase_line(),
-        pipeline_occupancy=round(stats.pipeline_occupancy, 4),
-        **_exec_metrics(stats),
-        **({"note": note} if note else {}),
-    )
-
-
-def bench_replay_pre_byzantium(n_blocks=120, txs_per_block=3):
-    """TRUE config #1 shape: Frontier-era semantics — receipts carry
-    per-tx INTERMEDIATE state roots (Receipt.scala:7-22), so every tx
-    must resolve a real root before the next runs. That serializes
-    hashing onto the host eager path by construction: no window > 1 is
-    semantically possible, and a device dispatch per tx would pay a
-    blocking round trip thousands of times for single-path hashes. This
-    metric reports that era honestly at window=1; the windowed device
-    pipeline metric above is the Byzantium+ shape."""
-    import dataclasses
-
-    from khipu_tpu.config import SyncConfig, fixture_config
-    from khipu_tpu.domain.block import Block as _Block
-    from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
-    from khipu_tpu.domain.transaction import Transaction, sign_transaction
-    from khipu_tpu.storage.storages import Storages
-    from khipu_tpu.sync.chain_builder import ChainBuilder
-    from khipu_tpu.sync.replay import ReplayDriver
-
-    # pre-Byzantium (and pre-EIP-155: Frontier txs sign without a
-    # chain id), per BASELINE config #1's actual era
-    far = 10**9
-    cfg = dataclasses.replace(
-        fixture_config(
-            chain_id=1,
-            byzantium_block=far,
-            constantinople_block=far,
-            petersburg_block=far,
-            istanbul_block=far,
-            eip155_block=far,
-            eip160_block=far,
-            eip161_block=far,
-            eip170_block=far,
-        ),
-        sync=SyncConfig(parallel_tx=False, commit_window_blocks=1),
-    )
-    nsenders = min(max(txs_per_block, 2), 64)
-    keys, addrs = _replay_keys(nsenders)
-    receivers = [
-        bytes.fromhex("%040x" % (0xDEAD0000 + i)) for i in range(256)
-    ]
-    alloc = {a: 10**24 for a in addrs}
-    builder = ChainBuilder(
-        Blockchain(Storages(), cfg), cfg, GenesisSpec(alloc=alloc)
-    )
-    blocks = []
-    nonces = [0] * nsenders
-    for n in range(n_blocks):
-        txs = []
-        for j in range(txs_per_block):
-            i = j % nsenders
-            txs.append(
-                sign_transaction(
-                    Transaction(
-                        nonces[i], 10**9, 21_000,
-                        receivers[(j * 5 + n) % len(receivers)], 77 + n,
-                    ),
-                    keys[i],
-                    chain_id=None,  # Frontier: no replay protection
-                )
-            )
-            nonces[i] += 1
-        blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-    wire = [_Block.decode(b.encode()) for b in blocks]
-    target = Blockchain(Storages(), cfg)
-    target.load_genesis(GenesisSpec(alloc=alloc))
-    stats = ReplayDriver(target, cfg).replay(wire)
-    # honest-shape gate: the replayed receipts really carry 32-byte
-    # intermediate state roots, not EIP-658 status bytes
-    receipts = target.get_receipts(1)
-    assert receipts and all(
-        isinstance(r.post_tx_state, bytes) and len(r.post_tx_state) == 32
-        for r in receipts
-    ), "fixture is not pre-Byzantium-shaped"
-    emit(
-        "replay_pre_byzantium_window1_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        window=1,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        note=(
-            "true Frontier shape: intermediate-root receipts force "
-            "window=1 + host-eager per-tx hashing (see docstring)"
-        ),
-    )
-
-
-def bench_replay_contended(n_blocks=16, txs_per_block=50, hot_recipients=4,
-                           hot_fraction=0.2, window=8):
-    """Config #4 adversarial variant: ERC-20-style token transfers with
-    CONTENDED storage slots, so the optimistic-parallel merge actually
-    detects conflicts and re-executes (the disjoint-transfer variant
-    above measures the best case only). A `hot_fraction` of each block's
-    txs pays one of `hot_recipients` shared addresses — every later tx
-    touching a hot balance slot reads what an earlier tx wrote and must
-    re-run serially (Ledger.scala:393-434 path). Token bytecode runs on
-    the native EVM when built."""
-    from khipu_tpu.domain.transaction import (
-        Transaction,
-        contract_address,
-        sign_transaction,
-    )
-
-    nsenders = txs_per_block  # one tx per sender per block: distinct nonces
-    keys, addrs = _replay_keys(nsenders, seed_base=101)
-    alloc = {a: 10**24 for a in addrs}
-
-    # token runtime: balance[CALLER] -= amt; balance[to] += amt
-    # (wrapping — contention shape is the point, not ERC-20 semantics)
-    runtime = bytes(
-        [
-            0x60, 0x00, 0x35,        # PUSH1 0 CALLDATALOAD    .. to
-            0x60, 0x20, 0x35,        # PUSH1 32 CALLDATALOAD   .. to amt
-            0x33, 0x54,              # CALLER SLOAD            .. to amt bal_c
-            0x81, 0x90, 0x03,        # DUP2 SWAP1 SUB          .. to amt bal_c-amt
-            0x33, 0x55,              # CALLER SSTORE           .. to amt
-            0x81, 0x54, 0x01,        # DUP2 SLOAD ADD          .. to bal_to+amt
-            0x90, 0x55,              # SWAP1 SSTORE[to]        .. (empty)
-            0x00,                    # STOP
-        ]
-    )
-    init = (
-        bytes([0x60 + len(runtime) - 1]) + runtime
-        + bytes([0x60, 0x00, 0x52])
-        + bytes([0x60, len(runtime), 0x60, 32 - len(runtime), 0xF3])
-    )
-
-    token = contract_address(addrs[0], 0)
-    hot = [
-        bytes.fromhex("%040x" % (0xA0000000 + i))
-        for i in range(hot_recipients)
-    ]
-    cold = [bytes.fromhex("%040x" % (0xB0000000 + i)) for i in range(4096)]
-    n_hot = max(1, int(txs_per_block * hot_fraction))
-
-    def build(builder):
-        blocks = [
-            builder.add_block(
-                [sign_transaction(
-                    Transaction(0, 10**9, 500_000, None, 0, payload=init),
-                    keys[0], chain_id=1,
-                )],
-                coinbase=b"\xaa" * 20,
-            )
-        ]
-        nonces = [1] + [0] * (nsenders - 1)
-        for n in range(n_blocks):
-            txs = []
-            for j in range(txs_per_block):
-                if j < n_hot:
-                    to = hot[(j + n) % hot_recipients]
-                else:
-                    to = cold[(n * txs_per_block + j * 13) % len(cold)]
-                payload = to.rjust(32, b"\x00") + (1).to_bytes(32, "big")
-                txs.append(
-                    sign_transaction(
-                        Transaction(
-                            nonces[j], 10**9, 200_000, token, 0,
-                            payload=payload,
-                        ),
-                        keys[j],
-                        chain_id=1,
-                    )
-                )
-                nonces[j] += 1
-            blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-        return blocks
-
-    # DEVICE commit: with the pipelined seal/collect the fused-finalize
-    # round trip overlaps host execution, so this metric now includes
-    # conflicts AND the windowed device commit in one number (the
-    # round-4 review asked for exactly this combination)
-    stats = _replay_fixture(True, window, alloc, build, device_commit=True)
-    from khipu_tpu.evm.native_vm import available as native_available
-
-    emit(
-        "replay_contended_erc20_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        parallel_pct=round(
-            100 * stats.parallel_txs / stats.txs if stats.txs else 0
-        ),
-        conflicts=stats.conflicts,
-        hot_recipients=hot_recipients,
-        hot_fraction=hot_fraction,
-        window=window,
-        device_commit=True,
-        native_evm=native_available(),
-        phases=stats.phase_line(),
-        pipeline_occupancy=round(stats.pipeline_occupancy, 4),
-        **_exec_metrics(stats),
-    )
-
-
-def bench_replay_conflict_storm(n_blocks=16, txs_per_block=50,
-                                hot_senders=4, window=8):
-    """ISSUE 14 adversarial fixture #1: hot-KEY contention for the
-    conflict-aware scheduler. Every block's txs come from only
-    ``hot_senders`` accounts (sequential nonces), so each tx's
-    predicted read of its sender conflicts with the previous tx from
-    the same sender — the planner's frontier chains them and the
-    disjoint batches collapse toward serial (max width ==
-    hot_senders, ~txs_per_block/hot_senders batches per block). Every
-    tx is still a plain transfer, so fast_path_coverage stays ~1.0:
-    the collapse is purely a SCHEDULING storm, isolating the cost of
-    many narrow vectorized batches + frontier bookkeeping from the
-    interpreter residue (the mixed-contract fixture covers that)."""
-    from khipu_tpu.domain.transaction import Transaction, sign_transaction
-
-    keys, addrs = _replay_keys(hot_senders, seed_base=301)
-    receivers = [
-        bytes.fromhex("%040x" % (0xC0DE0000 + i)) for i in range(8)
-    ]
-
-    def build(builder):
-        blocks = []
-        nonces = [0] * hot_senders
-        for n in range(n_blocks):
-            txs = []
-            for j in range(txs_per_block):
-                i = j % hot_senders
-                txs.append(
-                    sign_transaction(
-                        Transaction(
-                            nonces[i], 10**9, 21_000,
-                            receivers[(j + n) % len(receivers)],
-                            1_000 + n,
-                        ),
-                        keys[i], chain_id=1,
-                    )
-                )
-                nonces[i] += 1
-            blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-        return blocks
-
-    stats = _replay_fixture(
-        True, window, {a: 10**24 for a in addrs}, build,
-        device_commit=True,
-    )
-    emit(
-        "replay_conflict_storm_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        conflicts=stats.conflicts,
-        hot_senders=hot_senders,
-        window=window,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        phases=stats.phase_line(),
-        pipeline_occupancy=round(stats.pipeline_occupancy, 4),
-        **_exec_metrics(stats),
-    )
-
-
-def bench_replay_mixed_contract(n_blocks=12, txs_per_block=40,
-                                call_fraction=0.6, window=8):
-    """Mixed contract/transfer traffic: ``call_fraction`` of each
-    block's txs call a counter contract whose SSTORE slot is a
-    CONSTANT (slot 0); the rest are plain transfers. Under ISSUE 14's
-    caller/arg-only derivation this was the adversarial fixture the
-    fast path could NOT carry (coverage pinned < 0.5). ISSUE 17's
-    ``("const", slot)`` rule makes the constant slot derivable, the
-    purity scan proves the counter straight-line, and after one
-    observed + TRUST_AFTER checked blocks the calls execute in the
-    trusted vectorized lane — so the SAME fixture now pins the
-    opposite claim: steady-state fast_path_coverage must CLEAR the
-    gate floor (~0.9 here; every call past the warmup blocks plus
-    every transfer is batched). Same-slot calls still conflict, so
-    the counter calls serialize into width-1 batches — the fixture
-    keeps the scheduler honest about conflicts while the templated
-    executor absorbs the interpreter cost."""
-    from khipu_tpu.domain.transaction import (
-        Transaction,
-        contract_address,
-        sign_transaction,
-    )
-
-    nsenders = txs_per_block  # one tx per sender per block
-    keys, addrs = _replay_keys(nsenders, seed_base=401)
-    alloc = {a: 10**24 for a in addrs}
-
-    # counter runtime: storage[0] += 1 — the slot is a literal, so no
-    # (caller|arg|map) derivation can explain it and the learner goes
-    # opaque after the first observation
-    runtime = bytes([
-        0x60, 0x00, 0x54,        # PUSH1 0 SLOAD
-        0x60, 0x01, 0x01,        # PUSH1 1 ADD
-        0x60, 0x00, 0x55,        # PUSH1 0 SSTORE
-        0x00,                    # STOP
-    ])
-    init = (
-        bytes([0x60 + len(runtime) - 1]) + runtime
-        + bytes([0x60, 0x00, 0x52])
-        + bytes([0x60, len(runtime), 0x60, 32 - len(runtime), 0xF3])
-    )
-    counter = contract_address(addrs[0], 0)
-    receivers = [
-        bytes.fromhex("%040x" % (0xD00D0000 + i)) for i in range(64)
-    ]
-    n_calls = int(txs_per_block * call_fraction)
-
-    def build(builder):
-        blocks = [
-            builder.add_block(
-                [sign_transaction(
-                    Transaction(0, 10**9, 500_000, None, 0, payload=init),
-                    keys[0], chain_id=1,
-                )],
-                coinbase=b"\xaa" * 20,
-            )
-        ]
-        nonces = [1] + [0] * (nsenders - 1)
-        for n in range(n_blocks):
-            txs = []
-            for j in range(txs_per_block):
-                if j < n_calls:
-                    tx = Transaction(
-                        nonces[j], 10**9, 100_000, counter, 0,
-                    )
-                else:
-                    tx = Transaction(
-                        nonces[j], 10**9, 21_000,
-                        receivers[(j * 5 + n) % len(receivers)],
-                        1_000 + n,
-                    )
-                txs.append(sign_transaction(tx, keys[j], chain_id=1))
-                nonces[j] += 1
-            blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-        return blocks
-
-    stats = _replay_fixture(True, window, alloc, build, device_commit=True)
-    from khipu_tpu.evm.native_vm import available as native_available
-
-    emit(
-        "replay_mixed_contract_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        conflicts=stats.conflicts,
-        call_fraction=call_fraction,
-        window=window,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        native_evm=native_available(),
-        phases=stats.phase_line(),
-        pipeline_occupancy=round(stats.pipeline_occupancy, 4),
-        **_exec_metrics(stats),
-    )
-
-
-# ERC-20 transfer(to, amount) with REAL keccak mapping slots: balances
-# live at keccak(pad32(holder) ++ pad32(0)) — sender slot debits by the
-# amount word, recipient slot credits. Calldata is the raw two words
-# (no ABI selector), so arg0 = recipient, arg1 = amount. Straight-line
-# and fully whitelisted for the purity scan (const memory offsets, const
-# SHA3 size), which is what lets the learner derive ("map_caller", 0) /
-# ("map_arg", 0, 0) write rules and trust the code after confirmation.
-_ERC20_RUNTIME = bytes([
-    0x33,                    # CALLER
-    0x60, 0x00, 0x52,        # PUSH1 0  MSTORE   mem[0:32] = caller
-    0x60, 0x00,              # PUSH1 0  (mapping base slot)
-    0x60, 0x20, 0x52,        # PUSH1 32 MSTORE   mem[32:64] = 0
-    0x60, 0x40, 0x60, 0x00,  # PUSH1 64 PUSH1 0
-    0x20,                    # SHA3              sender slot
-    0x80, 0x54,              # DUP1 SLOAD        sender balance
-    0x60, 0x20, 0x35,        # PUSH1 32 CALLDATALOAD   amount
-    0x90, 0x03,              # SWAP1 SUB         bal - amount
-    0x90, 0x55,              # SWAP1 SSTORE      debit sender
-    0x60, 0x00, 0x35,        # PUSH1 0 CALLDATALOAD    recipient
-    0x60, 0x00, 0x52,        # PUSH1 0  MSTORE   mem[0:32] = recipient
-    0x60, 0x40, 0x60, 0x00,  # PUSH1 64 PUSH1 0  (mem[32:64] still 0)
-    0x20,                    # SHA3              recipient slot
-    0x80, 0x54,              # DUP1 SLOAD        recipient balance
-    0x60, 0x20, 0x35,        # PUSH1 32 CALLDATALOAD   amount
-    0x01,                    # ADD               bal + amount
-    0x90, 0x55,              # SWAP1 SSTORE      credit recipient
-    0x00,                    # STOP
-])
-
-# the runtime is wider than one word, so the constructor CODECOPYs it
-# out of the init code instead of the counter's PUSH32 trick
-_ERC20_INIT = bytes([
-    0x60, len(_ERC20_RUNTIME),  # PUSH1 len
-    0x60, 0x0C,                 # PUSH1 12 (runtime offset in init code)
-    0x60, 0x00,                 # PUSH1 0
-    0x39,                       # CODECOPY
-    0x60, len(_ERC20_RUNTIME),  # PUSH1 len
-    0x60, 0x00,                 # PUSH1 0
-    0xF3,                       # RETURN
-]) + _ERC20_RUNTIME
-
-
-def bench_replay_erc20_heavy(n_blocks=16, txs_per_block=40, window=8):
-    """ISSUE 17 fixture: mapping-write-dominated ERC-20 traffic — the
-    workload the templated-call lane exists for. Every tx past the
-    deploy block is a token ``transfer(to, amount)`` against ONE
-    contract whose balances are a REAL keccak mapping: two SSTOREs per
-    call at keccak(pad32(holder) ++ pad32(0)). Holders are all
-    distinct (40 senders paying 64 disjoint receiver addresses) and
-    the amounts VARY per call, so the learner must prove the
-    ``old -/+ arg1`` effect shape, not memorize one delta. Block 1
-    observes (interpreter residue), blocks 2..1+TRUST_AFTER confirm
-    (checked lane), everything after executes as width-40 vectorized
-    batches whose slot keys come from ONE native keccak256_batch call
-    per block. Steady-state fast_path_coverage lands ~0.8 (the gate
-    pins a per-fixture floor); the execute phase share must stay
-    under the watchdog's 0.9 ceiling WITH the vectorized lane doing
-    the carrying — on the interpreter path this fixture buries the
-    driver."""
-    from khipu_tpu.domain.transaction import (
-        Transaction,
-        contract_address,
-        sign_transaction,
-    )
-
-    nsenders = txs_per_block  # one tx per sender per block
-    keys, addrs = _replay_keys(nsenders, seed_base=501)
-    alloc = {a: 10**24 for a in addrs}
-
-    init = _ERC20_INIT
-    token = contract_address(addrs[0], 0)
-    holders = [
-        bytes.fromhex("%040x" % (0xE20E2000 + i)) for i in range(64)
-    ]
-
-    def build(builder):
-        blocks = [
-            builder.add_block(
-                [sign_transaction(
-                    Transaction(0, 10**9, 500_000, None, 0, payload=init),
-                    keys[0], chain_id=1,
-                )],
-                coinbase=b"\xaa" * 20,
-            )
-        ]
-        nonces = [1] + [0] * (nsenders - 1)
-        for n in range(n_blocks):
-            txs = []
-            for j in range(txs_per_block):
-                # distinct recipient per tx within a block: the 40
-                # calls stay pairwise slot-disjoint -> one batch
-                rcpt = holders[(j + n * 7) % len(holders)]
-                amount = 1_000 + 13 * j + n  # varied, never constant
-                payload = (
-                    rcpt.rjust(32, b"\x00")
-                    + amount.to_bytes(32, "big")
-                )
-                tx = Transaction(
-                    nonces[j], 10**9, 200_000, token, 0, payload=payload,
-                )
-                txs.append(sign_transaction(tx, keys[j], chain_id=1))
-                nonces[j] += 1
-            blocks.append(builder.add_block(txs, coinbase=b"\xaa" * 20))
-        return blocks
-
-    stats = _replay_fixture(True, window, alloc, build, device_commit=True)
-    from khipu_tpu.evm.native_vm import available as native_available
-
-    emit(
-        "replay_erc20_heavy_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        conflicts=stats.conflicts,
-        window=window,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        native_evm=native_available(),
-        phases=stats.phase_line(),
-        pipeline_occupancy=round(stats.pipeline_occupancy, 4),
-        **_exec_metrics(stats),
-    )
-
-
-def bench_parallel_scaling(ntx=50):
-    """Multicore wall-clock scaling of the optimistic-parallel executor
-    over the native (GIL-releasing) EVM: one 50-tx disjoint-transfer
-    block, parallel vs sequential, emitted as a scaling factor. On a
-    1-core box this SKIPS with a note instead of asserting a speedup
-    that cannot physically appear — the claim stays falsifiable
-    wherever the bench environment provides cores
-    (TxProcessor.scala:28-49 is the reference's parallel pool)."""
-    import os
-
-    cores = os.cpu_count() or 1
-    from khipu_tpu.evm.native_vm import available as native_available
-
-    if cores < 2 or not native_available():
-        emit(
-            "parallel_exec_multicore_scaling",
-            0,
-            "x",
-            note=(
-                f"skipped: cores={cores}, native_evm="
-                f"{native_available()} (needs >=2 cores + native EVM "
-                "for a meaningful wall-clock scaling measurement)"
-            ),
-        )
-        return
-    import dataclasses
-
-    from khipu_tpu.config import SyncConfig, fixture_config
-    from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
-    from khipu_tpu.domain.transaction import Transaction, sign_transaction
-    from khipu_tpu.storage.storages import Storages
-    from khipu_tpu.sync.chain_builder import ChainBuilder
-
-    keys, addrs = _replay_keys(ntx)
-    alloc = {a: 10**24 for a in addrs}
-
-    def run(parallel):
-        cfg = dataclasses.replace(
-            fixture_config(chain_id=1),
-            sync=SyncConfig(
-                parallel_tx=parallel, tx_workers=min(cores, 8)
-            ),
-        )
-        builder = ChainBuilder(
-            Blockchain(Storages(), cfg), cfg, GenesisSpec(alloc=alloc)
-        )
-        txs = [
-            sign_transaction(
-                Transaction(
-                    0, 10**9, 21_000,
-                    bytes.fromhex("%040x" % (0xCAFE0000 + i)), 1,
-                ),
-                keys[i],
-                chain_id=1,
-            )
-            for i in range(ntx)
-        ]
-        for stx in txs:
-            stx.sender  # pre-recover: measure execution, not ECDSA
-        t0 = time.perf_counter()
-        builder.add_block(txs, coinbase=b"\xaa" * 20)
-        return time.perf_counter() - t0
-
-    run(False)  # warm code paths
-    seq = min(run(False) for _ in range(3))
-    par = min(run(True) for _ in range(3))
-    emit(
-        "parallel_exec_multicore_scaling",
-        round(seq / par, 2),
-        "x",
-        cores=cores,
-        seq_s=round(seq, 4),
-        par_s=round(par, 4),
-        ntx=ntx,
-    )
-
-
-def bench_bulk_build():
-    """Config #3: fresh 100k-account state trie, one root, through the
-    batched device hasher; reports the host-structure vs device-hash
-    split the round-2 verdict asked for."""
-    from khipu_tpu.base.crypto.keccak import keccak256
-    from khipu_tpu.domain.account import Account, address_key
-    from khipu_tpu.trie.bulk import bulk_build, device_hasher
-
-    n = 100_000
-    t0 = time.perf_counter()
-    pairs = [
-        (
-            address_key(i.to_bytes(20, "big")),
-            Account(nonce=0, balance=10**18 + i).encode(),
-        )
-        for i in range(n)
-    ]
-    t_prep = time.perf_counter() - t0
-
-    # cold pass compiles the one fused fixpoint program (the whole DAG
-    # resolves in a single dispatch — trie/fused.py, same machinery as
-    # the windowed replay commit); steady state is the representative
-    # number (every later epoch reuses the compiled shape)
-    t_cold0 = time.perf_counter()
-    bulk_build(pairs, fused=True)
-    cold = time.perf_counter() - t_cold0
-    split = {}
-    t1 = time.perf_counter()
-    root, nodes = bulk_build(pairs, fused=True, stats_out=split)
-    total = time.perf_counter() - t1
-    # sanity: reopenable root, content-addressed nodes, and the fused
-    # root must match the per-level device path (one probe per run)
-    assert len(root) == 32 and len(nodes) > n // 2
-    probe = next(iter(nodes.items()))
-    assert keccak256(probe[1]) == probe[0]
-    sub = pairs[: 2048]
-    assert bulk_build(sub, fused=True)[0] == bulk_build(
-        sub, hasher=device_hasher
-    )[0], "fused bulk root diverged from the level loop"
-    emit(
-        "mpt_bulk_build_100k_accounts",
-        round(n / total),
-        "accounts/s",
-        total_s=round(total, 3),
-        device_hash_s=round(split.get("device_s", 0.0), 3),
-        pack_dispatch_s=round(split.get("pack_s", 0.0), 3),
-        host_structure_s=round(total - split.get("device_s", 0.0), 3),
-        encode_prep_s=round(t_prep, 3),
-        cold_compile_s=round(cold, 3),
-        nodes=len(nodes),
-    )
-
-
-def _build_mirror(N, L):
-    """Shared #5/#2 scaffolding: N random L-byte nodes admitted into
-    the REAL DeviceNodeMirror (storage/device_mirror.py — the store's
-    word-major device cache, fast-sync admits into the same object).
-    Claims are HOST-computed keccak (independent oracle). Returns
-    (mirror, class_mirror, ingest_s, host_hash_s)."""
-    import numpy as np
-
-    from khipu_tpu.base.crypto.keccak import keccak256
-    from khipu_tpu.ops.keccak_jnp import RATE
-    from khipu_tpu.storage.device_mirror import DeviceNodeMirror
-
-    rng = np.random.default_rng(7)
-    raw = rng.integers(0, 256, (N, L), dtype=np.uint8)
-    t0 = time.perf_counter()
-    hashes = [keccak256(raw[i].tobytes()) for i in range(N)]
-    host_hash_s = time.perf_counter() - t0
-
-    # uniform-length population -> exact-length class: rows resident
-    # UNPADDED, kernel pads in registers (18% less HBM per hash)
-    mirror = DeviceNodeMirror(capacity_rows_per_class=N)
-    t0 = time.perf_counter()
-    mirror.admit_packed(hashes, raw, [L] * N, exact=True)
-    cm = mirror._classes[(L // RATE + 1, L)]
-    import jax
-
-    jax.block_until_ready(cm.resident)
-    ingest_s = time.perf_counter() - t0
-    return mirror, cm, ingest_s, host_hash_s
-
-
-_MIRROR_CACHE = {}
-
-
-def _mirror_for(N, L):
-    key = (N, L)
-    if key not in _MIRROR_CACHE:
-        _MIRROR_CACHE[key] = _build_mirror(N, L)
-    return _MIRROR_CACHE[key]
-
-
-def bench_snapshot_verify(N=1 << 20, L=576):
-    """Config #5 (single-chip form): whole-snapshot content-address
-    verification through the REAL device mirror — N nodes resident as
-    word-major tiles (the layout the store keeps at rest), re-hashed
-    and compared against host-computed claimed hashes in one dispatch.
-    Zero per-call layout work; fast-sync runs this same verify at
-    completion (sync/fast_sync.py)."""
-    import jax
-
-    mirror, cm, ingest_s, host_hash_s = _mirror_for(N, L)
-
-    assert mirror.verify() == 0  # warm + correctness
-    times = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        bad = cm.verify()
-        times.append(time.perf_counter() - t0)
-        assert bad == 0
-    # negative control: a forged claim must be detected
-    import jax.numpy as jnp
-
-    poisoned = cm.claimed.at[0, 0, 0, 0].add(jnp.uint32(1))
-    assert int(jax.device_get(cm._verify(cm.resident, poisoned))) == 1
-    dt = sorted(times)[len(times) // 2]
-    emit(
-        "snapshot_verify_576B_nodes_per_sec_per_chip",
-        round(N / dt),
-        "nodes/s/chip",
-        resident_nodes=mirror.resident_count,
-        ingest_s=round(ingest_s, 3),
-        host_oracle_hash_s=round(host_hash_s, 3),
-        note="real store-mirror path: resident word-major tiles, "
-             "host-keccak claims",
-    )
-
-
-def bench_keccak_ingest_path(N=1 << 20, L=576, ROUNDS=8):
-    """Secondary #2 datapoint: batch-major u32 rows in HBM with the
-    word-major retile + in-kernel pad on device — the INGEST-path rate
-    a node paying the layout transpose sees (was the primary until the
-    store's device mirror made the resident layout the real hot path).
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from khipu_tpu.base.crypto.keccak import keccak256
-    from khipu_tpu.ops.keccak_pallas import _build_device_fixed_words
-
-    run = _build_device_fixed_words(L, False)
-    base = jax.random.bits(jax.random.PRNGKey(2026), (N, L // 4), jnp.uint32)
-
-    @jax.jit
-    def one(words, salt):
-        return run(words ^ salt)
-
-    # correctness gate: a wrong kernel benches at zero
-    digests = one(base, jnp.uint32(0))
-    rows = np.asarray(jax.device_get(base[:4])).astype("<u4")
-    outs = np.asarray(jax.device_get(digests[:4])).astype("<u4")
-    for i in range(4):
-        assert outs[i].tobytes() == keccak256(rows[i].tobytes()), "kernel mismatch"
-
-    @jax.jit
-    def step(words, salt0):
-        def body(i, carry):
-            acc, salt = carry
-            return acc ^ run(words ^ salt), salt + jnp.uint32(1)
-        acc, _ = jax.lax.fori_loop(
-            0, ROUNDS, body, (jnp.zeros((N, 8), jnp.uint32), salt0)
-        )
-        return acc
-
-    np.asarray(jax.device_get(step(base, jnp.uint32(0))[:1]))  # warm
-    times = []
-    for i in range(1, 6):
-        t0 = time.perf_counter()
-        np.asarray(jax.device_get(step(base, jnp.uint32(i * ROUNDS))[:1]))
-        times.append(time.perf_counter() - t0)
-    dt = sorted(times)[len(times) // 2]
-    emit(
-        "keccak256_576B_ingest_path_hashes_per_sec_per_chip",
-        round(ROUNDS * N / dt),
-        "hashes/s/chip",
-        note="batch-major ingest layout (pays the on-device word-major "
-             "retile); the primary runs on the store mirror's resident "
-             "tiles",
-    )
-
-
-def bench_keccak_primary(N=1 << 20, L=576, ROUNDS=32):
-    """Config #2 (PRIMARY): sustained batched Keccak over the node
-    store's device mirror — the REAL resident tiles fast-sync admits
-    into, already in the kernel's word-major layout (zero per-dispatch
-    layout work; the store paid the transpose once at write time).
-    ROUNDS (default 32) x 1M x 576B hashes per dispatch (salted,
-    digests xor-accumulated so every hash is live) amortize the
-    per-dispatch round trip; the ingest-path secondary uses 8 rounds,
-    so its gap vs this metric mixes layout AND amortization effects."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    mirror, cm, _, _ = _mirror_for(N, L)
-    run = cm._run
-    tiles = cm.tiles
-
-    @jax.jit
-    def step(tiled, salt0):
-        def body(i, carry):
-            acc, salt = carry
-            return acc ^ run(tiled ^ salt), salt + jnp.uint32(1)
-        acc, _ = jax.lax.fori_loop(
-            0, ROUNDS, body,
-            (jnp.zeros((tiles, 8, 8, 128), jnp.uint32), salt0),
-        )
-        return acc
-
-    # correctness gate: the unsalted resident tiles verify against the
-    # host-keccak claims (a wrong kernel or layout benches at zero)
-    assert cm.verify() == 0
-
-    base = cm.resident
-    np.asarray(jax.device_get(step(base, jnp.uint32(0))[0, 0, 0, :1]))
-    times = []
-    for i in range(1, 6):
-        t0 = time.perf_counter()
-        np.asarray(
-            jax.device_get(step(base, jnp.uint32(i * ROUNDS))[0, 0, 0, :1])
-        )
-        times.append(time.perf_counter() - t0)
-    dt = sorted(times)[len(times) // 2]
-    rate = ROUNDS * N / dt
-    emit(
-        "keccak256_576B_trie_node_hashes_per_sec_per_chip",
-        round(rate),
-        "hashes/s/chip",
-        vs_baseline=round(rate / cpu_scalar_baseline(L), 2),
-        hashes_per_dispatch=ROUNDS * N,
-        note="store-mirror resident word-major tiles (the real hot "
-             "path; ingest-path variant reported separately)",
-    )
-
-
-def bench_replay_traced(chrome_out=None):
-    """``bench.py --trace``: the deep-pipeline headline config with the
-    flight recorder ON — emits the per-phase wall-clock breakdown (and
-    the span-derived occupancy next to the gauge) beside blocks/s.
-    Tracing cost is itself visible: compare this line's blocks/s
-    against replay_pipelined_blocks_per_sec from an untraced run."""
-    stats, report = run_traced_replay(
-        32, 50, window=4, pipeline_depth=4, chrome_out=chrome_out,
-    )
-    emit(
-        "replay_pipelined_blocks_per_sec_traced",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        txs=stats.txs,
-        window=4,
-        pipeline_depth=4,
-        **report,
-    )
-
-
-def bench_replay_chaos(seed=0, n_blocks=32, txs_per_block=50, window=4,
-                       pipeline_depth=4):
-    """``bench.py --chaos=<seed>``: the deep-pipeline headline config
-    under a STANDARD deterministic fault mix (slow store reads, slow
-    persists, occasional fused-dispatch failures falling back to the
-    host hasher), reported next to a clean run of the same shape — the
-    robustness overhead in one line. Same seed, same fault sequence
-    (chaos/plan.py determinism contract)."""
-    from khipu_tpu.chaos import FaultPlan, FaultRule, active, fault_log
-
-    clean = _bench_replay_stats(
-        n_blocks, txs_per_block, parallel=True, window=window,
-        pipeline_depth=pipeline_depth,
-    )
-    fault_log.reset()
-    plan = FaultPlan(seed=seed, rules=[
-        # slow disk: 1-in-1000 node/kv reads stall 0.5ms
-        FaultRule("storage.kv.get", "latency", prob=0.001,
-                  latency_s=0.0005),
-        FaultRule("storage.node.get", "latency", prob=0.001,
-                  latency_s=0.0005),
-        # slow persist phase: a quarter of windows pay +2ms
-        FaultRule("collector.persist", "latency", prob=0.25,
-                  latency_s=0.002),
-        # flaky device: 5% of fused dispatches fail -> host fallback
-        FaultRule("fused.dispatch", "raise", prob=0.05),
-    ])
-    with active(plan):
-        stats = _bench_replay_stats(
-            n_blocks, txs_per_block, parallel=True, window=window,
-            pipeline_depth=pipeline_depth,
-        )
-    snap = fault_log.snapshot()
-    emit(
-        "replay_chaos_blocks_per_sec",
-        round(stats.blocks_per_s, 2),
-        "blocks/s",
-        clean_blocks_per_s=round(clean.blocks_per_s, 2),
-        degradation_pct=round(
-            100 * (1 - stats.blocks_per_s / clean.blocks_per_s)
-            if clean.blocks_per_s else 0, 1
-        ),
-        seed=seed,
-        faults_fired=snap["fired"],
-        faults_by_kind=snap["byKind"],
-        window=window,
-        pipeline_depth=pipeline_depth,
-        n_blocks=n_blocks,
-        txs_per_block=txs_per_block,
-        note="standard fault mix: latent reads + slow persists + "
-             "flaky fused dispatch (docs/recovery.md)",
-    )
-
-
-# ---------------------------------------------------------- regression gate
-
-
-DEFAULT_COMPARE_THRESHOLDS = {
-    # blocks/s may regress to this fraction of the baseline before the
-    # gate trips — generous, because shared-CI hardware variance on the
-    # fixture replays is real (BENCH captures come from whatever box ran
-    # the driver); a true regression from a code change shows up as a
-    # structural drop, not noise
-    "min_blocks_per_s_ratio": 0.5,
-    # collect's share of driver wall clock may grow this much, absolute
-    "max_collect_share_delta": 0.15,
-    # device bytes/block may grow to this multiple of the baseline —
-    # skipped when the baseline predates the ledger and has no movement
-    # numbers
-    "max_bytes_per_block_ratio": 1.25,
-    # per-fixture fast_path_coverage floors (ISSUE 17): these fixtures
-    # replay mapping-write / constant-slot contract traffic the
-    # templated-call lane is supposed to carry — coverage collapsing
-    # below the floor means templates stopped promoting (learner
-    # regression) even if blocks/s happens to stay inside the ratio.
-    # Checked against the CURRENT run, baseline or not. Both measure
-    # ~0.998 warm; 0.8 is the acceptance floor with headroom for a
-    # fixture reshape, not for a lane outage
-    "min_fast_path_coverage": {
-        "replay_mixed_contract_blocks_per_sec": 0.8,
-        "replay_erc20_heavy_blocks_per_sec": 0.8,
-    },
-}
-
-
-def parse_baseline(path):
-    """A BENCH-style capture: {"tail": "<one JSON line per metric>",
-    "parsed": <last line>, ...}. metric -> line dict. Tolerates
-    malformed lines — a capture's byte budget can truncate the first
-    tail line mid-token (tests/fixtures/bench_capture_truncated_tail.json),
-    and a gate that crashes on its own baseline gates nothing."""
-    with open(path) as f:
-        doc = json.load(f)
-    out = {}
-    for raw in doc.get("tail", "").splitlines():
-        raw = raw.strip()
-        if not raw:
-            continue
-        try:
-            line = json.loads(raw)
-        except ValueError:
-            continue
-        if isinstance(line, dict) and "metric" in line:
-            out[line["metric"]] = line
-    parsed = doc.get("parsed")
-    if isinstance(parsed, dict) and "metric" in parsed:
-        out.setdefault(parsed["metric"], parsed)
-    return out
-
-
-def _collect_share(line):
-    """collect / (sum of driver-thread phases). The _bg phases overlap
-    driver work on the background thread — counting them would dilute
-    the share the baseline reported."""
-    phases = line.get("phases")
-    if not isinstance(phases, dict):
-        return None
-    total = sum(
-        v for k, v in phases.items()
-        if isinstance(v, (int, float)) and not k.endswith("_bg")
-    )
-    if total <= 0:
-        return None
-    return phases.get("collect", 0.0) / total
-
-
-def _baseline_bytes_per_block(line):
-    m = line.get("movement")
-    if isinstance(m, dict):
-        tot = m.get("device_bytes_total")
-        blocks = m.get("ledger_blocks")
-        if isinstance(tot, dict) and blocks:
-            return sum(tot.values()) / blocks
-    return None
-
-
-def _compare_line(line, base, bytes_per_block, th, speed_adjust=None):
-    metric = line["metric"]
-    out = {"metric": metric, "failures": []}
-    if bytes_per_block is not None:
-        out["bytes_per_block"] = round(bytes_per_block)
-    # coverage floor judges the CURRENT run alone — a new fixture with
-    # no baseline entry still fails the gate if its lane collapsed
-    floor = (th.get("min_fast_path_coverage") or {}).get(metric)
-    cov = line.get("fast_path_coverage")
-    if floor is not None and cov is not None:
-        out["fast_path_coverage"] = cov
-        if cov < floor:
-            out["failures"].append(
-                f"{metric}: fast_path_coverage {cov} < floor {floor}"
-            )
-    if base is None:
-        out["note"] = "no baseline entry (skipped)"
-        return out
-    if line.get("unit") == "blocks/s" and base.get("value"):
-        measured = line["value"]
-        # host-speed normalization: when both captures carry a
-        # host_speed_score, judge the ratio on the score-adjusted
-        # number (measured * score_base / score_now) so a faster or
-        # slower re-run host doesn't masquerade as a code change;
-        # baselines without a score (r10 and older) compare raw
-        adjusted = measured * speed_adjust if speed_adjust else measured
-        ratio = adjusted / base["value"]
-        out["blocks_per_s"] = measured
-        out["baseline_blocks_per_s"] = base["value"]
-        out["ratio"] = round(ratio, 3)
-        if speed_adjust:
-            out["host_speed_adjust"] = round(speed_adjust, 3)
-            out["adjusted_blocks_per_s"] = round(adjusted, 2)
-        if ratio < th["min_blocks_per_s_ratio"]:
-            out["failures"].append(
-                f"{metric}: blocks/s ratio {ratio:.3f} < "
-                f"{th['min_blocks_per_s_ratio']} "
-                f"({line['value']} vs baseline {base['value']}"
-                + (f", host-speed adjust {speed_adjust:.3f}x"
-                   if speed_adjust else "")
-                + ")"
-            )
-    share_now = _collect_share(line)
-    share_base = _collect_share(base)
-    if share_now is not None and share_base is not None:
-        out["collect_share"] = round(share_now, 4)
-        out["baseline_collect_share"] = round(share_base, 4)
-        if share_now - share_base > th["max_collect_share_delta"]:
-            out["failures"].append(
-                f"{metric}: collect share grew "
-                f"{share_base:.3f} -> {share_now:.3f} "
-                f"(> +{th['max_collect_share_delta']})"
-            )
-    base_bpb = _baseline_bytes_per_block(base)
-    if bytes_per_block is not None and base_bpb:
-        r = bytes_per_block / base_bpb
-        out["bytes_per_block_ratio"] = round(r, 3)
-        if r > th["max_bytes_per_block_ratio"]:
-            out["failures"].append(
-                f"{metric}: device bytes/block grew {r:.2f}x "
-                f"(> {th['max_bytes_per_block_ratio']}x)"
-            )
-    return out
-
-
-# ---------------------------------------------------- differential diff
-
-
-DEFAULT_DIFF_THRESHOLDS = {
-    # blocks/s below this fraction of the base capture counts as a
-    # regression the attribution must explain
-    "diff_min_blocks_per_s_ratio": 0.9,
-    # a phase's wall seconds must grow past BOTH of these to be named
-    # (wall clocks are noisy; tiny phases double all the time)
-    "diff_phase_rel": 0.20,
-    "diff_phase_abs_s": 0.02,
-    # bytes/block growth past BOTH of these is attributed (and counts
-    # as a regression by itself — measured bytes are not noise)
-    "diff_bytes_rel": 0.10,
-    "diff_bytes_abs": 1024,
-}
-
-
-def _fmt_bytes_per_block(n):
-    if abs(n) >= 1024:
-        return f"{n / 1024:+.1f} KB/block"
-    return f"{n:+d} B/block"
-
-
-def _diff_movement_key(base_m, new_m, key, th, attributions):
-    """Attribute bytes/block growth per phase (or sub-phase site) and
-    direction between two movement blocks. Returns True when anything
-    grew past tolerance."""
-    b = (base_m or {}).get(key) or {}
-    n = (new_m or {}).get(key) or {}
-    grew = False
-    for ph in sorted(set(b) | set(n)):
-        for d in ("h2d", "d2h"):
-            bb = int((b.get(ph) or {}).get(d, 0))
-            nn = int((n.get(ph) or {}).get(d, 0))
-            delta = nn - bb
-            if (delta > th["diff_bytes_abs"]
-                    and delta > th["diff_bytes_rel"] * max(bb, 1)):
-                attributions.append(
-                    f"{ph} {_fmt_bytes_per_block(delta)} ({d}, "
-                    f"{bb} -> {nn})"
-                )
-                grew = True
-    return grew
-
-
-def diff_lines(base, new, thresholds=None):
-    """Attribute the delta between two captures of ONE metric line:
-    blocks/s ratio, per-phase wall seconds, and per-phase /
-    per-sub-phase-site bytes per block. Returns {metric, regressed,
-    attributions: [human-readable strings]} — identical lines diff to
-    no attributions at all (the tolerance contract the analyzer tests
-    pin). This is the line that would have reduced the r05->r06
-    regression hunt to "seal.upload +252 KB/block"."""
-    th = dict(DEFAULT_DIFF_THRESHOLDS)
-    th.update(thresholds or {})
-    metric = new.get("metric") or base.get("metric")
-    out = {"metric": metric, "regressed": False, "attributions": []}
-    bv, nv = base.get("value"), new.get("value")
-    if new.get("unit") == "blocks/s" and bv and nv is not None:
-        ratio = nv / bv
-        out["ratio"] = round(ratio, 3)
-        if ratio < th["diff_min_blocks_per_s_ratio"]:
-            out["regressed"] = True
-            out["attributions"].append(
-                f"blocks/s {bv} -> {nv} ({ratio:.2f}x)"
-            )
-    bp = base.get("phases") or {}
-    np_ = new.get("phases") or {}
-    for ph in sorted(set(bp) | set(np_)):
-        b = bp.get(ph, 0.0)
-        n = np_.get(ph, 0.0)
-        if not isinstance(b, (int, float)):
-            b = 0.0
-        if not isinstance(n, (int, float)):
-            n = 0.0
-        delta = n - b
-        if (delta > th["diff_phase_abs_s"]
-                and delta > th["diff_phase_rel"] * max(b, 1e-9)):
-            out["attributions"].append(
-                f"phase {ph} {delta:+.2f} s ({b:.2f} -> {n:.2f})"
-            )
-    base_m = base.get("movement")
-    new_m = new.get("movement")
-    grew = _diff_movement_key(
-        base_m, new_m, "bytes_per_block_by_phase", th,
-        out["attributions"],
-    )
-    # sub-phase columns (captures from this PR onward): site-level
-    # attribution — "seal.upload grew" instead of "seal grew"
-    grew |= _diff_movement_key(
-        base_m, new_m, "bytes_per_block_by_subphase", th,
-        out["attributions"],
-    )
-    if grew:
-        out["regressed"] = True
-    return out
-
-
-def diff_captures(base_map, new_map, thresholds=None):
-    """Diff two parsed captures (metric -> line, as parse_baseline
-    returns): per-metric attribution over the metrics both carry.
-    Returns {metrics, attributions (flattened, metric-prefixed),
-    regressed, compared, skipped}."""
-    metrics = {}
-    attributions = []
-    regressed = False
-    shared = sorted(set(base_map) & set(new_map))
-    for m in shared:
-        if m == "bench_compare":
-            continue  # a gate line, not a measurement
-        d = diff_lines(base_map[m], new_map[m], thresholds)
-        metrics[m] = d
-        regressed |= d["regressed"]
-        attributions.extend(f"{m}: {a}" for a in d["attributions"])
-    return {
-        "metrics": metrics,
-        "attributions": attributions,
-        "regressed": regressed,
-        "compared": [m for m in shared if m != "bench_compare"],
-        "skipped": sorted(
-            (set(base_map) ^ set(new_map)) - {"bench_compare"}
-        ),
-    }
-
-
-def bench_diff(base_path, new_path, thresholds=None):
-    """``bench.py --diff=BASE.json --diff-to=NEW.json``: offline
-    differential analysis of two captures. Prints the attribution and
-    returns 1 when NEW regresses from BASE (blocks/s past the ratio
-    floor, or measured bytes/block growth past tolerance)."""
-    result = diff_captures(
-        parse_baseline(base_path), parse_baseline(new_path), thresholds
-    )
-    emit(
-        "bench_diff",
-        int(result["regressed"]),
-        "regressed",
-        base=base_path,
-        new=new_path,
-        compared=result["compared"],
-        attributions=result["attributions"],
-    )
-    if result["attributions"]:
-        print(f"bench_diff: {base_path} -> {new_path}", file=sys.stderr)
-        for a in result["attributions"]:
-            print(f"  {a}", file=sys.stderr)
-    else:
-        print(
-            f"bench_diff: no attribution ({base_path} -> {new_path} "
-            "within tolerance)",
-            file=sys.stderr,
-        )
-    return 1 if result["regressed"] else 0
-
-
-def bench_compare(path, thresholds=None, runners=None, diff=False):
-    """``bench.py --compare=BASELINE.json``: re-run the headline replay
-    configs with the TransferLedger on, diff blocks/s, collect share,
-    and device bytes/block against the captured baseline, and return
-    non-zero past the thresholds — the bench regression gate
-    (scripts/bench_gate.sh wraps this next to tier-1). The emitted
-    ``bench_compare`` line carries the movement metrics a FUTURE
-    baseline capture needs for the bytes/block comparison. With
-    ``diff=True`` (gate passes ``--diff``) each comparison also runs
-    the differential analyzer against the baseline line, so a gate
-    failure prints WHICH phase/site moved, not just that the headline
-    ratio tripped."""
-    from khipu_tpu.ledger.schedule import reset_learner
-    from khipu_tpu.observability.profiler import LEDGER
-    from khipu_tpu.sync.prefetch import flush_sender_cache
-
-    th = dict(DEFAULT_COMPARE_THRESHOLDS)
-    th.update(thresholds or {})
-    base = parse_baseline(path)
-    # host-speed normalization factor: re-measure the keccak score on
-    # THIS host and scale every blocks/s ratio by score_base/score_now.
-    # Guarded — r10 and older captures predate the score and compare raw
-    speed_adjust = None
-    score_now = host_speed_score()
-    base_score = (base.get("host_speed_score") or {}).get("value")
-    if base_score and score_now:
-        speed_adjust = base_score / score_now
-    if runners is None:
-        runners = [
-            lambda: bench_replay(
-                32, 50, "replay_parallel_commit_fixture_blocks_per_sec",
-                parallel=True, window=8,
-            ),
-            bench_replay_contended,
-            # ISSUE 14 scheduler fixtures: no pre-r09 baseline entry
-            # exists for these — _compare_line tolerates the miss
-            # ("no baseline entry (skipped)") until the next capture
-            bench_replay_conflict_storm,
-            bench_replay_mixed_contract,
-            # ISSUE 17 fixture: mapping-write-dominated ERC-20 traffic
-            # (no pre-r11 baseline entry; tolerated the same way)
-            bench_replay_erc20_heavy,
-            # ISSUE 20 fixture: eth_getLogs indexing scans (no pre-r12
-            # baseline entry; tolerated until the next capture)
-            lambda: bench_getlogs(smoke=False),
-        ]
-    failures = []
-    comparisons = []
-    LEDGER.enable()
-    # every metric line emitted under the comparison carries its real
-    # ratio against the baseline (vs_baseline was a 0.0 placeholder
-    # outside --compare runs for ten releases; see emit())
-    _BASELINE_CTX["map"] = base
-    _BASELINE_CTX["speed_adjust"] = speed_adjust
-    try:
-        for run in runners:
-            LEDGER.reset()  # per-config movement numbers
-            # per-config COLD start for the cross-fixture caches too:
-            # templates learned by one fixture's contracts and senders
-            # recovered for its keys must not subsidize the next
-            # config's number (the baseline was captured the same way)
-            reset_learner()
-            flush_sender_cache()
-            mark = len(_EMITTED)
-            run()
-            bpb = None
-            movement = {}
-            if LEDGER.blocks:
-                tot = LEDGER.direction_totals()
-                bpb = sum(tot.values()) / LEDGER.blocks
-                movement = {
-                    "device_bytes_total": tot,
-                    "ledger_blocks": LEDGER.blocks,
-                    "bytes_per_block_by_phase":
-                        LEDGER.phase_bytes_per_block(),
-                    "bytes_per_block_by_subphase":
-                        LEDGER.subphase_bytes_per_block(),
-                }
-            for line in _EMITTED[mark:]:
-                base_line = base.get(line["metric"])
-                cmp = _compare_line(
-                    line, base_line, bpb, th, speed_adjust=speed_adjust
-                )
-                if movement:
-                    cmp["movement"] = movement
-                if diff and base_line is not None:
-                    new_line = dict(line)
-                    if movement:
-                        new_line["movement"] = movement
-                    d = diff_lines(base_line, new_line, thresholds)
-                    if d["attributions"]:
-                        cmp["attribution"] = d["attributions"]
-                        for a in d["attributions"]:
-                            print(f"  diff {line['metric']}: {a}",
-                                  file=sys.stderr)
-                comparisons.append(cmp)
-                failures.extend(cmp["failures"])
-    finally:
-        LEDGER.disable()
-        _BASELINE_CTX["map"] = None
-        _BASELINE_CTX["speed_adjust"] = None
-    emit(
-        "bench_compare",
-        len(failures),
-        "failures",
-        baseline=path,
-        thresholds=th,
-        host_speed_score=score_now,
-        baseline_host_speed_score=base_score,
-        **({"host_speed_adjust": round(speed_adjust, 3)}
-           if speed_adjust else
-           {"host_speed_note": "baseline has no score; ratios raw"}),
-        comparisons=comparisons,
-        **({"failed": failures} if failures else {}),
-    )
-    return 1 if failures else 0
-
-
-def bench_capture(out_path, runners=None):
-    """``bench.py --capture=BENCH_rNN.json``: run the same headline
-    replay configs the --compare gate re-runs, with the TransferLedger
-    on, and write a BENCH-style baseline document whose metric lines
-    carry the movement block (bytes/block by CURRENT phase names,
-    collect-phase d2h) — a baseline captured this way lets the next
-    --compare enforce the bytes-per-block ratio instead of skipping it
-    (pre-ledger captures have no movement numbers)."""
-    from khipu_tpu.ledger.schedule import reset_learner
-    from khipu_tpu.observability.profiler import LEDGER
-    from khipu_tpu.sync.prefetch import flush_sender_cache
-
-    if runners is None:
-        runners = [
-            lambda: bench_replay(
-                32, 50, "replay_parallel_commit_fixture_blocks_per_sec",
-                parallel=True, window=8,
-            ),
-            bench_replay_contended,
-            bench_replay_conflict_storm,
-            bench_replay_mixed_contract,
-            bench_replay_erc20_heavy,
-            # indexing fixture: getlogs scan rate rides the capture so
-            # future --compare runs gate it like any blocks/s metric
-            lambda: bench_getlogs(smoke=False),
-            # storage-engine gate: ingest delta vs sqlite rides the
-            # capture so BENCH_rNN documents the Kesque numbers
-            lambda: bench_ingest(smoke=False),
-        ]
-    lines = []
-    # host-speed stamp FIRST: the score a future --compare divides by
-    # must describe the host that produced the blocks/s lines below
-    emit(
-        "host_speed_score", host_speed_score(), "hashes/s",
-        note="keccak microworkload; --compare normalizes blocks/s by "
-             "score_base/score_now",
-    )
-    lines.append(dict(_EMITTED[-1]))
-    LEDGER.enable()
-    try:
-        for run in runners:
-            LEDGER.reset()  # per-config movement numbers
-            # cold cross-fixture caches per config, mirroring
-            # bench_compare: learned templates and recovered senders
-            # must not leak across the config boundary
-            reset_learner()
-            flush_sender_cache()
-            mark = len(_EMITTED)
-            run()
-            movement = {}
-            if LEDGER.blocks:
-                by_phase = LEDGER.phase_bytes_per_block()
-                movement = {
-                    "device_bytes_total": LEDGER.direction_totals(),
-                    "ledger_blocks": LEDGER.blocks,
-                    "bytes_per_block_by_phase": by_phase,
-                    "bytes_per_block_by_subphase":
-                        LEDGER.subphase_bytes_per_block(),
-                    "collect_d2h_bytes_per_block": (
-                        by_phase.get("collect", {}).get("d2h", 0)
-                    ),
-                }
-            for line in _EMITTED[mark:]:
-                row = dict(line)
-                if movement:
-                    row["movement"] = movement
-                lines.append(row)
-    finally:
-        LEDGER.disable()
-    doc = {
-        "cmd": f"python bench.py --capture={out_path}",
-        "rc": 0,
-        "tail": "\n".join(json.dumps(ln) for ln in lines),
-        "parsed": lines[-1] if lines else None,
-    }
-    with open(out_path, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-    print(f"captured {len(lines)} metric line(s) -> {out_path}",
-          file=sys.stderr)
 
 
 def _serve_setup(n_blocks, txs_per_block, window=2, depth=2):
@@ -1949,7 +228,7 @@ def _serve_setup(n_blocks, txs_per_block, window=2, depth=2):
 
 
 def bench_serve(smoke=False):
-    """``bench.py --serve``: the serving-plane bench — mixed RPC load
+    """``scenarios.py serve``: the serving-plane bench — mixed RPC load
     against a node MID-SYNC (the windowed pipelined replay importing
     blocks on another thread), with the loadgen's read-your-writes
     checker on. Three phases: (A) unloaded read-only baseline p99,
@@ -2339,9 +618,9 @@ def bench_serve(smoke=False):
 def _fleet_setup(n_blocks, txs_per_block=4, sync_kwargs=None,
                  serving_kwargs=None):
     """Primary + fork branch + 2 read replicas + FleetRouter, wired
-    for ``bench.py --serve --http`` (and, with ``sync_kwargs``
+    for ``scenarios.py serve-http`` (and, with ``sync_kwargs``
     overriding the target's SyncConfig — e.g. a windowed pipeline so
-    the collector stages are live — for ``bench.py --gameday``).
+    the collector stages are live — for ``scenarios.py gameday``).
     Fixture chains are always BUILT under the serial window=1 config,
     whatever the target runs.
 
@@ -2500,7 +779,7 @@ def _fleet_setup(n_blocks, txs_per_block=4, sync_kwargs=None,
 
 
 def bench_serve_http(smoke=False):
-    """``bench.py --serve --http``: the replica-fleet bench over the
+    """``scenarios.py serve-http``: the replica-fleet bench over the
     REAL wire path — keep-alive HTTP into a FleetRouter fronting a
     primary plus two read replicas, with the read-your-writes checker
     (consistent-read tokens) on the whole time. Three phases: (A)
@@ -2669,6 +948,9 @@ def bench_serve_http(smoke=False):
     scores = telemetry.health_scores()
     assert scores[replicas[0].name].score == 0.0, scores
     assert scores[replicas[1].name].score > 0.0, scores
+    # nothing below needs the survivor tailing; a caller that lives on
+    # (a test) must not inherit its thread
+    replicas[1].stop()
 
     violations = (
         len(floor.violations) + len(overload.violations)
@@ -2770,7 +1052,7 @@ def bench_serve_http(smoke=False):
 
 
 def bench_rebalance(smoke=False, deadline_s=120.0):
-    """``bench.py --rebalance``: elastic-membership smoke/bench — a
+    """``scenarios.py rebalance``: elastic-membership smoke/bench — a
     3-shard in-process cluster takes a 4th shard through the full
     epoch-fenced join (plan / stream / cutover) and then retires an
     original. Emits ``shard_boot_to_serving_seconds`` (join call to
@@ -2886,7 +1168,7 @@ def bench_rebalance(smoke=False, deadline_s=120.0):
 
 
 def bench_reorg(smoke=False, deadline_s=120.0):
-    """``bench.py --reorg``: the fork-battle fixture — a node serving
+    """``scenarios.py reorg``: the fork-battle fixture — a node serving
     balance reads through a ReadView while a heavier branch displaces
     its tip. Two rounds: (1) the switch is KILLED mid-adopt at a
     ``reorg.*`` chaos seam and recovered in-process off the journaled
@@ -3531,12 +1813,15 @@ def _gameday_run(smoke, seed, result):
         "telemetry": telemetry,
         "watchdog": wd,
     })
+    # a caller that lives on (a test) must not inherit the tail threads
+    for r in live_replicas:
+        r.stop()
     clear_current_event()
 
 
 def bench_gameday(smoke=False, seed=0, deadline_s=None,
                   chrome_out=None):
-    """``bench.py --gameday``: one seeded scenario composing every
+    """``scenarios.py gameday``: one seeded scenario composing every
     failure mode the repo has proven in isolation — shard join +
     collector death + replica death + shard death + fork battle,
     under 4x overload — gated on the full invariant set and (full
@@ -3709,7 +1994,7 @@ def bench_gameday(smoke=False, seed=0, deadline_s=None,
 
 
 def bench_ingest(smoke=False, deadline_s=180.0):
-    """``bench.py --ingest``: the Kesque storage-engine gate — three
+    """``scenarios.py ingest``: the Kesque storage-engine gate — three
     first-class metrics, all gated:
 
     * ``persist_bytes_per_sec`` — bulk ``append_batch`` throughput of
@@ -3991,51 +2276,8 @@ def bench_ingest(smoke=False, deadline_s=180.0):
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def bench_conformance(gate=1.0):
-    """``bench.py --conformance``: run the GeneralStateTests-format
-    corpus (tests/fixtures/state_tests — the same files the
-    pytest-marked ``conformance`` suite parametrizes over) through
-    khipu_tpu/statetest.py and gate on the pass rate. The gate is the
-    CURRENT rate (1.0): conformance only ratchets, it never regresses
-    silently."""
-    import glob
-    import os
-
-    from khipu_tpu.statetest import run_file
-
-    fixdir = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "tests", "fixtures", "state_tests",
-    )
-    files = sorted(glob.glob(os.path.join(fixdir, "*.json")))
-    results = []
-    for p in files:
-        results.extend(run_file(p))
-    total = len(results)
-    passed = sum(1 for r in results if r.ok)
-    rate = passed / total if total else 0.0
-    failed = [
-        f"{r.name} [{r.fork}] idx={r.index}"
-        for r in results if not r.ok
-    ]
-    emit(
-        "statetest_pass_rate", round(rate, 4), "fraction",
-        passed=passed, total=total, files=len(files), gate=gate,
-        **({"failed": failed[:10]} if failed else {}),
-        note="ethereum/tests GeneralStateTests schema corpus via "
-             "khipu_tpu.statetest (per-fork, per-index cases)",
-    )
-    if total == 0 or rate < gate:
-        print(
-            f"bench_conformance: FAILED — pass rate {rate:.4f} < gate "
-            f"{gate} ({passed}/{total}; first failures: {failed[:3]})",
-            file=sys.stderr,
-        )
-        sys.exit(1)
-
-
 def bench_getlogs(smoke=False):
-    """``bench.py --getlogs``: the indexing fixture — a chain whose
+    """``scenarios.py getlogs``: the indexing fixture — a chain whose
     every block carries LOG1-emitting contract calls, scanned by
     repeated full-range address+topic ``eth_getLogs`` queries through
     the RPC service (the workload an indexer backfilling an event
@@ -4120,242 +2362,38 @@ def bench_getlogs(smoke=False):
     )
 
 
-def bench_history(pattern=None):
-    """``bench.py --history``: walk the committed BENCH_r*.json
-    captures and render one per-metric trajectory table across
-    releases. Rate metrics (unit contains "/s") are re-expressed in
-    the NEWEST scored capture's host frame (value * score_ref /
-    score_capture — the same normalization --compare gates on);
-    captures that predate host_speed_score print raw, marked ``*``."""
-    import glob
-    import os
+MODES = {
+    "serve": bench_serve,
+    "serve-http": bench_serve_http,
+    "rebalance": bench_rebalance,
+    "reorg": bench_reorg,
+    "ingest": bench_ingest,
+    "getlogs": bench_getlogs,
+    "gameday": bench_gameday,
+}
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    paths = sorted(
-        glob.glob(pattern or os.path.join(here, "BENCH_r*.json"))
-    )
-    caps = []
-    for p in paths:
-        try:
-            caps.append((
-                os.path.basename(p)
-                .replace("BENCH_", "").replace(".json", ""),
-                parse_baseline(p),
-            ))
-        except Exception as e:  # noqa: BLE001 - skip broken captures
-            print(f"bench_history: skipping {p}: {e}", file=sys.stderr)
-    if not caps:
-        print("bench_history: no BENCH_r*.json captures found",
-              file=sys.stderr)
-        sys.exit(1)
-    scores = {
-        name: (m.get("host_speed_score") or {}).get("value")
-        for name, m in caps
-    }
-    ref_name = ref_score = None
-    for name, _m in reversed(caps):
-        if scores[name]:
-            ref_name, ref_score = name, scores[name]
-            break
-    metrics, units = [], {}
-    for _name, m in caps:
-        for k, line in m.items():
-            if k in ("host_speed_score", "bench_compare"):
-                continue
-            if k not in units:
-                metrics.append(k)
-                units[k] = str(line.get("unit", ""))
-    table = {}
-    for k in metrics:
-        row = {}
-        for name, m in caps:
-            line = m.get(k)
-            v = line.get("value") if isinstance(line, dict) else None
-            if not isinstance(v, (int, float)):
-                continue
-            normalized = False
-            if "/s" in units[k] and ref_score and scores[name]:
-                v = v * ref_score / scores[name]
-                normalized = True
-            row[name] = (v, normalized)
-        table[k] = row
 
-    def fmt(v, normalized, is_rate):
-        s = f"{v:,.4g}"
-        if is_rate and ref_score and not normalized:
-            s += "*"
-        return s
-
-    names = [n for n, _ in caps]
-    mw = max(len(k) for k in metrics) + 2
-    colw = {
-        n: max(
-            [len(n)] + [
-                len(fmt(*table[k][n], "/s" in units[k]))
-                for k in metrics if n in table[k]
-            ]
-        ) + 2
-        for n in names
-    }
-    head = (f"bench history — {len(caps)} captures"
-            + (f"; rates in {ref_name}'s host frame "
-               f"(host_speed_score {ref_score:,.0f})" if ref_score
-               else "; no scored capture, all values raw"))
-    print(head)
-    header = "metric".ljust(mw) + "unit".ljust(10) + "".join(
-        n.rjust(colw[n]) for n in names
-    )
-    print(header)
-    print("-" * len(header))
-    for k in metrics:
-        is_rate = "/s" in units[k]
-        cells = "".join(
-            ("-" if n not in table[k]
-             else fmt(*table[k][n], is_rate)).rjust(colw[n])
-            for n in names
-        )
-        print(k.ljust(mw) + units[k][:9].ljust(10) + cells)
-    if ref_score:
-        print("* raw: capture predates host_speed_score "
-              "(no cross-host normalization possible)")
-    emit(
-        "bench_history", len(caps), "captures",
-        reference=ref_name,
-        reference_host_speed_score=ref_score,
-        metrics={
-            k: {n: round(v, 4) for n, (v, _norm) in table[k].items()}
-            for k in metrics
-        },
-    )
+def parse_args(argv):
+    """argv -> (gate function, its keyword arguments). No mode or an
+    unknown one is argparse's usage error (exit 2)."""
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    sub = parser.add_subparsers(dest="mode", required=True)
+    modes = {mode: sub.add_parser(mode) for mode in MODES}
+    for p in modes.values():
+        p.add_argument("--smoke", action="store_true")
+    modes["gameday"].add_argument("--seed", type=int, default=0)
+    modes["gameday"].add_argument("--chrome-out", default=None)
+    kwargs = vars(parser.parse_args(argv))
+    return MODES[kwargs.pop("mode")], kwargs
 
 
 def main() -> None:
+    fn, kwargs = parse_args(sys.argv[1:])
     from khipu_tpu import device
 
     device.place_compile_cache()
-    if "--serve" in sys.argv:
-        if "--http" in sys.argv:
-            bench_serve_http(smoke="--smoke" in sys.argv)
-        else:
-            bench_serve(smoke="--smoke" in sys.argv)
-        return
-    if "--rebalance" in sys.argv:
-        bench_rebalance(smoke="--smoke" in sys.argv)
-        return
-    if "--reorg" in sys.argv:
-        bench_reorg(smoke="--smoke" in sys.argv)
-        return
-    if "--ingest" in sys.argv:
-        bench_ingest(smoke="--smoke" in sys.argv)
-        return
-    if "--conformance" in sys.argv:
-        bench_conformance()
-        return
-    if "--getlogs" in sys.argv:
-        bench_getlogs(smoke="--smoke" in sys.argv)
-        return
-    if "--history" in sys.argv:
-        bench_history()
-        return
-    if "--gameday" in sys.argv:
-        seed = 0
-        chrome_out = None
-        for arg in sys.argv[1:]:
-            if arg.startswith("--seed="):
-                seed = int(arg.split("=", 1)[1])
-            elif arg.startswith("--chrome-out="):
-                chrome_out = arg.split("=", 1)[1]
-        bench_gameday(smoke="--smoke" in sys.argv, seed=seed,
-                      chrome_out=chrome_out)
-        return
-    compare_path = None
-    diff_path = None
-    diff_to_path = None
-    want_diff = False
-    thresholds = {}
-    for arg in sys.argv[1:]:
-        if arg.startswith("--capture="):
-            bench_capture(arg.split("=", 1)[1])
-            return
-        if arg.startswith("--compare="):
-            compare_path = arg.split("=", 1)[1]
-        elif arg == "--diff":
-            want_diff = True
-        elif arg.startswith("--diff="):
-            diff_path = arg.split("=", 1)[1]
-        elif arg.startswith("--diff-to="):
-            diff_to_path = arg.split("=", 1)[1]
-        elif arg.startswith("--min-blocks-ratio="):
-            thresholds["min_blocks_per_s_ratio"] = float(
-                arg.split("=", 1)[1]
-            )
-        elif arg.startswith("--max-collect-delta="):
-            thresholds["max_collect_share_delta"] = float(
-                arg.split("=", 1)[1]
-            )
-        elif arg.startswith("--max-bytes-ratio="):
-            thresholds["max_bytes_per_block_ratio"] = float(
-                arg.split("=", 1)[1]
-            )
-    if diff_path is not None and diff_to_path is not None:
-        # offline differential mode: no replay runs, just attribution
-        sys.exit(bench_diff(diff_path, diff_to_path, thresholds))
-    if diff_path is not None and compare_path is None:
-        print("bench_diff: --diff=BASE.json needs --diff-to=NEW.json",
-              file=sys.stderr)
-        sys.exit(2)
-    if compare_path is not None:
-        sys.exit(bench_compare(
-            compare_path, thresholds=thresholds,
-            diff=want_diff or diff_path is not None,
-        ))
-    for arg in sys.argv[1:]:
-        if arg.startswith("--chaos"):
-            seed = int(arg.split("=", 1)[1]) if "=" in arg else 0
-            bench_replay_chaos(seed)
-            return
-    if "--trace" in sys.argv:
-        chrome_out = None
-        for arg in sys.argv[1:]:
-            if arg.startswith("--chrome-out="):
-                chrome_out = arg.split("=", 1)[1]
-        bench_replay_traced(chrome_out)
-        return
-    bench_replay_pre_byzantium()
-    bench_replay(
-        120, 3, "replay_early_era_fixture_blocks_per_sec",
-        parallel=False, window=40,
-        note=(
-            "byzantium-SHAPED fixture blocks (the windowed device "
-            "pipeline needs status receipts); the true Frontier-era "
-            "number is the separate pre_byzantium_window1 metric"
-        ),
-    )
-    bench_replay(
-        32, 50, "replay_parallel_commit_fixture_blocks_per_sec",
-        parallel=True, window=8,
-    )
-    # deep-pipeline headline: same parallel-commit shape, smaller
-    # windows but 4 sealed-but-uncollected in flight — measures how
-    # much of collect+save hides behind execution (the occupancy
-    # fraction; docs/window_pipeline.md)
-    bench_replay(
-        32, 50, "replay_pipelined_blocks_per_sec",
-        parallel=True, window=4, pipeline_depth=4,
-    )
-    bench_replay_contended()
-    bench_replay_conflict_storm()
-    bench_replay_mixed_contract()
-    bench_replay_erc20_heavy()
-    bench_parallel_scaling()
-    bench_bulk_build()
-    bench_snapshot_verify()
-    bench_keccak_ingest_path()
-    bench_keccak_primary()  # primary metric: keep LAST
+    fn(**kwargs)
 
 
 if __name__ == "__main__":
-    import os
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     main()

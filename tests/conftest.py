@@ -6,6 +6,13 @@ Stands in for real multi-chip TPU hardware the same way the reference's
 """
 
 import os
+import sys
+
+# the root scripts tests import (scenarios, chip_smoke) live beside
+# the package, not in it
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")

@@ -25,7 +25,7 @@ def _sync_cfg(**overrides):
 
 def _slow_probe(platform="doctored"):
     """A backend where the d2d gather LOSES to the host memcpy 100x —
-    the BENCH_r07 1-core-CPU shape."""
+    the round-7 1-core-CPU shape."""
     return ProbeResult(platform, 1e6, 1e8, False)
 
 

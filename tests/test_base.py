@@ -209,3 +209,27 @@ class TestNativeRLPCodec:
             R.rlp_encode(deep)
         with _pytest.raises(R.RLPError):
             R._py_rlp_encode(deep)
+
+
+class TestConfigSurface:
+    def test_leaf_config_fields_are_counted(self):
+        """Every field is a configuration somebody has to cover: a PR
+        that adds or removes one changes this number on purpose.
+        KhipuConfig's own fields are the leaf groups, not options."""
+        import dataclasses
+
+        from khipu_tpu import config
+
+        leaves = [
+            cls for cls in vars(config).values()
+            if dataclasses.is_dataclass(cls) and isinstance(cls, type)
+            and cls is not config.KhipuConfig
+        ]
+        assert len(leaves) == 9
+        assert sum(len(dataclasses.fields(c)) for c in leaves) == 118
+        sync = {f.name for f in dataclasses.fields(config.SyncConfig)}
+        assert len(sync) == 27
+        # retired in PR 29 (one never ran, one lost on the chip): no
+        # execute-stage device switch, no sender hash switch
+        assert not [n for n in sync if n.startswith("exec_")
+                    or n.endswith("_hash")]

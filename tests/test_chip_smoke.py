@@ -10,12 +10,11 @@ import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+import chip_smoke
+from khipu_tpu.observability.profiler import LEDGER
+from khipu_tpu.observability.recorder import compile_log
 
-import chip_smoke  # noqa: E402
-from khipu_tpu.observability.profiler import LEDGER  # noqa: E402
-from khipu_tpu.observability.recorder import compile_log  # noqa: E402
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")

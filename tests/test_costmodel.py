@@ -1,7 +1,7 @@
 """Per-window cost model (observability/costmodel.py): floors,
 bound classification, and the ledger x span join — driven by synthetic
 ledger events and spans, no replay needed (the end-to-end surface is
-covered by the bench --trace smoke)."""
+covered by the recorded-replay smoke in test_observability.py)."""
 
 import types
 

@@ -532,7 +532,7 @@ class TestSeamAudit:
                 )
 
     def test_every_seam_is_exercised_by_some_test(self):
-        corpus = (REPO / "bench.py").read_text(encoding="utf-8")
+        corpus = (REPO / "scenarios.py").read_text(encoding="utf-8")
         corpus += "".join(
             p.read_text(encoding="utf-8")
             for p in sorted((REPO / "tests").glob("*.py"))
@@ -542,7 +542,7 @@ class TestSeamAudit:
             if (seam[:-1] if seam.endswith("*") else seam) not in corpus
         )
         assert not unexercised, (
-            f"chaos seams referenced by no test or bench: {unexercised}"
+            f"chaos seams referenced by no test or scenario: {unexercised}"
         )
 
 

@@ -2,12 +2,10 @@
 off, ring-overflow accounting, cross-thread lifecycle linkage through
 the deep pipeline, occupancy agreement with the live gauge, chrome
 trace_event export, the bounded fused compile cache, and the
-bench --trace per-phase breakdown."""
+per-phase breakdown of a recorded replay."""
 
 import dataclasses
 import json
-import os
-import sys
 import threading
 
 import pytest
@@ -17,9 +15,11 @@ from khipu_tpu.base.crypto.secp256k1 import (
     pubkey_to_address,
 )
 from khipu_tpu.config import ObservabilityConfig, SyncConfig, fixture_config
+from khipu_tpu.domain.block import Block
 from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
 from khipu_tpu.domain.transaction import Transaction, sign_transaction
 from khipu_tpu.observability import export, recorder
+from khipu_tpu.observability.profiler import LEDGER
 from khipu_tpu.observability.trace import (
     Tracer,
     _NULL_SPAN,
@@ -55,6 +55,23 @@ def pipeline_cfg(w=2, depth=2):
 N_BLOCKS = 20
 
 
+def _transfer_chain(n_blocks, txs_per_block):
+    builder = ChainBuilder(
+        Blockchain(Storages(), CFG), CFG,
+        GenesisSpec(alloc={a: 1000 * ETH for a in ADDRS}),
+    )
+    blocks = []
+    nonces = [0] * 4
+    for n in range(n_blocks):
+        txs = []
+        for j in range(txs_per_block):
+            i = j % 4
+            txs.append(tx(i, nonces[i], ADDRS[(i + 1) % 4], 100 + n))
+            nonces[i] += 1
+        blocks.append(builder.add_block(txs, coinbase=MINER))
+    return blocks
+
+
 @pytest.fixture(scope="module")
 def chain():
     """20 transfer blocks (windowed pipeline shape, no device needed).
@@ -62,20 +79,7 @@ def chain():
     hand-off) amortizes below the occupancy-agreement tolerance — at 5
     blocks x 3 txs the span-vs-gauge check sat on the tolerance edge
     and flaked under CI load."""
-    builder = ChainBuilder(
-        Blockchain(Storages(), CFG), CFG,
-        GenesisSpec(alloc={a: 1000 * ETH for a in ADDRS}),
-    )
-    blocks = []
-    nonces = [0] * 4
-    for n in range(N_BLOCKS):
-        txs = []
-        for j in range(16):
-            i = j % 4
-            txs.append(tx(i, nonces[i], ADDRS[(i + 1) % 4], 100 + n))
-            nonces[i] += 1
-        blocks.append(builder.add_block(txs, coinbase=MINER))
-    return blocks
+    return _transfer_chain(N_BLOCKS, 16)
 
 
 def _fresh_chain(cfg):
@@ -100,6 +104,33 @@ def traced_replay(chain):
     finally:
         tracer.disable()
         tracer.reset()
+
+
+def _recorded_replay(n_blocks, txs_per_block, chrome_out=None):
+    """A fresh chain through wire RLP (replay pays sender recovery and
+    parse, like a sync), replayed on the host hasher with the recorder
+    and the transfer ledger ON and reset after the chain build, so
+    spans and ledger cover exactly the replay. Returns (stats, spans);
+    restores the disabled default."""
+    blocks = [
+        Block.decode(b.encode())
+        for b in _transfer_chain(n_blocks, txs_per_block)
+    ]
+    cfg = pipeline_cfg(w=2, depth=2)
+    bc = _fresh_chain(cfg)
+    tracer.enable()
+    LEDGER.enable()
+    try:
+        tracer.reset()
+        LEDGER.reset()
+        stats = ReplayDriver(bc, cfg, device_commit=False).replay(blocks)
+        spans = tracer.snapshot()
+        if chrome_out:
+            export.dump_chrome_trace(chrome_out)
+    finally:
+        tracer.disable()
+        LEDGER.disable()
+    return stats, spans
 
 
 # ------------------------------------------------------ disabled mode
@@ -390,20 +421,15 @@ class TestCompileCache:
             recorder.compile_log.reset()
 
 
-# ------------------------------------------------- bench.py --trace
+# ------------------------------------------- recorded-replay breakdown
 
 
-class TestBenchTrace:
-    def test_traced_bench_breakdown_matches_wall(self):
-        """Satellite gate: the --trace per-phase breakdown (driver
-        phases tile the driver's wall clock) sums to within 10% of the
-        replay's measured wall time on the tiny fixture chain. Host
-        hasher (device_commit=False) keeps this out of 'slow'."""
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        if root not in sys.path:
-            sys.path.insert(0, root)
-        from bench import run_traced_replay
-
+class TestPhaseBreakdown:
+    def test_driver_phases_tile_the_wall(self):
+        """The per-phase breakdown of a recorded replay: the driver
+        phases tile the driver's wall clock, so their sum lands within
+        10% of the replay's measured wall time on the tiny fixture
+        chain. Host hasher keeps this out of 'slow'."""
         # The timing-agreement checks retry over up to 3 independent
         # runs: on a loaded CI box the scheduler can preempt the
         # process between a span exit and the busy-clock stop, pushing
@@ -411,33 +437,33 @@ class TestBenchTrace:
         # disagrees on every run. The structural checks (phases
         # present, no drops, block count) assert unconditionally.
         for attempt in range(3):
-            stats, report = run_traced_replay(
-                n_blocks=24, txs_per_block=8, window=2,
-                pipeline_depth=2, device_commit=False,
-            )
+            stats, spans = _recorded_replay(24, 8)
             assert not tracer.enabled  # helper restores the default
             assert stats.blocks == 24
-            assert report["wall_s"] > 0
+            assert stats.seconds > 0
+            breakdown = recorder.phase_breakdown(spans)
             for phase in recorder.REQUIRED_PHASES:
-                assert phase in report["phase_seconds"], (
-                    report["phase_seconds"]
-                )
-            assert report["dropped"] == 0
+                assert phase in breakdown, breakdown
+            assert tracer.dropped == 0
+            driver_total = sum(
+                v for k, v in breakdown.items()
+                if k in recorder.DRIVER_PHASES
+            )
             wall_ok = (
-                abs(report["driver_total_s"] - report["wall_s"])
-                <= 0.10 * report["wall_s"]
+                abs(driver_total - stats.seconds) <= 0.10 * stats.seconds
             )
             # same self-measurement bias allowance as
             # test_occupancy_agrees_with_gauge
             occ_ok = abs(
-                report["occupancy_spans"] - report["occupancy_gauge"]
+                recorder.occupancy(spans) - stats.pipeline_occupancy
             ) < 0.08
             if wall_ok and occ_ok:
                 break
         else:
             raise AssertionError(
                 "breakdown disagreed with wall clock on 3/3 runs: "
-                f"{report}"
+                f"driver {driver_total} wall {stats.seconds} "
+                f"{breakdown}"
             )
 
 
@@ -712,47 +738,39 @@ class TestMetricsSuperset:
         assert f"khipu_pending_txs {pending}" in lines
 
 
-# --------------------------------------- bench --trace registry smoke
+# ------------------------------------- recorded-replay registry smoke
 
 
-class TestBenchTraceRegistrySmoke:
-    def test_trace_smoke_chrome_valid_and_families_unique(self, tmp_path):
-        """CI satellite: the bench --trace path end to end — the chrome
-        trace it writes is valid JSON with events, and EVERY family in
-        the registry snapshot appears exactly once (one # TYPE line,
-        >=1 sample line) in the khipu_metrics_text exposition."""
+class TestRecordedReplayRegistrySmoke:
+    def test_chrome_valid_and_families_unique(self, tmp_path):
+        """A recorded replay end to end — the chrome trace it writes is
+        valid JSON with events, and EVERY family in the registry
+        snapshot appears exactly once (one # TYPE line, >=1 sample
+        line) in the khipu_metrics_text exposition."""
         import re
-
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        if root not in sys.path:
-            sys.path.insert(0, root)
-        from bench import run_traced_replay
 
         from khipu_tpu.observability.registry import REGISTRY
 
-        chrome = tmp_path / "bench_trace.json"
-        stats, report = run_traced_replay(
-            n_blocks=12, txs_per_block=4, window=2, pipeline_depth=2,
-            device_commit=False, chrome_out=str(chrome),
-        )
+        chrome = tmp_path / "replay_trace.json"
+        stats, _spans = _recorded_replay(12, 4, chrome_out=str(chrome))
         assert stats.blocks == 12
-        assert report["chrome_trace"] == str(chrome)
         doc = json.loads(chrome.read_text())
         assert doc["traceEvents"]
-        assert report["registry_families"] > 0
         # phase histograms observed real latencies during the run
-        assert report["phase_observations"]
-        assert sum(report["phase_observations"].values()) > 0
+        assert sum(
+            h.value["count"] for h in recorder.PHASE_HISTOGRAMS.values()
+        ) > 0
         # the device-resident-commit pin: collect-phase d2h stays at
         # (at most) the 32 B/block rootcheck — the staged pipeline must
         # never pull node bytes back to host on the critical path. The
         # host-hasher smoke run moves ZERO device bytes in collect; the
         # device path is pinned <=256 B/block by TestDeviceMirrorCommit.
-        assert report["movement"]["collect_d2h_bytes_per_block"] <= 64, (
-            report["movement"]
-        )
+        assert LEDGER.blocks == 12
+        by_phase = LEDGER.phase_bytes_per_block()
+        assert by_phase.get("collect", {}).get("d2h", 0) <= 64, by_phase
 
         snap = REGISTRY.snapshot()
+        assert snap
         text = REGISTRY.prometheus_text()
         lines = text.splitlines()
         type_lines = [ln for ln in lines if ln.startswith("# TYPE ")]

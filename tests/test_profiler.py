@@ -1,8 +1,7 @@
 """Data-movement ledger tests (khipu_tpu/observability/profiler.py):
 exact byte accounting against a known-size node fixture, zero-cost
 disabled mode (bit-exact replay, no extra device syncs), chrome counter
-tracks, the bench --compare regression gate, and the registry /
-sampling satellites that rode along (scrape-pass collector caching,
+tracks, and the registry / sampling satellites that rode along (scrape-pass collector caching,
 histogram bucket overrides, deterministic per-trace-id sampling)."""
 
 import dataclasses
@@ -466,113 +465,6 @@ class TestWindowReportRPC:
         cls = rep["collect_classes"]
         assert cls["store-write"]["bytes"] > 0
         assert cls["block-save"]["seconds"] > 0
-
-
-# ------------------------------------------------------- compare gate
-
-
-class TestCompareGate:
-    @staticmethod
-    def _bench():
-        import os
-        import sys
-
-        sys.path.insert(
-            0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        import bench
-
-        return bench
-
-    def _tiny_runner(self, bench):
-        # 12x8 rather than the original 4x4: the 4x4 fixture's phase
-        # totals are single-digit milliseconds, where 1 ms of scheduler
-        # jitter on a loaded box reads as a ~0.12 collect-share swing —
-        # flaking the honest self-compare against the 0.15 share gate
-        def run():
-            bench.bench_replay(
-                12, 8, "replay_parallel_commit_fixture_blocks_per_sec",
-                parallel=True, window=2,
-            )
-        return run
-
-    def _baseline_doc(self, lines):
-        return {
-            "n": 1, "cmd": "test", "rc": 0,
-            "tail": "\n".join(json.dumps(x) for x in lines),
-        }
-
-    def test_parse_baseline_tolerates_truncated_lines(self, tmp_path):
-        bench = self._bench()
-        p = tmp_path / "base.json"
-        doc = self._baseline_doc([{"metric": "ok", "value": 1}])
-        # prepend a truncated fragment, the driver-capture shape
-        doc["tail"] = 'runcated_fragment": 1}\n' + doc["tail"]
-        p.write_text(json.dumps(doc))
-        base = bench.parse_baseline(str(p))
-        assert base == {"ok": {"metric": "ok", "value": 1}}
-
-    def test_real_baseline_parses(self):
-        """A driver capture keeps the LAST bytes of stdout, so its
-        first tail line is cut mid-JSON (fixture: that shape, values
-        blanked)."""
-        import os
-
-        bench = self._bench()
-        base = bench.parse_baseline(os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "fixtures",
-            "bench_capture_truncated_tail.json",
-        ))
-        assert "replay_contended_erc20_blocks_per_sec" in base
-        assert (
-            "keccak256_576B_trie_node_hashes_per_sec_per_chip" in base
-        )
-
-    def test_honest_run_exits_zero(self, tmp_path):
-        bench = self._bench()
-        run = self._tiny_runner(bench)
-        # capture the tiny config's own output as its baseline: an
-        # honest re-run of the same code cannot regress against itself
-        mark = len(bench._EMITTED)
-        run()
-        line = bench._EMITTED[mark]
-        p = tmp_path / "honest.json"
-        p.write_text(json.dumps(self._baseline_doc([line])))
-        assert bench.bench_compare(str(p), runners=[run]) == 0
-
-    def test_doctored_baseline_trips_nonzero(self, tmp_path):
-        bench = self._bench()
-        run = self._tiny_runner(bench)
-        doctored = {
-            "metric": "replay_parallel_commit_fixture_blocks_per_sec",
-            "value": 10**9, "unit": "blocks/s",
-        }
-        p = tmp_path / "doctored.json"
-        p.write_text(json.dumps(self._baseline_doc([doctored])))
-        assert bench.bench_compare(str(p), runners=[run]) == 1
-        # the gate line names the failure
-        gate = bench._EMITTED[-1]
-        assert gate["metric"] == "bench_compare"
-        assert gate["value"] == 1 and gate["failed"]
-
-    def test_collect_share_regression_trips(self, tmp_path):
-        bench = self._bench()
-        run = self._tiny_runner(bench)
-        mark = len(bench._EMITTED)
-        run()
-        line = dict(bench._EMITTED[mark])
-        # doctor the BASELINE's phase split: collect share near zero,
-        # so the honest re-run's real share reads as a regression
-        phases = {k: 0.0 for k in line.get("phases", {})}
-        phases["execute"] = 10.0
-        line["phases"] = phases
-        p = tmp_path / "share.json"
-        p.write_text(json.dumps(self._baseline_doc([line])))
-        rc = bench.bench_compare(
-            str(p), runners=[run],
-            thresholds={"max_collect_share_delta": 0.01},
-        )
-        assert rc == 1
 
 
 # ----------------------------------------------- registry satellites

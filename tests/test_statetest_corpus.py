@@ -3,9 +3,7 @@ state_tests/ — the ethereum/tests GeneralStateTest filler shape).
 
 Every fixture file runs through the REAL execution stack (Ledger ->
 EVM -> trie commit) and every case must land on the filler's post
-state root exactly. ``bench.py --conformance`` runs the SAME corpus
-and gates ``statetest_pass_rate`` at 1.0; this marks the corpus as a
-pytest surface so tier-1 catches a regression without the bench.
+state root exactly; tier-1 runs the corpus on every PR.
 """
 
 import glob
