@@ -4,7 +4,7 @@ Single-chip `trie/fused.py` resolves a window's whole placeholder DAG in
 one dispatch. This module is its SPMD form for a device mesh (SURVEY
 §2.8b/c): node rows shard round-robin across the "nodes" axis, each
 round every chip hashes ITS rows and `all_gather`s the digest table so
-the child-substitution scatter (which references arbitrary rows) sees
+the child substitution (which references arbitrary rows) sees
 every digest — the same hash-local/gather-global shape the sharded bulk
 build uses for level boundaries (parallel/keccak_sharded.py).
 
@@ -13,8 +13,9 @@ digests over ICI. Work scales 1/n_dev; the gathered table is tiny
 (32 B/node) next to the encodings, so the collective stays cheap.
 
 Row assignment is ROUND-ROBIN (global row r -> device r % n_dev, local
-slot r // n_dev): padding rows land at every device's local tail, so
-each device always owns a spare row for dummy (padding) substitutions.
+slot r // n_dev): padding rows land at every device's local tail.
+The substitution itself is trie/fused.py's (subst_plan / substitute):
+one helper for both programs.
 """
 
 from __future__ import annotations
@@ -32,6 +33,9 @@ from khipu_tpu.trie.fused import (
     MAX_DEPTH,
     _pow2,
     _scan_sites,
+    sorted_sites,
+    subst_plan,
+    substitute,
     topo_levels,
 )
 
@@ -43,9 +47,11 @@ def _build_fused_sharded(sig: Tuple[Tuple[int, int, int], ...],
 
     Inputs (leading dim = n_dev, sharded on the nodes axis):
       per class: enc u8[n_dev, rpd, nblocks*RATE]
-      per class: rows32 i32[n_dev, nsubs*32], cols32 i32[n_dev, nsubs*32],
-                 child i32[n_dev, nsubs]   (child indices are GLOBAL
-                 positions in the gathered digest table)
+      per class: rows i32[n_dev, nsubs], offs i32[n_dev, nsubs],
+                 child i32[n_dev, nsubs]: each device's sites in (row,
+                 off) order, padding last with rows == rows_per_dev
+                 (child indices are GLOBAL positions in the gathered
+                 digest table)
     Output: per-class digests u8[n_dev, rpd, 32] (gathered layout).
     """
     import jax
@@ -68,16 +74,14 @@ def _build_fused_sharded(sig: Tuple[Tuple[int, int, int], ...],
             )  # [sum_c rpd_c, 32]
             return jax.lax.all_gather(local, AXIS, tiled=True)
 
+        plans = [
+            subst_plan(sig[c][0], sig[c][1], *subs[3 * c : 3 * c + 3])
+            for c in range(k)
+        ]
+
         def body(_, encs):
             G = all_digests(encs)
-            out = []
-            for c in range(k):
-                rows32 = subs[3 * c]
-                cols32 = subs[3 * c + 1]
-                child = subs[3 * c + 2]
-                vals = G[child].reshape(-1)
-                out.append(encs[c].at[rows32, cols32].set(vals))
-            return out
+            return [substitute(encs[c], plans[c], G) for c in range(k)]
 
         encs = jax.lax.fori_loop(0, rounds, body, encs)
         return all_digests(encs)  # replicated full table
@@ -140,18 +144,24 @@ def fused_resolve_sharded(
         return d * sum_rpd + offset_c[nb] + local
 
     dpos: Dict[bytes, int] = {}
+    row_of: Dict[bytes, int] = {}
     for nb in class_list:
         for r, ph in enumerate(classes[nb]):
             dpos[ph] = gpos(nb, r)
+            row_of[ph] = r
 
     # every substitution, from the one site scan (trie/fused.py
-    # _scan_sites), grouped by the node that holds it
+    # _scan_sites): where the node that holds it lives, and its
+    # child's place in the gathered table
     site_node, site_off, site_child = _scan_sites(to_resolve, prefix, {})
     node_gpos = np.fromiter((dpos[ph] for ph in phs), np.int64, len(phs))
-    subs_of: Dict[bytes, List[Tuple[int, int]]] = {}
-    for i, off, cp in zip(site_node.tolist(), site_off.tolist(),
-                          node_gpos[site_child].tolist()):
-        subs_of.setdefault(phs[i], []).append((off, cp))
+    node_nb = np.fromiter(
+        (len(enc) // RATE + 1 for enc in to_resolve.values()),
+        np.int64, len(phs))
+    node_row = np.fromiter(map(row_of.__getitem__, phs), np.int64, len(phs))
+    site_nb = node_nb[site_node]
+    site_row = node_row[site_node]
+    site_gpos = node_gpos[site_child]
 
     enc_bufs: List[np.ndarray] = []
     sub_arrays: List[np.ndarray] = []
@@ -163,9 +173,6 @@ def fused_resolve_sharded(
         # keccak padding on every row (real rows re-pad below)
         buf[:, :, 0] ^= 0x01
         buf[:, :, width - 1] ^= 0x80
-        per_dev_subs: List[List[Tuple[int, int, int]]] = [
-            [] for _ in range(n_dev)
-        ]
         for r, ph in enumerate(rows):
             enc = to_resolve[ph]
             d, local = r % n_dev, r // n_dev
@@ -173,27 +180,19 @@ def fused_resolve_sharded(
             buf[d, local, : len(enc)] = np.frombuffer(enc, dtype=np.uint8)
             buf[d, local, len(enc)] ^= 0x01
             buf[d, local, width - 1] ^= 0x80
-            for off, cp in subs_of.get(ph, ()):
-                per_dev_subs[d].append((local, off, cp))
-        nsubs = _pow2(
-            max(max((len(s) for s in per_dev_subs), default=0), 1),
-            floor=256,
-        )
-        rows32 = np.empty((n_dev, nsubs * 32), dtype=np.int32)
-        cols32 = np.empty((n_dev, nsubs * 32), dtype=np.int32)
-        child = np.empty((n_dev, nsubs), dtype=np.int32)
+        per_dev = []  # each device's (local row, off, child), ordered
         for d in range(n_dev):
-            subs = list(per_dev_subs[d])
-            while len(subs) < nsubs:  # dummies hit the local spare row
-                subs.append((rpd[nb] - 1, 0, 0))
-            for m, (local, off, cp) in enumerate(subs):
-                rows32[d, m * 32 : (m + 1) * 32] = local
-                cols32[d, m * 32 : (m + 1) * 32] = np.arange(
-                    off, off + 32, dtype=np.int32
-                )
-                child[d, m] = cp
+            on = (site_nb == nb) & (site_row % n_dev == d)
+            per_dev.append(sorted_sites(
+                site_row[on] // n_dev, site_off[on], site_gpos[on], width))
+        nsubs = _pow2(max(len(s[0]) for s in per_dev) + 1, floor=256)
+        # padding names the row past the device's last: dropped there
+        subs = np.zeros((3, n_dev, nsubs), dtype=np.int32)
+        subs[0] = rpd[nb]
+        for d, dev_subs in enumerate(per_dev):
+            subs[:, d, : len(dev_subs[0])] = dev_subs
         enc_bufs.append(buf)
-        sub_arrays.extend([rows32, cols32, child])
+        sub_arrays.extend(subs)
         sig.append((nb, rpd[nb], nsubs))
 
     rounds = _pow2(depth, floor=8)

@@ -16,7 +16,9 @@ into a single XLA program:
          digests = keccak(all nodes)        # pallas, per class
          encodings[parent, off:off+32] = digests[child]
      After k rounds every node within k levels of the leaves carries
-     its final digest — after `depth` rounds all do.
+     its final digest — after `depth` rounds all do. The second line
+     is no scatter: a row gathers its children's digests side by side
+     and shifts them to their offsets (subst_plan / substitute).
 
 Substitution is length-invariant (a placeholder is exactly 32 bytes,
 replaced by a 32-byte hash; RLP headers never change — the same
@@ -133,6 +135,129 @@ def _pow2(n: int, floor: int = 1) -> int:
     while v < n:
         v *= 2
     return v
+
+
+SITE = 32  # bytes of a placeholder, and of the digest that replaces it
+
+
+def sorted_sites(row: np.ndarray, off: np.ndarray, child: np.ndarray,
+                 width: int):
+    """One class's substitutions ordered by ``(row, off)``, which is
+    what :func:`subst_plan` counts a row's sites by. Sites are 32 bytes
+    that do not overlap and lie inside their node's encoding (a
+    placeholder was written as one ref inside one node, and
+    ``find_sites`` starts its next search 32 bytes on), so in that order
+    their flat positions are strictly increasing and 32 apart or more:
+    checked here in one ``diff``, because a site that broke it would be
+    dropped or misplaced on the device without a word."""
+    key = row * width + off
+    order = np.argsort(key, kind="stable")  # O(n) on what is sorted
+    key = key[order]
+    if key.size and (
+        (np.diff(key) < SITE).any()
+        or off.min() < 0 or off.max() + SITE >= width
+    ):
+        raise FusedUnsupported("substitution sites overlap or leave their row")
+    return row[order], off[order], child[order]
+
+
+def subst_plan(nb: int, nrows: int, rows, offs, child):
+    """Everything of one class's substitution that no round changes,
+    built on the device ONCE per dispatch, before the loop.
+
+    ``rows``, ``offs``, ``child`` are i32[nsubs] in ``(row, off)`` order
+    (:func:`sorted_sites`); a padding entry has ``rows == nrows``, out
+    of range, and is dropped by the two table scatters, the only ones
+    there are: it costs index space and no write. A row of ``width`` bytes has room for at most
+    ``slots = (width - 1) // 32`` sites, so the class's children fit a
+    table ``[nrows, slots]``: a site's slot is its rank among its row's.
+    A round then gathers whole digests by that table into
+    ``[nrows, slots * 32]`` (the digests of a row side by side, in
+    offset order) and moves byte ``32 * slot + i`` to ``off + i``. The
+    shift ``off - 32 * slot`` never decreases along a row, so the move
+    is an EXPAND network: one stage per bit of the shift, highest bit
+    first, each a shift of the whole buffer by ``2**k`` bytes and a
+    select; no two bytes ever meet on the way (a byte further along
+    starts further along and has moved at least as far). Stage ``k``'s
+    mask lives where its bytes ARRIVE, which is found by running the
+    network backwards over the shifts themselves, lowest bit first.
+
+    Returns ``(table, covered, masks)`` for :func:`substitute`."""
+    import jax
+    import jax.numpy as jnp
+
+    width = nb * RATE
+    slots = (width - 1) // SITE
+    with jax.named_scope("fused.subst"), jax.named_scope(f"c{nb}"):
+        idx = jnp.arange(rows.shape[0], dtype=jnp.int32)
+        first = jnp.concatenate(
+            [jnp.ones((1,), bool), rows[1:] != rows[:-1]])
+        slot = idx - jax.lax.cummax(jnp.where(first, idx, 0))
+        at = jnp.stack([rows, slot], axis=1)
+        dnums = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1))
+
+        def by_slot(vals, fill):  # [nrows, slots]; padding is dropped
+            return jax.lax.scatter(
+                jnp.full((nrows, slots), fill, jnp.int32), at, vals,
+                dnums, indices_are_sorted=True, unique_indices=True,
+                mode="drop")
+
+        table = by_slot(child, 0)
+        start = by_slot(offs, width)  # no site: starts past every byte
+        # per byte: how many of the row's sites start at or before it,
+        # and where the last of them starts
+        b = jnp.arange(width, dtype=jnp.int32)[None, :]
+        rank = jnp.zeros((nrows, width), jnp.int32)
+        site = jnp.full((nrows, width), -SITE, jnp.int32)
+        for s in range(slots):
+            o = start[:, s, None]
+            begun = o <= b
+            rank = rank + begun
+            site = jnp.where(begun, o, site)  # starts increase along s
+        covered = b - site < SITE
+        shift = jnp.where(covered, site - SITE * (rank - 1), 0)
+        masks = []
+        here = covered  # a byte on its way is at this position
+        for k in range(max(width - SITE - 1, 1).bit_length()):
+            moves = here & ((shift >> k) & 1).astype(bool)
+            masks.append(moves)
+            came = _shifted(moves, -(1 << k))
+            shift = jnp.where(came, _shifted(shift, -(1 << k)), shift)
+            here = came | (here & ~moves)
+        return table, covered, tuple(masks)
+
+
+def _shifted(x, by: int):
+    """``x`` moved ``by`` positions along its rows (towards the end for
+    ``by > 0``), zeros coming in."""
+    import jax.numpy as jnp
+
+    if by > 0:
+        return jnp.pad(x[:, :-by], ((0, 0), (by, 0)))
+    return jnp.pad(x[:, -by:], ((0, 0), (0, -by)))
+
+
+def substitute(enc, plan, digests):
+    """One round of one class: ``enc`` u8[nrows, width] with every site
+    of ``plan`` (:func:`subst_plan`) taking its child's row of
+    ``digests`` u8[n, 32]. Bytes outside a site, a padding row's among
+    them, pass through untouched."""
+    import jax
+    import jax.numpy as jnp
+
+    table, covered, masks = plan
+    nrows, width = enc.shape
+    with jax.named_scope("fused.gather"):
+        vals = digests[table]  # [nrows, slots, 32] u8
+    with jax.named_scope("fused.subst"), \
+            jax.named_scope(f"c{width // RATE}"):
+        moved = vals.reshape(nrows, -1)
+        moved = jnp.pad(moved, ((0, 0), (0, width - moved.shape[1])))
+        for k in reversed(range(len(masks))):
+            moved = jnp.where(masks[k], _shifted(moved, 1 << k), moved)
+        return jnp.where(covered, moved, enc)
 
 
 # how many windows a held bucket outlives the last window that needed
@@ -353,9 +478,9 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
 
     sig: per class (nblocks, nrows, nsubs, nadmit), nrows % TILE == 0.
     Inputs: for each class, enc u8[nrows, nblocks*RATE]; then for each
-    class rows i32[nsubs], offs i32[nsubs], child i32[nsubs] — the
-    x32 byte-index expansion happens ON DEVICE (uploading pre-expanded
-    index arrays tripled the per-window upload);
+    class rows i32[nsubs], offs i32[nsubs], child i32[nsubs] in
+    (row, off) order, padding last with rows == nrows (subst_plan
+    turns them into the loop's tables ON DEVICE, before the loop);
     then ext u8[ext_rows, 32] — RESOLVED-INPUT TILES: final digests
     of a previous (possibly still in-flight) window's nodes, consumed
     device-to-device so cross-window placeholder refs resolve without
@@ -415,27 +540,20 @@ def _build_fused_impl(sig: Tuple[Tuple[int, int, int, int], ...],
                     [runners[c](encs[c]) for c in range(k)], axis=0
                 )  # [sum rows, 32] u8 — ONE output array, one fetch
 
-        idx32 = jnp.arange(32, dtype=jnp.int32)
+        # what no round changes, once, before the loop
+        plans = [
+            subst_plan(sig[c][0], sig[c][1], *subs[3 * c : 3 * c + 3])
+            for c in range(k)
+        ]
 
         def body(_, carry):
             encs, _ = carry
             G = hash_all(encs)
             with jax.named_scope("fused.gather"):
                 Gf = jnp.concatenate([G, ext], axis=0)
-            new_encs = []
-            for c in range(k):
-                rows = subs[3 * c]
-                offs = subs[3 * c + 1]
-                child = subs[3 * c + 2]
-                with jax.named_scope("fused.gather"):
-                    vals = Gf[child]  # [nsubs, 32] u8
-                with jax.named_scope("fused.subst"), \
-                        jax.named_scope(f"c{sig[c][0]}"):
-                    rows32 = jnp.repeat(rows, 32)
-                    cols32 = (offs[:, None] + idx32).reshape(-1)
-                    new_encs.append(
-                        encs[c].at[rows32, cols32].set(vals.reshape(-1)))
-            return new_encs, G
+            return [
+                substitute(encs[c], plans[c], Gf) for c in range(k)
+            ], G
 
         # the carried digests are write-only inside the loop (each
         # round recomputes G), so the initial value is never read:
@@ -829,8 +947,9 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             of_class = np.flatnonzero(node_nb == nb)
             members[nb] = of_class
             rows = classes[nb] = [phs[i] for i in of_class.tolist()]
-            # +1 guarantees at least one spare padding row for dummy subs;
-            # pallas needs whole 1024-row tiles, the jnp path only pow-2
+            # +1 guarantees at least one spare padding row, the filler
+            # of the admit slots; pallas needs whole 1024-row tiles, the
+            # jnp path only pow-2
             n = len(rows) + 1
             nrows_pad[nb] = held.take(
                 (nb, "rows"),
@@ -887,9 +1006,10 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             lens = np.zeros(npad, dtype=np.int64)
             lens[: len(rows)] = enc_len[members[nb]]
             in_class = site_nb == nb
-            subs = np.stack(
-                [site_row[in_class], site_off[in_class],
-                 site_gpos[in_class]], axis=1)  # (row, off, child_gpos)
+            subs = np.stack(sorted_sites(
+                site_row[in_class], site_off[in_class],
+                site_gpos[in_class], width,
+            ), axis=1)  # (row, off, child_gpos) by (row, off)
             # padding rows still need valid keccak padding (their digests
             # are discarded, but the kernel hashes them): lens 0
             if npad > len(rows):
@@ -906,8 +1026,9 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
             # compile on the first window that hits it)
             nsubs = held.take((nb, "subs"), _pow2(
                 len(subs) + 1, floor=1024 if use_jnp else 4096))
-            dummy_row = nrows_pad[nb] - 1  # guaranteed padding row
-            sub_np = np.full((nsubs, 3), (dummy_row, 0, 0), dtype=np.int32)
+            # a padding substitution names the row past the last: the
+            # device drops it, and it stays last in (row, off) order
+            sub_np = np.full((nsubs, 3), (npad, 0, 0), dtype=np.int32)
             sub_np[: len(subs)] = subs
             live_subs += len(subs)
             enc_bufs.append(buf)
@@ -931,7 +1052,7 @@ def _fused_submit(to_resolve, deps, prefix, use_jnp, depth, ext,
                 # and how many of a class's rows are live is no count
                 # of the signature
                 nadmit = -(-nrows_pad[nb] // _MTILE) * _MTILE
-                aidx_np = np.full(nadmit, dummy_row, dtype=np.int32)
+                aidx_np = np.full(nadmit, npad - 1, dtype=np.int32)
                 aidx_np[:n_live] = aidx
                 akeys.extend([None] * (nadmit - n_live))
                 alens.extend([0] * (nadmit - n_live))

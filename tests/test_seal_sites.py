@@ -497,8 +497,9 @@ class _Capture:
         return run, 0.0
 
     def subs(self):
-        """{class: sorted [(row, off, child_gpos)]} as uploaded, the
-        dummies (the class's last padding row) taken out."""
+        """{class: [(row, off, child_gpos)]} as uploaded, in (row, off)
+        order, the padding (the row past the class's last, which the
+        device drops) taken out of the end."""
         n = len(self.sig)
         out = {}
         for c, (nb, nrows, nsubs, _) in enumerate(self.sig):
@@ -507,10 +508,11 @@ class _Capture:
                 self.inputs[n + 3 * c : n + 3 * c + 3])
             assert row.shape == off.shape == child.shape == (nsubs,)
             assert row.dtype == off.dtype == child.dtype == np.int32
-            real = row != nrows - 1
+            real = row < nrows
+            assert (row[~real] == nrows).all() and not real[real.sum():].any()
             assert not off[~real].any() and not child[~real].any()
-            out[nb] = sorted(zip(row[real].tolist(), off[real].tolist(),
-                                 child[real].tolist()))
+            out[nb] = list(zip(row[real].tolist(), off[real].tolist(),
+                               child[real].tolist()))
         return out
 
     def encodings(self):
