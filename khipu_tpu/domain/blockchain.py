@@ -22,10 +22,17 @@ from khipu_tpu.domain.receipt import Receipt, decode_receipts, encode_receipts
 from khipu_tpu.ledger.bloom import EMPTY_BLOOM
 from khipu_tpu.evm.dataword import to_minimal_bytes
 from khipu_tpu.ledger.world import BlockWorldState, TrieStorage
+from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.observability.trace import span
 from khipu_tpu.storage.storages import Storages
 from khipu_tpu.trie.bulk import bulk_build, device_hasher, host_hasher
 from khipu_tpu.trie.mpt import EMPTY_TRIE_HASH, MerklePatriciaTrie
+
+RECEIPT_LOGS = REGISTRY.counter(
+    "khipu_receipt_logs_total",
+    help="log entries in the receipts save_block stored "
+         "(domain/blockchain.py)",
+)
 
 
 @dataclass(frozen=True)
@@ -151,6 +158,7 @@ class Blockchain:
         s.block_header_storage.put(n, block.header.encode())
         s.block_body_storage.put(n, block.body.encode())
         s.receipts_storage.put(n, encode_receipts(receipts))
+        RECEIPT_LOGS.inc(sum(len(r.logs) for r in receipts))
         s.total_difficulty_storage.put_td(n, total_difficulty)
         s.block_numbers.put(block.hash, n)
         for i, tx in enumerate(block.body.transactions):

@@ -41,7 +41,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple, Union
 
 from khipu_tpu.base.crypto.secp256k1 import HALF_N
@@ -57,6 +57,34 @@ from khipu_tpu.ledger.rewards import block_rewards
 from khipu_tpu.ledger.world import BlockWorldState
 from khipu_tpu.observability.journey import JOURNEY
 from khipu_tpu.observability.profiler import HOST, LEDGER
+from khipu_tpu.observability.registry import REGISTRY
+
+# Which executor lane ran a block's transactions, and the wall seconds
+# each lane took: vector = execute_fast_batch + execute_call_batch,
+# checked / residue = run_captured in that role, optimistic / sequential
+# = the whole of _execute_optimistic / _execute_sequential. A block's
+# Stats carry both and execute_block books them here when it returns:
+# seconds as they were spent (a scheduled attempt that is thrown away
+# keeps its seconds), transactions by the lanes of the attempt that
+# stood, so they sum to the block's count.
+EXEC_LANES = ("vector", "checked", "residue", "optimistic", "sequential")
+LANE_SECONDS = {
+    lane: REGISTRY.counter(
+        "khipu_exec_lane_seconds_total",
+        help="wall seconds inside each execute lane (ledger/ledger.py)",
+        labels={"lane": lane},
+    )
+    for lane in EXEC_LANES
+}
+LANE_TXS = {
+    lane: REGISTRY.counter(
+        "khipu_exec_lane_txs_total",
+        help="transactions by the execute lane whose result stood "
+             "(ledger/ledger.py)",
+        labels={"lane": lane},
+    )
+    for lane in EXEC_LANES
+}
 
 
 class BlockExecutionError(Exception):
@@ -103,6 +131,14 @@ class Stats:
     fast_path_txs: int = 0  # txs through the vectorized batch executor
     residue_txs: int = 0  # txs through the serial interpreter residue
     mispredicted_txs: int = 0  # scheduled attempts discarded post-hoc
+    # per EXEC_LANES lane: txs of the attempt that stood (they sum to
+    # tx_count) and wall seconds spent, thrown-away attempts included
+    lane_txs: Dict[str, int] = field(
+        default_factory=lambda: dict.fromkeys(EXEC_LANES, 0))
+    lane_seconds: Dict[str, float] = field(
+        default_factory=lambda: dict.fromkeys(EXEC_LANES, 0.0))
+    batches: int = 0  # batch steps of the plan that stood
+    fallback: bool = False  # a scheduled attempt was thrown away
 
     @property
     def parallel_rate(self) -> float:
@@ -451,6 +487,7 @@ def execute_block(
                     stats.conflict_count = 0
                     stats.fast_path_txs = 0
                     stats.residue_txs = 0
+                    stats.fallback = True
                     world = None
                     rewards_paid = False
                 except ValidationAfterExecError:
@@ -472,20 +509,31 @@ def execute_block(
                     stats.conflict_count = 0
                     stats.fast_path_txs = 0
                     stats.residue_txs = 0
+                    stats.fallback = True
                     world = None
                     rewards_paid = False
                     validated_scheduled = False
             if world is None:
+                _t0 = time.perf_counter()
                 world, receipts, gas_used = _execute_optimistic(
                     config, block_env, txs, senders, parent_state_root,
                     make_world, header, khipu_config.sync.tx_workers,
                     stats,
                 )
+                stats.lane_seconds["optimistic"] += (
+                    time.perf_counter() - _t0)
+                # whatever a thrown-away attempt had counted goes
+                stats.lane_txs = dict.fromkeys(EXEC_LANES, 0)
+                stats.lane_txs["optimistic"] = len(txs)
+                stats.batches = 0
         else:
+            _t0 = time.perf_counter()
             world, receipts, gas_used = _execute_sequential(
                 config, block_env, txs, senders, parent_state_root,
                 make_world, header,
             )
+            stats.lane_seconds["sequential"] += time.perf_counter() - _t0
+            stats.lane_txs["sequential"] = len(txs)
     finally:
         if traced:
             from khipu_tpu.evm.vm import set_trace
@@ -496,6 +544,9 @@ def execute_block(
         _pay_rewards(world, block, khipu_config)
     stats.gas_used = gas_used
     stats.exec_seconds = time.perf_counter() - t0
+    for lane in EXEC_LANES:
+        LANE_TXS[lane].inc(stats.lane_txs[lane])
+        LANE_SECONDS[lane].inc(stats.lane_seconds[lane])
 
     if validate and not validated_scheduled:
         _validate_after(block, world, receipts, gas_used, check_root, hasher)
@@ -574,6 +625,7 @@ def _execute_scheduled(
     )
     stats.conflict_count += plan.conflicted
     trusted_used: Set[bytes] = set()
+    lanes = {"vector": 0, "checked": 0, "residue": 0}
 
     receipts: List[Receipt] = []
     outcomes: List[Optional[TxResult]] = [None] * len(txs)
@@ -638,13 +690,13 @@ def _execute_scheduled(
             )
             _t0 = time.perf_counter()
             captured = run_captured(i, accumulated_gas)
+            _dt = time.perf_counter() - _t0
             # host-side classification event: per-tx interpreter time,
             # so the cost model attributes execute-phase time to the
             # residue vs the vectorized batches
-            LEDGER.record(
-                "exec.residue", HOST, 0,
-                duration=time.perf_counter() - _t0,
-            )
+            LEDGER.record("exec.residue", HOST, 0, duration=_dt)
+            stats.lane_seconds["residue"] += _dt
+            lanes["residue"] += 1
             stats.residue_txs += 1
             if JOURNEY.enabled:
                 JOURNEY.record(txs[i].hash, "execute",
@@ -703,12 +755,12 @@ def _execute_scheduled(
                         }
                 _t0 = time.perf_counter()
                 captured = run_captured(i, 0)
+                _dt = time.perf_counter() - _t0
                 # checked template calls run the interpreter too —
                 # same cost bucket as the residue (per-tx EVM time)
-                LEDGER.record(
-                    "exec.residue", HOST, 0,
-                    duration=time.perf_counter() - _t0,
-                )
+                LEDGER.record("exec.residue", HOST, 0, duration=_dt)
+                stats.lane_seconds["checked"] += _dt
+                lanes["checked"] += 1
                 if not footprint_ok(
                     pred, captured["reads"], captured["written"]
                 ):
@@ -738,12 +790,12 @@ def _execute_scheduled(
         if call_items:
             _t0 = time.perf_counter()
             results = execute_call_batch(config, merged, call_items)
+            _dt = time.perf_counter() - _t0
             # vectorized templated-call time joins the transfer batch
             # in the exec.batch cost bucket
-            LEDGER.record(
-                "exec.batch", HOST, 0,
-                duration=time.perf_counter() - _t0,
-            )
+            LEDGER.record("exec.batch", HOST, 0, duration=_dt)
+            stats.lane_seconds["vector"] += _dt
+            lanes["vector"] += len(call_items)
             for (i, _, _, ch, _), r in zip(call_items, results):
                 outcomes[i] = r
                 trusted_used.add(ch)
@@ -753,18 +805,21 @@ def _execute_scheduled(
         if fast_items:
             _t0 = time.perf_counter()
             results = execute_fast_batch(config, merged, fast_items)
+            _dt = time.perf_counter() - _t0
             # host-side classification event: vectorized fast-path
             # time per batch (joins with exec.residue for the execute
             # cost-model breakdown)
-            LEDGER.record(
-                "exec.batch", HOST, 0,
-                duration=time.perf_counter() - _t0,
-            )
+            LEDGER.record("exec.batch", HOST, 0, duration=_dt)
+            stats.lane_seconds["vector"] += _dt
+            lanes["vector"] += len(fast_items)
             for (i, _, _), r in zip(fast_items, results):
                 outcomes[i] = r
             stats.fast_path_txs += len(fast_items)
             stats.parallel_count += len(fast_items)
     post_through(len(txs))
+    # only now: an attempt that raised above leaves no lane counts
+    stats.lane_txs.update(lanes)
+    stats.batches = sum(1 for st in plan.steps if st.kind == "batch")
     return merged, receipts, cumulative, trusted_used
 
 

@@ -1053,7 +1053,7 @@ class ReplayDriver:
                     ph["validate"] += time.perf_counter() - t0
                     t0 = time.perf_counter()
                     with span("execute", block=header.number,
-                              txs=len(block.body.transactions)):
+                              txs=len(block.body.transactions)) as sp:
                         result = execute_block(
                             block,
                             b"",  # the open session IS the parent state
@@ -1062,6 +1062,15 @@ class ReplayDriver:
                             validate=True,
                             check_root=False,  # deferred to finalize
                         )
+                        # the lanes whose result stood (they sum to
+                        # txs) with the seconds each took, and whether
+                        # a scheduled attempt was thrown away first
+                        st = result.stats
+                        for lane, n in st.lane_txs.items():
+                            sp.set_tag(lane, n)
+                            sp.set_tag(lane + "_s", st.lane_seconds[lane])
+                        sp.set_tag("batches", st.batches)
+                        sp.set_tag("fallback", int(st.fallback))
                     ph["execute"] += time.perf_counter() - t0
                     t0 = time.perf_counter()
                     committer.commit_block(
