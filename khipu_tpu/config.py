@@ -107,8 +107,8 @@ class SyncConfig:
     # conflict-aware scheduled execution (ledger/schedule.py): predict
     # read/write sets, pack disjoint batches, vectorize plain-transfer
     # batches (ledger/batch_exec.py), serial residue for everything
-    # unpredictable; mispredictions fall back to the optimistic path
-    # whole-block. False = always optimistic (the P1 oracle). Only
+    # unpredictable; a misprediction re-runs its segment of the plan
+    # serially. False = always optimistic (the P1 oracle). Only
     # engages for Byzantium+ blocks (pre-Byzantium receipts embed
     # intermediate roots, which forbid out-of-order execution)
     scheduled_tx: bool = True

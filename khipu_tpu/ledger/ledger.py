@@ -10,8 +10,12 @@ Parity: ledger/Ledger.scala:95 —
                                  :393-434); _execute_scheduled is the
                                  conflict-aware front end (schedule.py
                                  plans, batch_exec.py vectorizes the
-                                 plain-transfer batches, optimistic is
-                                 the misprediction fallback)
+                                 plain-transfer batches; a misprediction
+                                 rolls back to the last residue barrier
+                                 and re-runs that segment serially;
+                                 optimistic is the whole-block fallback
+                                 for an invalid tx or a trusted
+                                 template's wrong root)
   validateAndExecuteTransaction:517 -> _validate_stx + execute_transaction
   prepareProgramContext:660   -> inside execute_transaction
   runVM:710                   -> khipu_tpu.evm.vm
@@ -64,9 +68,10 @@ from khipu_tpu.observability.registry import REGISTRY
 # checked / residue = run_captured in that role, optimistic / sequential
 # = the whole of _execute_optimistic / _execute_sequential. A block's
 # Stats carry both and execute_block books them here when it returns:
-# seconds as they were spent (a scheduled attempt that is thrown away
-# keeps its seconds), transactions by the lanes of the attempt that
-# stood, so they sum to the block's count.
+# seconds as they were spent (a scheduled attempt or a rolled-back
+# segment that is thrown away keeps its seconds), transactions by the
+# lanes of the attempt that stood (a re-run segment's under residue),
+# so they sum to the block's count.
 EXEC_LANES = ("vector", "checked", "residue", "optimistic", "sequential")
 LANE_SECONDS = {
     lane: REGISTRY.counter(
@@ -130,7 +135,9 @@ class Stats:
     exec_seconds: float = 0.0
     fast_path_txs: int = 0  # txs through the vectorized batch executor
     residue_txs: int = 0  # txs through the serial interpreter residue
-    mispredicted_txs: int = 0  # scheduled attempts discarded post-hoc
+    mispredicted_txs: int = 0  # footprint escapes (each demotes a hash)
+    reruns: int = 0  # segments rolled back to their barrier and re-run
+    rerun_txs: int = 0  # transactions in those segments
     # per EXEC_LANES lane: txs of the attempt that stood (they sum to
     # tx_count) and wall seconds spent, thrown-away attempts included
     lane_txs: Dict[str, int] = field(
@@ -138,7 +145,7 @@ class Stats:
     lane_seconds: Dict[str, float] = field(
         default_factory=lambda: dict.fromkeys(EXEC_LANES, 0.0))
     batches: int = 0  # batch steps of the plan that stood
-    fallback: bool = False  # a scheduled attempt was thrown away
+    fallback: bool = False  # the whole scheduled attempt was thrown away
 
     @property
     def parallel_rate(self) -> float:
@@ -440,6 +447,27 @@ def execute_block(
                     Misprediction,
                 )
 
+                def void_attempt() -> None:
+                    """The scheduled attempt is void: its world AND its
+                    counts go (its seconds stay), and the whole block
+                    re-runs on the optimistic path, which owns the
+                    authoritative outcome (correctness never depends
+                    on prediction)."""
+                    EXEC_GAUGES["fallbacks"] += 1
+                    if JOURNEY.enabled:
+                        for stx in txs:
+                            JOURNEY.record(stx.hash, "execute",
+                                           lane="serial-fallback",
+                                           rerun=True,
+                                           block=header.number)
+                    stats.parallel_count = 0
+                    stats.conflict_count = 0
+                    stats.fast_path_txs = 0
+                    stats.residue_txs = 0
+                    stats.reruns = 0
+                    stats.rerun_txs = 0
+                    stats.fallback = True
+
                 trusted_used = set()
                 try:
                     world, receipts, gas_used, trusted_used = (
@@ -464,10 +492,10 @@ def execute_block(
                         )
                         validated_scheduled = True
                 except (Misprediction, TxValidationError) as e:
-                    # the scheduled attempt is void: discard its world
-                    # AND its stats, then re-run the whole block on the
-                    # optimistic path, which owns the authoritative
-                    # outcome (correctness never depends on prediction)
+                    # an invalid tx names no segment to roll back to.
+                    # A Misprediction is the backstop only:
+                    # _execute_scheduled recovers from its own, inside
+                    # the segment that held the call
                     if isinstance(e, Misprediction):
                         stats.mispredicted_txs += 1
                         EXEC_GAUGES["mispredictions"] += 1
@@ -476,40 +504,19 @@ def execute_block(
                                            "mispredict",
                                            reason=e.detail,
                                            block=header.number)
-                    EXEC_GAUGES["fallbacks"] += 1
-                    if JOURNEY.enabled:
-                        for stx in txs:
-                            JOURNEY.record(stx.hash, "execute",
-                                           lane="serial-fallback",
-                                           rerun=True,
-                                           block=header.number)
-                    stats.parallel_count = 0
-                    stats.conflict_count = 0
-                    stats.fast_path_txs = 0
-                    stats.residue_txs = 0
-                    stats.fallback = True
+                    void_attempt()
                     world = None
                     rewards_paid = False
                 except ValidationAfterExecError:
                     if not trusted_used:
                         raise  # scheduled-but-unvectorized roots are
                         # authoritative — this would be a real bug
+                    # a wrong root names no segment either
                     for ch in trusted_used:
                         LEARNER.demote(ch)
                     stats.mispredicted_txs += 1
                     EXEC_GAUGES["mispredictions"] += 1
-                    EXEC_GAUGES["fallbacks"] += 1
-                    if JOURNEY.enabled:
-                        for stx in txs:
-                            JOURNEY.record(stx.hash, "execute",
-                                           lane="serial-fallback",
-                                           rerun=True,
-                                           block=header.number)
-                    stats.parallel_count = 0
-                    stats.conflict_count = 0
-                    stats.fast_path_txs = 0
-                    stats.residue_txs = 0
-                    stats.fallback = True
+                    void_attempt()
                     world = None
                     rewards_paid = False
                     validated_scheduled = False
@@ -596,13 +603,28 @@ def _execute_scheduled(
     predicted tx may touch the beneficiary (the planner routes those
     to the residue), so deferring fee posting is invisible.
 
+    A misprediction costs its SEGMENT, not the block. A segment is the
+    run of batch steps between two residue barriers (or the block's
+    ends): at its start everything earlier has executed and posted, so
+    the merged world there is the exact sequential state, and nothing
+    posts inside it. A segment that holds a checked call (the only
+    kind that can escape) starts from a checkpoint of the merged world;
+    when a checked call's footprint escapes, its code hash is demoted,
+    the world goes back to the checkpoint and the segment's txs run in
+    index order through the residue step's own body (run_serial),
+    which IS the sequential semantics; then the plan goes on. A later
+    segment that calls a hash demoted earlier in the block is run that
+    way at once, not attempted.
+
     Returns (world, receipts, gas_used, trusted_used) where
     ``trusted_used`` is the set of code hashes whose calls executed
-    vectorized — execute_block's header-oracle backstop demotes them
-    all if the block root comes out wrong.
+    vectorized (a rolled-back batch's included: a superset only widens
+    the backstop) — execute_block's header-oracle backstop demotes
+    them all if the block root comes out wrong.
 
-    Raises schedule.Misprediction or TxValidationError to demand the
-    whole-block optimistic fallback (caller: execute_block).
+    Raises TxValidationError to demand the whole-block optimistic
+    fallback (caller: execute_block); a schedule.Misprediction is
+    caught here, and would demand the same if one ever got out.
     """
     from khipu_tpu.ledger.batch_call import execute_call_batch
     from khipu_tpu.ledger.batch_exec import execute_fast_batch
@@ -611,6 +633,7 @@ def _execute_scheduled(
         EMPTY_CODE_HASH,
         EXEC_GAUGES,
         LEARNER,
+        RESIDUE,
         Misprediction,
         Template,
         _apply_rules,
@@ -626,6 +649,8 @@ def _execute_scheduled(
     stats.conflict_count += plan.conflicted
     trusted_used: Set[bytes] = set()
     lanes = {"vector": 0, "checked": 0, "residue": 0}
+    batches = 0
+    demoted: Set[bytes] = set()  # code hashes that escaped in THIS block
 
     receipts: List[Receipt] = []
     outcomes: List[Optional[TxResult]] = [None] * len(txs)
@@ -680,44 +705,73 @@ def _execute_scheduled(
         outcomes[i] = r
         return captured
 
-    for step in plan.steps:
-        if step.kind == "residue":
-            i = step.indices[0]
-            post_through(i)  # the residue sees exact sequential state
-            tx = txs[i].tx
-            code_hash = (
-                merged.get_code_hash(tx.to) if tx.to is not None else None
+    escaped = "actual footprint escaped prediction"
+
+    def note_escape(i: int, code_hash: bytes) -> None:
+        """Tx i's actual footprint lies outside its prediction: its
+        template lied, so the hash goes opaque for good."""
+        LEARNER.demote(code_hash)
+        demoted.add(plan.predicted[i].code_hash)
+        stats.mispredicted_txs += 1
+        EXEC_GAUGES["mispredictions"] += 1
+        if JOURNEY.enabled:
+            JOURNEY.record(txs[i].hash, "mispredict", reason=escaped,
+                           block=header.number)
+
+    def run_serial(i: int, rerun: bool = False) -> None:
+        """Tx i on the exact sequential state: every earlier tx's fee
+        posts first, it validates against the true running gas total,
+        and it posts before anything later runs. The residue step, and
+        what a rolled-back segment runs each of its txs through (a
+        predicted call among them is still held to its prediction: the
+        result stands either way, but a second template that lies in
+        the same segment is un-learnt in the same block)."""
+        post_through(i)
+        tx = txs[i].tx
+        code_hash = (
+            merged.get_code_hash(tx.to) if tx.to is not None else None
+        )
+        _t0 = time.perf_counter()
+        captured = run_captured(i, accumulated_gas)
+        _dt = time.perf_counter() - _t0
+        # host-side classification event: per-tx interpreter time,
+        # so the cost model attributes execute-phase time to the
+        # residue vs the vectorized batches
+        LEDGER.record("exec.residue", HOST, 0, duration=_dt)
+        stats.lane_seconds["residue"] += _dt
+        lanes["residue"] += 1
+        stats.residue_txs += 1
+        if JOURNEY.enabled:
+            JOURNEY.record(txs[i].hash, "execute", lane="residue",
+                           index=i, **({"rerun": True} if rerun else {}))
+        pred = plan.predicted.get(i)
+        if (pred is not None and pred.code_hash is not None
+                and pred.code_hash not in demoted
+                and not footprint_ok(
+                    pred, captured["reads"], captured["written"])):
+            note_escape(i, code_hash)
+        if (
+            code_hash is not None
+            and code_hash != EMPTY_CODE_HASH
+            and senders[i] is not None
+            and outcomes[i].error is None
+            and outcomes[i].status == 1
+        ):
+            # teach the learner from successful template-shaped
+            # calls only — error/revert paths have partial
+            # footprints that would under-predict (a verdict the
+            # learner already holds stands: a re-run teaches nothing)
+            LEARNER.observe(
+                code_hash, senders[i], tx.to, tx.payload,
+                captured["reads"], captured["written"],
+                code=merged.get_code(tx.to),
             )
-            _t0 = time.perf_counter()
-            captured = run_captured(i, accumulated_gas)
-            _dt = time.perf_counter() - _t0
-            # host-side classification event: per-tx interpreter time,
-            # so the cost model attributes execute-phase time to the
-            # residue vs the vectorized batches
-            LEDGER.record("exec.residue", HOST, 0, duration=_dt)
-            stats.lane_seconds["residue"] += _dt
-            lanes["residue"] += 1
-            stats.residue_txs += 1
-            if JOURNEY.enabled:
-                JOURNEY.record(txs[i].hash, "execute",
-                               lane="residue", index=i)
-            if (
-                code_hash is not None
-                and code_hash != EMPTY_CODE_HASH
-                and senders[i] is not None
-                and outcomes[i].error is None
-                and outcomes[i].status == 1
-            ):
-                # teach the learner from successful template-shaped
-                # calls only — error/revert paths have partial
-                # footprints that would under-predict
-                LEARNER.observe(
-                    code_hash, senders[i], tx.to, tx.payload,
-                    captured["reads"], captured["written"],
-                    code=merged.get_code(tx.to),
-                )
-            post_through(i + 1)
-            continue
+        post_through(i + 1)
+
+    def run_batch(step, counts: Dict[str, int]) -> None:
+        """One batch step of a segment on the merged world; ``counts``
+        takes its txs by lane, booked only if the segment stands.
+        Raises Misprediction at the first checked call that escapes."""
         fast_items = []
         call_items = []
         for i in step.indices:
@@ -760,15 +814,12 @@ def _execute_scheduled(
                 # same cost bucket as the residue (per-tx EVM time)
                 LEDGER.record("exec.residue", HOST, 0, duration=_dt)
                 stats.lane_seconds["checked"] += _dt
-                lanes["checked"] += 1
+                counts["checked"] += 1
                 if not footprint_ok(
                     pred, captured["reads"], captured["written"]
                 ):
-                    LEARNER.demote(code_hash)
-                    raise Misprediction(
-                        i, "actual footprint escaped prediction"
-                    )
-                stats.parallel_count += 1
+                    note_escape(i, code_hash)
+                    raise Misprediction(i, escaped)
                 EXEC_GAUGES["checked_call_txs"] += 1
                 if JOURNEY.enabled:
                     JOURNEY.record(txs[i].hash, "execute",
@@ -795,12 +846,10 @@ def _execute_scheduled(
             # in the exec.batch cost bucket
             LEDGER.record("exec.batch", HOST, 0, duration=_dt)
             stats.lane_seconds["vector"] += _dt
-            lanes["vector"] += len(call_items)
+            counts["vector"] += len(call_items)
             for (i, _, _, ch, _), r in zip(call_items, results):
                 outcomes[i] = r
                 trusted_used.add(ch)
-            stats.fast_path_txs += len(call_items)
-            stats.parallel_count += len(call_items)
             EXEC_GAUGES["vector_call_txs"] += len(call_items)
         if fast_items:
             _t0 = time.perf_counter()
@@ -811,15 +860,74 @@ def _execute_scheduled(
             # cost-model breakdown)
             LEDGER.record("exec.batch", HOST, 0, duration=_dt)
             stats.lane_seconds["vector"] += _dt
-            lanes["vector"] += len(fast_items)
+            counts["vector"] += len(fast_items)
             for (i, _, _), r in zip(fast_items, results):
                 outcomes[i] = r
-            stats.fast_path_txs += len(fast_items)
-            stats.parallel_count += len(fast_items)
+
+    def attempt(segment, checked: bool) -> bool:
+        """The segment's batch steps on the merged world. False: a
+        checked call escaped and the world is back at the segment's
+        start, with nothing of the attempt booked but its seconds."""
+        nonlocal merged, batches
+        # only a checked call can escape, so only a segment that holds
+        # one pays for a checkpoint. Taken outside run_captured, which
+        # swaps reads/written: copy() shares ``reads`` (a superset
+        # after a rollback is harmless, as after a reverted frame) and
+        # copies ``written``
+        checkpoint = merged.copy() if checked else None
+        counts = {"vector": 0, "checked": 0}
+        try:
+            for step in segment:
+                run_batch(step, counts)
+        except Misprediction:
+            if checkpoint is None:
+                raise  # no checked call, no escape: the backstop's
+            merged = checkpoint
+            return False
+        lanes["vector"] += counts["vector"]
+        lanes["checked"] += counts["checked"]
+        stats.fast_path_txs += counts["vector"]
+        stats.parallel_count += counts["vector"] + counts["checked"]
+        batches += len(segment)
+        return True
+
+    steps = plan.steps
+    k = 0
+    while k < len(steps):
+        if steps[k].kind == RESIDUE:
+            run_serial(steps[k].indices[0])
+            k += 1
+            continue
+        end = k + 1
+        while end < len(steps) and steps[end].kind != RESIDUE:
+            end += 1
+        segment, k = steps[k:end], end
+        # a segment that calls a hash this block has already seen
+        # escape is not attempted at all
+        checked = known_escape = False
+        for step in segment:
+            for i in step.indices:
+                code_hash = plan.predicted[i].code_hash
+                if code_hash is None:
+                    continue
+                if code_hash in demoted:
+                    known_escape = True
+                elif i not in plan.trusted:
+                    checked = True
+        if not known_escape and attempt(segment, checked):
+            continue
+        indices = sorted(i for step in segment for i in step.indices)
+        for i in indices:
+            run_serial(i, rerun=not known_escape)
+        if not known_escape:
+            stats.reruns += 1
+            stats.rerun_txs += len(indices)
+            EXEC_GAUGES["segment_reruns"] += 1
+            EXEC_GAUGES["rerun_txs"] += len(indices)
     post_through(len(txs))
     # only now: an attempt that raised above leaves no lane counts
     stats.lane_txs.update(lanes)
-    stats.batches = sum(1 for st in plan.steps if st.kind == "batch")
+    stats.batches = batches
     return merged, receipts, cumulative, trusted_used
 
 

@@ -10,11 +10,13 @@ into maximal batches via greedy precedence-respecting coloring, and
 route everything unpredictable to a serial residue. Batches then
 execute with zero merge conflicts BY CONSTRUCTION (the fast path
 skips the snapshot+merge machinery entirely); a post-hoc comparison
-of actual vs predicted touched sets catches every misprediction and
-falls the whole block back to the optimistic path — correctness never
-depends on a prediction being right (Block-STM-style scheduled OCC,
-but scheduling conflicts away up front instead of aborting into
-them).
+of actual vs predicted touched sets catches every misprediction, and
+the executor rolls the merged world back to the last residue barrier
+and runs that SEGMENT of the plan again in index order, from the exact
+sequential state the barrier left — correctness never depends on a
+prediction being right (Block-STM-style scheduled OCC, but scheduling
+conflicts away up front instead of aborting into them, and an abort
+costs the transactions since the last barrier, not the block).
 
 Footprint algebra (mirrors the world's merge categories):
 
@@ -46,8 +48,8 @@ tx's own fields (int(sender), int(arg_i), a small literal slot, or
 the Solidity mapping form keccak(pad32(x) ++ pad32(k))) for the code
 hash to earn a template. Underivable slots (state-dependent indexing)
 mark the hash OPAQUE — permanently residue. A template whose
-prediction a later tx violates is demoted to opaque and the block
-falls back.
+prediction a later tx violates is demoted to opaque and the segment
+that held the call is re-run serially (ledger._execute_scheduled).
 
 Templated calls graduate through a three-phase trust protocol:
 
@@ -74,7 +76,9 @@ Templated calls graduate through a three-phase trust protocol:
   precondition validation, net storage deltas + EIP-2200 gas applied
   bit-exactly. The ``_validate_after`` header oracle backstops the
   whole scheme: a trusted template that ever produces a wrong root
-  demotes and the block re-runs optimistically.
+  demotes and the block re-runs optimistically (the one whole-block
+  fallback left besides an invalid transaction: a wrong root names no
+  segment to roll back to).
 """
 
 from __future__ import annotations
@@ -104,8 +108,10 @@ try:  # one registry family for the whole execute stage
         "residue_txs": 0,
         "batches": 0,
         "max_batch_width": 0,
-        "mispredictions": 0,
-        "fallbacks": 0,
+        "mispredictions": 0,  # footprint escapes (each demotes a hash)
+        "segment_reruns": 0,  # segments rolled back and re-run serially
+        "rerun_txs": 0,  # transactions in those segments
+        "fallbacks": 0,  # whole blocks re-run on the optimistic path
         "templates": 0,
         "opaque_codes": 0,
         "vector_call_txs": 0,  # trusted templated calls, vectorized
@@ -117,17 +123,21 @@ except Exception:  # pragma: no cover - stdlib-only fallback
     EXEC_GAUGES = {
         k: 0 for k in (
             "planned_blocks", "fast_txs", "call_txs", "residue_txs",
-            "batches", "max_batch_width", "mispredictions", "fallbacks",
-            "templates", "opaque_codes", "vector_call_txs",
+            "batches", "max_batch_width", "mispredictions",
+            "segment_reruns", "rerun_txs", "fallbacks", "templates",
+            "opaque_codes", "vector_call_txs",
             "checked_call_txs", "trusted_templates", "effect_retirements",
         )
     }
 
 
 class Misprediction(Exception):
-    """A predicted tx touched state outside its predicted footprint —
-    the scheduled execution is discarded and the block re-runs on the
-    optimistic path (which never trusts predictions)."""
+    """A predicted tx touched state outside its predicted footprint.
+    ledger._execute_scheduled raises and catches it inside one segment
+    (roll back to the last barrier, re-run the segment serially); one
+    that ever reaches execute_block discards the scheduled execution
+    and re-runs the block on the optimistic path (which never trusts
+    predictions)."""
 
     def __init__(self, index: int, detail: str):
         super().__init__(f"tx[{index}]: {detail}")
@@ -157,6 +167,9 @@ class Predicted:
     slots: frozenset  # of (address, key) — read+write
     code_r: frozenset
     acct_w: frozenset = frozenset()
+    # CALL only: the target's code hash at plan time, so the executor
+    # can tell a call whose template has been demoted since
+    code_hash: Optional[bytes] = None
 
 
 @dataclass
@@ -940,6 +953,7 @@ def _classify(stx, sender: Optional[bytes], beneficiary: bytes,
         acct_d=frozenset(acct_d),
         slots=frozenset((to, s) for s in slots),
         code_r=frozenset((to,)),
+        code_hash=code_hash,
     ), trusted
 
 

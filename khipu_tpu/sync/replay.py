@@ -1063,13 +1063,17 @@ class ReplayDriver:
                             check_root=False,  # deferred to finalize
                         )
                         # the lanes whose result stood (they sum to
-                        # txs) with the seconds each took, and whether
-                        # a scheduled attempt was thrown away first
+                        # txs) with the seconds each took, the segments
+                        # rolled back and re-run serially with their
+                        # txs, and whether the whole scheduled attempt
+                        # was thrown away first
                         st = result.stats
                         for lane, n in st.lane_txs.items():
                             sp.set_tag(lane, n)
                             sp.set_tag(lane + "_s", st.lane_seconds[lane])
                         sp.set_tag("batches", st.batches)
+                        sp.set_tag("reruns", st.reruns)
+                        sp.set_tag("rerun_txs", st.rerun_txs)
                         sp.set_tag("fallback", int(st.fallback))
                     ph["execute"] += time.perf_counter() - t0
                     t0 = time.perf_counter()

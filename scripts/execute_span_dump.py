@@ -1,0 +1,47 @@
+#!/usr/bin/env python3
+"""One benchmark run with the window's ``execute`` spans written out.
+
+    python3 <repo>/scripts/execute_span_dump.py <out.jsonl> --workload <cell> \
+        --seed <n> --seconds <s> --trace 1
+
+Runs ``benchmark/run.py`` of the checkout it is started in (the working
+tree, or a copy of another commit) with the arguments after the first,
+and writes one JSON line per ``execute`` span of the window to
+``out.jsonl``: the span's seconds and its tags (the lanes' transactions
+and seconds, ``batches``, ``fallback``, and from PR 35 ``reruns`` /
+``rerun_txs``). What no per-layer metric reads yet is read from this
+dump: the residue lane's seconds a transaction on blocks that stood,
+``optimistic_s`` a block on blocks that fell back, sum(rerun_txs) /
+sum(txs). Edits nothing under ``benchmark/``: it wraps ``run.per_layer``,
+which is handed the driver's artefacts.
+"""
+
+import json
+import os
+import sys
+
+
+def main() -> int:
+    out_path = os.path.abspath(sys.argv[1])
+    sys.path.insert(0, os.getcwd())
+    from benchmark import run
+
+    inner = run.per_layer
+
+    def per_layer(cell_name, outcome):
+        spans = [s for s in outcome.artefacts.get("spans") or []
+                 if s.name == "execute"]
+        os.makedirs(os.path.dirname(out_path), exist_ok=True)
+        with open(out_path, "w") as f:
+            for s in spans:
+                f.write(json.dumps({"seconds": s.t1 - s.t0, **s.tags}) + "\n")
+        print(f"execute spans: {len(spans)} written to {out_path}",
+              flush=True)
+        return inner(cell_name, outcome)
+
+    run.per_layer = per_layer
+    return run.main(sys.argv[2:])
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -837,3 +837,172 @@ class TestExecPool:
         assert c is not b  # shutdown releases; next call rebuilds
         assert c.submit(lambda: 41 + 1).result() == 42
         shutdown_exec_pool()
+
+
+# ------------------------------------------- segment-local recovery
+
+
+class TestSegmentRollback:
+    """A misprediction rolls the merged world back to the last residue
+    barrier and re-runs that segment serially (ISSUE 35). Blocks are
+    built by the sequential executor and executed with validate=True:
+    gas, receipts root, bloom and state root are held to its headers."""
+
+    XOR_RUNTIME = TestMispredictionFallback.XOR_RUNTIME
+    BASE = 0x50000  # above the literal-slot rule's ceiling
+
+    @staticmethod
+    def _execute(blocks, cfg):
+        from khipu_tpu.ledger.ledger import execute_block
+
+        reset_templates()
+        bc = _fresh(cfg)
+        parent = bc.get_header_by_number(0)
+        stats = []
+        for block in blocks:
+            result = execute_block(
+                block, parent.state_root, bc.get_world_state, cfg)
+            bc.save_block(block, result.receipts, block.header.difficulty,
+                          result.world)
+            stats.append(result.stats)
+            parent = block.header
+        assert bc.get_header_by_number(len(blocks)).hash == blocks[-1].hash
+        return stats, bc
+
+    def _xor_call(self, xor):
+        def call(i, nonce, a0, a1):
+            return tx(
+                i, nonce, xor, 0, gas=100_000,
+                payload=a0.to_bytes(32, "big") + a1.to_bytes(32, "big"),
+            )
+        return call
+
+    def test_an_escape_into_a_slot_a_later_tx_already_used_needs_the_rollback(
+            self):
+        """The contaminating order. h and i are predicted on one slot, so
+        i waits for batch 1; j (a higher index) is predicted elsewhere
+        and runs in batch 0, BEFORE i. i's real write lands on j's slot:
+        serially j's SSTORE is a no-op (800 gas) on what i stored, in
+        the attempt it was the first store (20,000). Keeping the
+        attempt's prefix would keep j's wrong gas; only running the
+        segment again from the checkpoint, in index order, is exact."""
+        from khipu_tpu.domain.transaction import recover_senders
+
+        seq = _cfg(parallel=False)
+        builder = ChainBuilder(
+            Blockchain(Storages(), seq), seq, GenesisSpec(alloc=ALLOC)
+        )
+        xor = contract_address(ADDRS[0], 0)
+        call = self._xor_call(xor)
+        B = self.BASE
+        blocks = [
+            builder.add_block(
+                [tx(0, 0, None, 0, gas=500_000,
+                    payload=_init_code(self.XOR_RUNTIME)),
+                 tx(4, 0, ADDRS[10], 3)],
+                coinbase=MINER,
+            ),
+            # learning call: arg1=0 -> slot == arg0 -> ("arg", 0)
+            builder.add_block(
+                [call(1, 0, B + 9, 0), tx(5, 0, ADDRS[10], 3)],
+                coinbase=MINER,
+            ),
+            builder.add_block(
+                [call(2, 0, B, 0),      # h: slot B
+                 call(3, 0, B, 7),      # i: predicted B, writes B ^ 7
+                 call(6, 0, B ^ 7, 0),  # j: slot B ^ 7
+                 tx(7, 0, ADDRS[8], 9)],
+                coinbase=MINER,
+            ),
+        ]
+        cfg = _cfg()
+        stats, bc = self._execute(blocks[:2], cfg)
+        # the plan the executor will make of block 3: j before i
+        txs = list(blocks[2].body.transactions)
+        recover_senders(txs)
+        plan = plan_block(
+            txs, [t.sender for t in txs], MINER,
+            bc.get_world_state(blocks[1].header.state_root).get_code_hash,
+        )
+        assert [s.indices for s in plan.steps] == [[0, 2, 3], [1]]
+        stats, bc = self._execute(blocks, cfg)
+        escaped = stats[2]
+        assert not escaped.fallback and escaped.mispredicted_txs == 1
+        assert (escaped.reruns, escaped.rerun_txs) == (1, 4)
+        assert escaped.lane_txs["residue"] == 4
+        h, i, j = (r.cumulative_gas_used for r in bc.get_receipts(3)[:3])
+        # h and i each stored first (20,000); j found i's value (800)
+        assert h > 40_000 and i - h > 40_000 and j - i < 25_000
+
+    def test_a_rolled_back_segment_with_vector_calls_and_transfers(self):
+        """The escaping call sits in batch 1, so batch 0's trusted
+        (vectorised) token calls and plain transfers have already
+        changed the merged world when it escapes: the rollback undoes
+        them, and the serial re-run books all of them under residue."""
+        from khipu_tpu.ledger.schedule import EXEC_GAUGES
+
+        seq = _cfg(parallel=False)
+        builder = ChainBuilder(
+            Blockchain(Storages(), seq), seq, GenesisSpec(alloc=ALLOC)
+        )
+        token = contract_address(ADDRS[0], 0)
+        xor = contract_address(ADDRS[1], 0)
+        call = self._xor_call(xor)
+        B = self.BASE
+        holders = [
+            bytes.fromhex("%040x" % (0xE20E2000 + i)) for i in range(4)
+        ]
+
+        def send(i, nonce, rcpt, amount):
+            return tx(
+                i, nonce, token, 0, gas=200_000,
+                payload=rcpt.rjust(32, b"\x00")
+                + amount.to_bytes(32, "big"),
+            )
+
+        blocks = [
+            builder.add_block(
+                [tx(0, 0, None, 0, gas=500_000,
+                    payload=_codecopy_init(_ERC20_RUNTIME)),
+                 tx(1, 0, None, 0, gas=500_000,
+                    payload=_init_code(self.XOR_RUNTIME))],
+                coinbase=MINER,
+            ),
+            # observed in the residue: one template each
+            builder.add_block(
+                [send(2, 0, holders[0], 100), call(3, 0, B + 9, 0)],
+                coinbase=MINER,
+            ),
+            # two checked token calls: TRUST_AFTER confirmations
+            builder.add_block(
+                [send(2, 1, holders[1], 7), send(4, 0, holders[2], 8)],
+                coinbase=MINER,
+            ),
+            builder.add_block(
+                [send(2, 2, holders[3], 5),   # trusted: vector
+                 call(5, 0, B, 0),            # checked, stands
+                 send(4, 1, holders[0], 6),   # trusted: vector
+                 tx(6, 0, ADDRS[10], 3),      # plain: vector
+                 call(7, 0, B, 7),            # batch 1: escapes
+                 tx(8, 0, ADDRS[11], 4)],
+                coinbase=MINER,
+            ),
+        ]
+        vector_calls = EXEC_GAUGES["vector_call_txs"]
+        stats, bc = self._execute(blocks, _cfg())
+        assert stats[2].lane_txs["checked"] == 2
+        escaped = stats[3]
+        assert not escaped.fallback and escaped.mispredicted_txs == 1
+        assert (escaped.reruns, escaped.rerun_txs) == (1, 6)
+        assert escaped.lane_txs["residue"] == 6
+        assert escaped.lane_txs["vector"] == 0 and escaped.fast_path_txs == 0
+        assert escaped.lane_seconds["vector"] > 0  # the attempt's, kept
+        # the attempt did vectorise the two trusted calls before it
+        # was rolled back
+        assert EXEC_GAUGES["vector_call_txs"] == vector_calls + 2
+        erc20_hash = bc.get_world_state(
+            blocks[0].header.state_root).get_code_hash(token)
+        xor_hash = bc.get_world_state(
+            blocks[0].header.state_root).get_code_hash(xor)
+        assert LEARNER.lookup(xor_hash) == "opaque"
+        assert LEARNER.lookup(erc20_hash) != "opaque"
