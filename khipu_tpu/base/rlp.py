@@ -154,7 +154,7 @@ rlp_decode = _py_rlp_decode  # rebound to the C codec below when built
 # callers that imported the names BY VALUE during the compile get the
 # fast codec, and no import ever stalls on a gcc subprocess.
 def _bind_rlp_ext(forwarded: bool) -> bool:
-    global rlp_encode, rlp_decode
+    global rlp_encode, rlp_decode, native_ext
     try:
         from khipu_tpu.native.build import load_rlp_ext
 
@@ -167,12 +167,18 @@ def _bind_rlp_ext(forwarded: bool) -> bool:
             _impl[1] = ext.decode
         rlp_encode = ext.encode  # type: ignore[assignment]
         rlp_decode = ext.decode  # type: ignore[assignment]
+        native_ext = ext
         return True
     except Exception:  # toolchain quirks must never break the codec
         return False
 
 
 _impl = [_py_rlp_encode, _py_rlp_decode]
+# The bound extension module, None until (and unless) it is built and
+# loaded. What is not a codec reads it on every use instead of binding
+# by value: the trie's look-up (trie/mpt.py) takes its C walk from here
+# and its Python walk while this is None.
+native_ext = None
 
 
 def _init_rlp_ext() -> None:

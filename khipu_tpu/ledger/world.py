@@ -85,7 +85,7 @@ class TrieStorage:
 
     def load_original(self, key: int) -> int:
         """Committed (start-of-tx) value — EIP-2200's 'original'."""
-        raw = self.trie.get(self.key_bytes(key))
+        raw = self.trie.get_hashed(key.to_bytes(32, "big"))
         if raw is None:
             return 0
         return from_bytes(rlp_decode(raw))

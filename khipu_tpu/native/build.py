@@ -47,7 +47,10 @@ def _sources() -> List[str]:
 
 
 def _ext_sources() -> List[str]:
-    return [os.path.join(_CSRC_EXT, "rlp_ext.c")]
+    # the sponge is compiled in: the trie walk hashes its keys with no
+    # ctypes call between
+    return [os.path.join(_CSRC_EXT, "rlp_ext.c"),
+            os.path.join(_CSRC, "keccak.cc")]
 
 
 def _lib_cmd() -> List[str]:
@@ -56,7 +59,9 @@ def _lib_cmd() -> List[str]:
 
 
 def _ext_cmd() -> List[str]:
-    return ["gcc", "-O3", "-shared", "-fPIC",
+    # hidden: the extension's khipu_keccak is its own, whatever else
+    # the process has loaded (PyMODINIT_FUNC exports the init)
+    return ["gcc", "-O3", "-shared", "-fPIC", "-fvisibility=hidden",
             f"-I{sysconfig.get_paths()['include']}"]
 
 

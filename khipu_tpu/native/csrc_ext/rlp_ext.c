@@ -1,4 +1,5 @@
-/* RLP codec as a CPython extension — the hot host loop of trie commits.
+/* RLP codec as a CPython extension — the hot host loop of trie commits
+ * — and, further down, the trie's look-up walk over the nodes it decodes.
  *
  * Semantics are bit-identical to khipu_tpu/base/rlp.py (the pure-Python
  * reference implementation, kept as the no-toolchain fallback and as
@@ -266,17 +267,22 @@ static PyObject *dec_at(const unsigned char *d, Py_ssize_t len,
   return items;
 }
 
-static PyObject *py_decode(PyObject *self, PyObject *arg) {
-  Py_buffer view;
-  if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return NULL;
+/* One item that fills the whole buffer, as `decode` promises. */
+static PyObject *dec_exact(const unsigned char *d, Py_ssize_t len) {
   Py_ssize_t end;
-  PyObject *item =
-      dec_at((const unsigned char *)view.buf, view.len, 0, &end, 0);
-  if (item && end != view.len) {
+  PyObject *item = dec_at(d, len, 0, &end, 0);
+  if (item && end != len) {
     Py_DECREF(item);
     item = NULL;
     set_err("trailing bytes after RLP item");
   }
+  return item;
+}
+
+static PyObject *py_decode(PyObject *self, PyObject *arg) {
+  Py_buffer view;
+  if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return NULL;
+  PyObject *item = dec_exact((const unsigned char *)view.buf, view.len);
   PyBuffer_Release(&view);
   return item;
 }
@@ -420,6 +426,221 @@ static PyObject *py_snappy_compress(PyObject *self, PyObject *arg) {
   return out;
 }
 
+/* ------------------------------------------------------ trie look-up
+ *
+ * MerklePatriciaTrie.get as one call: the walk of trie/mpt.py's `_get`
+ * with the reference resolution of its `_resolve`, in the same order
+ * (decoded-node cache, then the session's staged and live log entries
+ * decoded here and NOT cached, then the source). The source is not
+ * read from here: a reference none of the three maps answers goes
+ * back to Python's `_resolve`, which reads it, fills the cache under
+ * its bound and raises MPTNodeMissingException, all as it does for the
+ * Python walk. A node of a shape the walk does not know (anything but
+ * a 2- or 17-item list with a bytes path) is handed to Python's `_get`
+ * with the nibbles that are left, so a malformed node fails exactly as
+ * it did.
+ *
+ * The GIL is held throughout, and the maps are the ones the Python
+ * walk reads, so `_resolve`'s thread-safety argument is unchanged. A
+ * call-back, though, can let go of the GIL, and another thread may
+ * then clear() the decoded cache or prune the staged map: the walk
+ * owns a reference to the node it stands on and to the child ref it
+ * asks for across every call-back, and to the three maps for the
+ * whole call.
+ *
+ * Counters (the process's, read by trie_counters): look-ups, the
+ * walk's own nanoseconds (two clock reads a look-up, the call-backs'
+ * time taken out with two more a call-back) and call-backs.
+ */
+
+#include <time.h>
+
+void khipu_keccak(int rate, const uint8_t *in, uint64_t in_len,
+                  uint8_t *out, int out_len); /* csrc/keccak.cc */
+
+static uint64_t trie_reads = 0, trie_walk_ns = 0, trie_callbacks = 0;
+static PyObject *s_root_ref, *s_dcache, *s_staged, *s_logs, *s_resolve,
+    *s_get;
+
+static inline uint64_t now_ns(void) {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
+}
+
+static inline unsigned nibble_at(const unsigned char *d, Py_ssize_t i) {
+  return (i & 1) ? (d[i >> 1] & 0x0F) : (d[i >> 1] >> 4);
+}
+
+typedef struct {
+  PyObject *trie, *dcache, *staged, *logs; /* owned for the call */
+  uint64_t callback_ns;
+} walk_t;
+
+/* `_resolve`: a NEW reference to the node behind `ref`, NULL on error. */
+static PyObject *walk_resolve(walk_t *w, PyObject *ref) {
+  if (PyList_Check(ref) || (PyBytes_CheckExact(ref) &&
+                            PyBytes_GET_SIZE(ref) == 0)) {
+    Py_INCREF(ref); /* an inline node, or BLANK */
+    return ref;
+  }
+  if (PyBytes_CheckExact(ref) && PyDict_CheckExact(w->dcache) &&
+      PyDict_CheckExact(w->staged) && PyDict_CheckExact(w->logs)) {
+    PyObject *node = PyDict_GetItemWithError(w->dcache, ref);
+    if (node != NULL && node != Py_None) {
+      Py_INCREF(node);
+      return node;
+    }
+    if (node == NULL && PyErr_Occurred()) return NULL;
+    PyObject *enc = PyDict_GetItemWithError(w->staged, ref);
+    if (enc == NULL && PyErr_Occurred()) return NULL;
+    if (enc == NULL || enc == Py_None) {
+      enc = NULL;
+      PyObject *log = PyDict_GetItemWithError(w->logs, ref);
+      if (log == NULL && PyErr_Occurred()) return NULL;
+      if (log != NULL && PyList_CheckExact(log) &&
+          PyList_GET_SIZE(log) == 2 &&
+          PyLong_CheckExact(PyList_GET_ITEM(log, 0))) {
+        int overflow;
+        long count = PyLong_AsLongAndOverflow(PyList_GET_ITEM(log, 0),
+                                              &overflow);
+        if ((count > 0 || overflow > 0) &&
+            PyList_GET_ITEM(log, 1) != Py_None)
+          enc = PyList_GET_ITEM(log, 1);
+      } else if (log != NULL) {
+        goto python; /* not a [count, encoded] record: Python's say */
+      }
+    }
+    if (enc != NULL) {
+      if (!PyBytes_CheckExact(enc)) goto python;
+      Py_INCREF(enc); /* decoding allocates: a finalizer may run */
+      node = dec_exact((const unsigned char *)PyBytes_AS_STRING(enc),
+                       PyBytes_GET_SIZE(enc));
+      Py_DECREF(enc);
+      return node;
+    }
+  }
+python:;
+  uint64_t t0 = now_ns();
+  PyObject *node = PyObject_CallMethodOneArg(w->trie, s_resolve, ref);
+  w->callback_ns += now_ns() - t0;
+  trie_callbacks++;
+  return node;
+}
+
+/* The Python walk from `node` on, with the nibbles from `pos`. */
+static PyObject *walk_in_python(walk_t *w, PyObject *node,
+                                const unsigned char *key, Py_ssize_t pos,
+                                Py_ssize_t n) {
+  PyObject *rest = PyBytes_FromStringAndSize(NULL, n - pos);
+  if (!rest) return NULL;
+  char *p = PyBytes_AS_STRING(rest);
+  for (Py_ssize_t i = pos; i < n; ++i) *p++ = (char)nibble_at(key, i);
+  PyObject *out = PyObject_CallMethodObjArgs(w->trie, s_get, node, rest,
+                                             NULL);
+  Py_DECREF(rest);
+  return out;
+}
+
+/* The walk proper: `key` is n nibbles, two a byte. */
+static PyObject *walk_get(walk_t *w, PyObject *root_ref,
+                          const unsigned char *key, Py_ssize_t n) {
+  PyObject *node = walk_resolve(w, root_ref); /* owned */
+  Py_ssize_t pos = 0;
+  while (node != NULL) {
+    PyObject *out = NULL, *child = NULL;
+    if (PyBytes_CheckExact(node) && PyBytes_GET_SIZE(node) == 0) {
+      out = Py_NewRef(Py_None); /* BLANK: absent */
+    } else if (PyList_CheckExact(node) && PyList_GET_SIZE(node) == 17) {
+      if (pos == n) { /* the branch's own value, `node[16] or None` */
+        PyObject *v = PyList_GET_ITEM(node, 16);
+        int truth = PyObject_IsTrue(v);
+        if (truth >= 0) out = Py_NewRef(truth ? v : Py_None);
+      } else {
+        child = PyList_GET_ITEM(node, nibble_at(key, pos));
+        pos += 1;
+      }
+    } else if (PyList_CheckExact(node) && PyList_GET_SIZE(node) == 2 &&
+               PyBytes_CheckExact(PyList_GET_ITEM(node, 0)) &&
+               PyBytes_GET_SIZE(PyList_GET_ITEM(node, 0)) > 0) {
+      PyObject *hp = PyList_GET_ITEM(node, 0);
+      const unsigned char *d = (const unsigned char *)PyBytes_AS_STRING(hp);
+      unsigned flag = d[0] >> 4;
+      Py_ssize_t skip = (flag & 1) ? 1 : 2; /* nibbles before the path */
+      Py_ssize_t plen = 2 * PyBytes_GET_SIZE(hp) - skip;
+      Py_ssize_t left = n - pos;
+      int same = (flag & 2) ? plen == left : plen <= left;
+      for (Py_ssize_t j = 0; same && j < plen; ++j)
+        same = nibble_at(d, skip + j) == nibble_at(key, pos + j);
+      if (!same) {
+        out = Py_NewRef(Py_None); /* diverges in a leaf or extension */
+      } else if (flag & 2) {
+        out = Py_NewRef(PyList_GET_ITEM(node, 1)); /* the leaf's value */
+      } else {
+        child = PyList_GET_ITEM(node, 1);
+        pos += plen;
+      }
+    } else {
+      out = walk_in_python(w, node, key, pos, n);
+    }
+    if (child == NULL) { /* answered (or failed: `out` is NULL) */
+      Py_DECREF(node);
+      return out;
+    }
+    Py_INCREF(child); /* borrowed from `node`, which stays owned too */
+    PyObject *next = walk_resolve(w, child);
+    Py_DECREF(child);
+    Py_DECREF(node);
+    node = next;
+  }
+  return NULL;
+}
+
+/* trie_get(trie, key, hashed): trie.get(key), or with `hashed` true
+ * trie.get(keccak256(key)). */
+static PyObject *py_trie_get(PyObject *self, PyObject *const *args,
+                             Py_ssize_t nargs) {
+  if (nargs != 3) {
+    PyErr_SetString(PyExc_TypeError, "trie_get(trie, key, hashed)");
+    return NULL;
+  }
+  int hashed = PyObject_IsTrue(args[2]);
+  if (hashed < 0) return NULL;
+  Py_buffer view;
+  if (PyObject_GetBuffer(args[1], &view, PyBUF_SIMPLE) < 0) return NULL;
+  uint64_t t0 = now_ns();
+  trie_reads++;
+  unsigned char digest[32];
+  const unsigned char *key = (const unsigned char *)view.buf;
+  Py_ssize_t n = 2 * view.len;
+  if (hashed) {
+    khipu_keccak(136, key, (uint64_t)view.len, digest, 32);
+    key = digest;
+    n = 64;
+  }
+  walk_t w = {args[0], NULL, NULL, NULL, 0};
+  PyObject *out = NULL;
+  PyObject *root_ref = PyObject_GetAttr(w.trie, s_root_ref);
+  w.dcache = PyObject_GetAttr(w.trie, s_dcache);
+  w.staged = PyObject_GetAttr(w.trie, s_staged);
+  w.logs = PyObject_GetAttr(w.trie, s_logs);
+  if (root_ref && w.dcache && w.staged && w.logs)
+    out = walk_get(&w, root_ref, key, n);
+  Py_XDECREF(root_ref);
+  Py_XDECREF(w.dcache);
+  Py_XDECREF(w.staged);
+  Py_XDECREF(w.logs);
+  PyBuffer_Release(&view);
+  trie_walk_ns += now_ns() - t0 - w.callback_ns;
+  return out;
+}
+
+static PyObject *py_trie_counters(PyObject *self, PyObject *noargs) {
+  return Py_BuildValue("(KKK)", (unsigned long long)trie_reads,
+                       (unsigned long long)trie_walk_ns,
+                       (unsigned long long)trie_callbacks);
+}
+
 static PyMethodDef methods[] = {
     {"encode", py_encode, METH_O, "RLP-encode bytes / nested lists."},
     {"decode", py_decode, METH_O, "RLP-decode one item (strict)."},
@@ -428,6 +649,10 @@ static PyMethodDef methods[] = {
      "Test-only: callable run between encode's size and write passes."},
     {"snappy_compress", py_snappy_compress, METH_O,
      "Greedy Snappy block-format compression."},
+    {"trie_get", (PyCFunction)(void (*)(void))py_trie_get, METH_FASTCALL,
+     "trie_get(trie, key, hashed): MerklePatriciaTrie.get in one call."},
+    {"trie_counters", py_trie_counters, METH_NOARGS,
+     "(look-ups, the walk's own ns, call-backs) since the load."},
     {NULL, NULL, 0, NULL},
 };
 
@@ -436,5 +661,14 @@ static struct PyModuleDef moduledef = {
 };
 
 PyMODINIT_FUNC PyInit_khipu_rlp_ext(void) {
+  s_root_ref = PyUnicode_InternFromString("_root_ref");
+  s_dcache = PyUnicode_InternFromString("_dcache");
+  s_staged = PyUnicode_InternFromString("_staged");
+  s_logs = PyUnicode_InternFromString("_logs");
+  s_resolve = PyUnicode_InternFromString("_resolve");
+  s_get = PyUnicode_InternFromString("_get");
+  if (!s_root_ref || !s_dcache || !s_staged || !s_logs || !s_resolve ||
+      !s_get)
+    return NULL;
   return PyModule_Create(&moduledef);
 }

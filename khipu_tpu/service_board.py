@@ -17,6 +17,7 @@ from khipu_tpu.config import KhipuConfig
 from khipu_tpu.domain.blockchain import Blockchain, GenesisSpec
 from khipu_tpu.observability.registry import REGISTRY
 from khipu_tpu.storage.storages import Storages
+from khipu_tpu.trie.mpt import trie_read_samples
 from khipu_tpu.txpool import OmmersPool, PendingTransactionsPool
 
 
@@ -40,6 +41,9 @@ class ServiceBoard:
         # shuts down (or a newer board of the process takes over)
         REGISTRY.register_collector(
             "nodestore", self.storages.nodestore_samples)
+        # khipu_trie_*: the process's counters, so one function for
+        # every board, and no board's shutdown takes it away
+        REGISTRY.register_collector("trie_reads", trie_read_samples)
         self.blockchain = Blockchain(self.storages, config)
         if self.blockchain.get_header_by_number(0) is None:
             self.blockchain.load_genesis(genesis or GenesisSpec())

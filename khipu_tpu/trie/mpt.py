@@ -28,6 +28,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple, Union
 
+from khipu_tpu.base import rlp as _rlp
 from khipu_tpu.base.crypto.keccak import keccak256
 from khipu_tpu.base.nibbles import bytes_to_nibbles, hp_decode, hp_encode
 from khipu_tpu.base.rlp import rlp_decode, rlp_encode
@@ -58,6 +59,27 @@ class MPTNodeMissingException(MPTException):
     def __init__(self, hash_: bytes):
         super().__init__(f"missing MPT node {hash_.hex()}")
         self.hash = hash_
+
+
+# Look-ups the Python walk answered (no extension bound, or not yet).
+# The native walk counts its own, in C.
+_python_reads = [0]
+
+
+def trie_read_samples() -> list:
+    """``khipu_trie_*``: how the process's trie look-ups were walked,
+    for a registry collector (``ServiceBoard`` registers it). The
+    seconds are the native walk's own, its call-backs' time taken out;
+    the Python walk is counted, not timed."""
+    ext = _rlp.native_ext
+    reads, walk_ns, callbacks = ext.trie_counters() if ext else (0, 0, 0)
+    return [
+        ("khipu_trie_reads_total", "counter", {"walk": "native"}, reads),
+        ("khipu_trie_reads_total", "counter", {"walk": "python"},
+         _python_reads[0]),
+        ("khipu_trie_read_seconds_total", "counter", {}, walk_ns / 1e9),
+        ("khipu_trie_read_callbacks_total", "counter", {}, callbacks),
+    ]
 
 
 def _is_branch(node: List) -> bool:
@@ -129,7 +151,32 @@ class MerklePatriciaTrie:
 
     # ------------------------------------------------------------ reads
 
+    # A look-up is ONE call into the extension (csrc_ext/rlp_ext.c,
+    # trie_get): nibbles, the walk and _resolve's three maps in C, and
+    # only a reference those maps lack calls back into _resolve below.
+    # It holds the GIL throughout and touches the dicts this walk
+    # touches, so a reader on another thread (RPC, the planner) is as
+    # safe as it was. Until the extension is bound (it builds on a
+    # background thread in a fresh checkout), and where it cannot be
+    # built, the Python walk answers and is counted as such; it is also
+    # the oracle of tests/test_trie_native_get.py.
+
     def get(self, key: bytes) -> Optional[bytes]:
+        ext = _rlp.native_ext
+        if ext is not None:
+            return ext.trie_get(self, key, False)
+        return self._py_get(key)
+
+    def get_hashed(self, preimage: bytes) -> Optional[bytes]:
+        """``get(keccak256(preimage))``, the hash taken inside the call
+        (a storage slot's key has no memo to lose)."""
+        ext = _rlp.native_ext
+        if ext is not None:
+            return ext.trie_get(self, preimage, True)
+        return self._py_get(keccak256(preimage))
+
+    def _py_get(self, key: bytes) -> Optional[bytes]:
+        _python_reads[0] += 1
         node = self._resolve(self._root_ref)
         if node == BLANK:
             return None

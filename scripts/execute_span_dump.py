@@ -12,13 +12,40 @@ and seconds, ``batches``, ``fallback``, and from PR 35 ``reruns`` /
 ``rerun_txs``). What no per-layer metric reads yet is read from this
 dump: the residue lane's seconds a transaction on blocks that stood,
 ``optimistic_s`` a block on blocks that fell back, sum(rerun_txs) /
-sum(txs). Edits nothing under ``benchmark/``: it wraps ``run.per_layer``,
-which is handed the driver's artefacts.
+sum(txs). From PR 39 it also prints what ``khipu_trie_*`` gained over
+the window, label by label (``walk="python"`` above 0: the extension was
+not bound for some look-up), where the driver hands over its registry
+snapshots (``sync.deep``, ``sync.contracts``), and the process's totals
+since boot where it does not (``sync.dense``). Edits nothing under
+``benchmark/``: it wraps ``run.per_layer``, which is handed the driver's
+artefacts.
 """
 
 import json
 import os
 import sys
+
+
+def trie_counters(artefacts) -> str:
+    """``khipu_trie_*`` over the window (or since boot), one line."""
+    snaps = artefacts.get("registry")
+    if snaps:
+        what = "over the window"
+    else:
+        from khipu_tpu.observability.registry import REGISTRY
+
+        what, snaps = "since boot", ({}, REGISTRY.snapshot())
+    out = []
+    for family, close in sorted(snaps[1].items()):
+        if not family.startswith("khipu_trie_"):
+            continue
+        was = snaps[0].get(family, 0)
+        if isinstance(close, dict):
+            out += [f"{family}{{{k}}} {v - (was or {}).get(k, 0):.6g}"
+                    for k, v in sorted(close.items())]
+        else:
+            out.append(f"{family} {close - was:.6g}")
+    return f"trie counters {what}: " + (", ".join(out) or "none")
 
 
 def main() -> int:
@@ -37,6 +64,7 @@ def main() -> int:
                 f.write(json.dumps({"seconds": s.t1 - s.t0, **s.tags}) + "\n")
         print(f"execute spans: {len(spans)} written to {out_path}",
               flush=True)
+        print(trie_counters(outcome.artefacts), flush=True)
         return inner(cell_name, outcome)
 
     run.per_layer = per_layer
