@@ -15,6 +15,14 @@ when the sync completes, whichever is first. Either way the closing
 verify runs over a mirror that holds most of the snapshot. The syncer
 gets its storages and its mirror through timing proxies, which is how
 the per-layer split is taken without touching the program.
+
+The peer keeps a row of clocks some twenty times a window (``SLICES``;
+wall, the driver thread's CPU, the process's CPU); the ``window: closed`` line
+prints them as rates per slice and artefact ``slices`` hands the rows
+over, so that a speed can be seen to hold or to flip inside a window.
+Under ``--trace 1`` the program's own tracer is on for the window and
+its ring's spans (``fastsync.*``, ``mirror.*``) are handed over as
+``spans``, which names the breakdown's idle gaps.
 """
 
 from __future__ import annotations
@@ -25,13 +33,17 @@ import os
 import subprocess
 import sys
 import time
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
 from benchmark.generators import snapshot as gen
 from benchmark.lib.outcome import Check, Outcome
 from benchmark.reference.keccak import keccak256_batch
+
+# rows of the window's clock: a slice of a twentieth of the window's
+# requests (50,000 nodes, ~1.3 s at the cell's size)
+SLICES = 20
 
 class Timed:
     """Wraps an object; calls to the named methods are timed and counted
@@ -204,6 +216,7 @@ def _run(env, builder, cache_file: str, rows: Dict[int, int],
          batch_size: int) -> Outcome:
     import jax
 
+    from khipu_tpu.observability.trace import tracer as program_tracer
     from khipu_tpu.storage.storages import Storages
     from khipu_tpu.sync.fast_sync import (
         STATE_NODE, FastSyncStateStorage, SyncState)
@@ -250,21 +263,31 @@ def _run(env, builder, cache_file: str, rows: Dict[int, int],
         target_root=src.root, downloaded_nodes=done,
         pending=[(STATE_NODE, h) for h in src.keys(done, pending_end)]))
     peer = gen.Peer(src.nodes(), env.seed, int(traffic["forge_one_in"]))
+    peer.slice_requests = max(1, (len(src) - done) // (SLICES * batch_size))
     root = src.root
     book: Dict[str, List] = {}
     gc.collect()
     tw = env.trace_window() if env.trace else None
     if tw:
+        # the program's span ring, sized to hold the whole window: a
+        # batch opens seven spans, a tile and a checkpoint a few more
+        # (142 k spans for 20,000 batches: my chip run, PR 41)
+        program_tracer.enable(capacity=max(
+            program_tracer.DEFAULT_CAPACITY,
+            10 * (len(src) - done) // batch_size + 4096))
+        program_tracer.reset()
         tw.start()
     # ------------------------------------------------------ the window
     env.log("window: open")
     setup_s = time.perf_counter() - env.t_proc0
     t_open = time.perf_counter()
     peer.deadline = t_open + env.seconds
+    peer.mark(t_open)
     complete = sync_once(
         storages, mirror, peer, root, batch_size, book, env,
         check_batches=env.control != "no-batch-check")
     t_loop = time.perf_counter()
+    peer.mark(t_loop)
     bad = 0
     if not complete:  # a completed sync has flushed and verified itself
         tm = Timed(mirror, "mirror", ["flush", "verify"], book)
@@ -272,14 +295,22 @@ def _run(env, builder, cache_file: str, rows: Dict[int, int],
         bad = tm.verify()
     verify_s = book["mirror.verify"][0]
     t_close = time.perf_counter()
+    spans, spans_dropped = [], 0
     if tw:
         tw.stop()
+        program_tracer.disable()
+        spans, spans_dropped = program_tracer.snapshot(), program_tracer.dropped
+        env.log(f"span ring: {len(spans)} kept, {spans_dropped} dropped")
     stored = book.get("store.account_node_storage.update", [0, 0, 0])[2]
     resident = mirror.resident_count - resident0
     env.log(f"window: closed after {t_close - t_open:.3f} s "
             f"(loop {t_loop - t_open:.3f} s), {stored} nodes stored, "
             f"{resident} more resident ({mirror.resident_count} in all), "
-            f"complete={complete}")
+            f"complete={complete}; slices, nodes a wall second / a "
+            "thread-CPU second / other threads' CPU seconds (the last: "
+            "what was left, with a completed sync's flush and verify): "
+            + " ".join(f"{n / w:.0f}/{n / c:.0f}/{o:.3f}"
+                       for n, w, c, o in slice_rates(peer.slices)))
 
     # ------------------------------------ after the window: the checks
     rng = np.random.default_rng([env.seed, 0x736E6368])
@@ -329,13 +360,29 @@ def _run(env, builder, cache_file: str, rows: Dict[int, int],
         "loop_s": t_loop - t_open, "window_s": window_s,
         "fetch_s": peer.seconds, "verify_ms": 1000.0 * verify_s,
         "resident_bytes": resident_bytes(mirror), "trace": tw,
-        "spans": [], "forged_sent": len(peer.forged),
+        "spans": [s for s in spans if s.t1 > t_open and s.t0 < t_close],
+        "spans_dropped": spans_dropped, "slices": peer.slices,
+        "forged_sent": len(peer.forged),
         "requests": peer.requests, "complete": complete,
     }
     attempted = len(peer.truthful) + len(peer.forged)
     failed = attempted - stored - len(peer.forged) if not env.control else 0
     storages.stop()
     return Outcome(e2e, checks, attempted, max(0, failed), art)
+
+
+def slice_rates(rows) -> List[Tuple[int, float, float, float]]:
+    """``Peer.mark`` rows as slices: (nodes handed over, wall seconds,
+    the driver thread's CPU seconds, the other threads' CPU seconds =
+    the process's minus the thread's) between each row and the next.
+    A slice in which no node came (a clock that did not move) is left
+    out."""
+    out = []
+    for a, b in zip(rows, rows[1:]):
+        nodes, wall, cpu = b[4] - a[4], b[0] - a[0], b[1] - a[1]
+        if nodes > 0 and wall > 0 and cpu > 0:
+            out.append((nodes, wall, cpu, (b[2] - a[2]) - cpu))
+    return out
 
 
 def resident_bytes(mirror) -> int:

@@ -8,7 +8,9 @@ on their first request (one flipped byte); a retry is answered
 truthfully, as a second, honest peer would. Requests of fewer than
 ``forge_min_request`` hashes are never forged, so that no request comes
 back without a single good node (the syncer treats that as a dead peer
-set and gives up).
+set and gives up). Every ``slice_requests``-th request (the driver sets
+it; 0: never) the peer keeps one row of clocks (``Peer.mark``), so that a
+window's speed can be read slice by slice.
 """
 
 from __future__ import annotations
@@ -182,6 +184,8 @@ class Peer:
         self.nodes = nodes
         self.forge_one_in = int(forge_one_in)
         self.forge_min_request = int(forge_min_request)
+        self.slice_requests = 0
+        self.slices: List[Tuple[float, float, float, int, int]] = []
         self.pick = seed % self.forge_one_in if self.forge_one_in else -1
         self.forged: Dict[bytes, bytes] = {}
         self.truthful: set = set()
@@ -192,6 +196,15 @@ class Peer:
     def _forge(self, h: bytes, value: bytes) -> bytes:
         pos = h[5] % len(value)
         return value[:pos] + bytes([value[pos] ^ 0x40]) + value[pos + 1:]
+
+    def mark(self, now: float = None) -> None:
+        """One row of the window's clock, read on the calling thread
+        (the driver thread: ``fetch`` is the syncer's call-back): wall,
+        that thread's CPU seconds, the whole process's, requests
+        answered and nodes handed over truthfully so far."""
+        self.slices.append((
+            time.perf_counter() if now is None else now, time.thread_time(),
+            time.process_time(), self.requests, len(self.truthful)))
 
     def fetch(self, hashes: List[bytes]) -> Dict[bytes, bytes]:
         t0 = time.perf_counter()
@@ -212,7 +225,10 @@ class Peer:
                 out[h] = value
                 self.truthful.add(h)
         self.requests += 1
-        self.seconds += time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self.seconds += t1 - t0
+        if self.slice_requests and self.requests % self.slice_requests == 0:
+            self.mark(t1)
         return out
 
 

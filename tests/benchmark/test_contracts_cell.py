@@ -30,7 +30,7 @@ from benchmark.reference.keccak import keccak256_batch  # noqa: E402
 SEED = 2_147_483_789
 NEW = {"exec_residue_share.sync", "exec_fallback_blocks_share.sync",
        "exec_batch_width.sync", "exec_interpreter_ms_per_block.sync",
-       "exec_vector_ms_per_block.sync"}
+       "exec_vector_ms_per_block.sync", "exec_rerun_txs_share.sync"}
 CELL = manifest.cell("sync.contracts")
 CONF, TRAFFIC = CELL["config_file"], CELL["traffic_file"]
 SIZES = bench_run.merged(CONF, True)["sizes"]

@@ -28,7 +28,8 @@ from benchmark.reference.keccak import keccak256_batch  # noqa: E402
 SEED = 2_147_483_777
 NEW = {"node_cache_miss_reads_per_block.sync", "node_read_us_per_miss.sync",
        "node_read_ms_per_block.sync", "fused_rounds_per_dispatch.sync",
-       "exec_interpreted_share.sync"}
+       "exec_interpreted_share.sync", "trie_read_ms_per_block.sync",
+       "trie_reads_per_block.sync"}
 CONF = manifest.cell("sync.deep")["config_file"]
 TRAFFIC = manifest.cell("sync.deep")["traffic_file"]
 
