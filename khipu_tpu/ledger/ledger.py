@@ -58,7 +58,7 @@ from khipu_tpu.evm.dispatch import run_create, run_message_call
 from khipu_tpu.evm.vm import BlockEnv, MessageEnv
 from khipu_tpu.ledger.bloom import bloom_of_logs, bloom_union
 from khipu_tpu.ledger.rewards import block_rewards
-from khipu_tpu.ledger.world import BlockWorldState
+from khipu_tpu.ledger.world import WORLD_COPIES, BlockWorldState
 from khipu_tpu.observability.journey import JOURNEY
 from khipu_tpu.observability.profiler import HOST, LEDGER
 from khipu_tpu.observability.registry import REGISTRY
@@ -73,6 +73,14 @@ from khipu_tpu.observability.registry import REGISTRY
 # lanes of the attempt that stood (a re-run segment's under residue),
 # so they sum to the block's count.
 EXEC_LANES = ("vector", "checked", "residue", "optimistic", "sequential")
+# What execute_block does outside those lanes, timed around whole calls
+# (Stats.part_seconds): plan = plan_block; post = every post_through
+# (fees, receipts, blooms through _tx_post); checkpoint = a segment's
+# copy of the merged world before its checked calls run (going back to
+# it is an assignment; the re-run is the residue lane's); validate =
+# _pay_rewards + _validate_after. Lanes and parts do not overlap, and
+# what they leave of the block's execute time has no name.
+EXEC_PARTS = ("plan", "post", "checkpoint", "validate")
 LANE_SECONDS = {
     lane: REGISTRY.counter(
         "khipu_exec_lane_seconds_total",
@@ -144,6 +152,13 @@ class Stats:
         default_factory=lambda: dict.fromkeys(EXEC_LANES, 0))
     lane_seconds: Dict[str, float] = field(
         default_factory=lambda: dict.fromkeys(EXEC_LANES, 0.0))
+    part_seconds: Dict[str, float] = field(
+        default_factory=lambda: dict.fromkeys(EXEC_PARTS, 0.0))
+    # BlockWorldState.copy calls on the executing thread and their
+    # seconds: mostly call frames, so INSIDE the interpreter lanes (and
+    # the checkpoint part) — a sub-part, not one more addend
+    copies: int = 0
+    copy_seconds: float = 0.0
     batches: int = 0  # batch steps of the plan that stood
     fallback: bool = False  # the whole scheduled attempt was thrown away
 
@@ -417,6 +432,8 @@ def execute_block(
     senders = [stx.sender for stx in txs]
     t0 = time.perf_counter()
     stats = Stats(tx_count=len(txs))
+    copy_book = WORLD_COPIES.mine()
+    copies0, copy_s0 = copy_book
 
     traced = khipu_config.sync.debug_trace_at == header.number
     if traced:
@@ -484,6 +501,7 @@ def execute_block(
                         # available — a trusted template that produces
                         # a wrong root demotes (never oscillates back)
                         # and the block re-runs without it, bit-exact
+                        _t0 = time.perf_counter()
                         _pay_rewards(world, block, khipu_config)
                         rewards_paid = True
                         _validate_after(
@@ -491,6 +509,8 @@ def execute_block(
                             check_root, hasher,
                         )
                         validated_scheduled = True
+                        stats.part_seconds["validate"] += (
+                            time.perf_counter() - _t0)
                 except (Misprediction, TxValidationError) as e:
                     # an invalid tx names no segment to roll back to.
                     # A Misprediction is the backstop only:
@@ -547,16 +567,23 @@ def execute_block(
 
             set_trace(None)
 
+    _t0 = time.perf_counter()
     if not rewards_paid:
         _pay_rewards(world, block, khipu_config)
     stats.gas_used = gas_used
-    stats.exec_seconds = time.perf_counter() - t0
+    _t1 = time.perf_counter()
+    stats.exec_seconds = _t1 - t0
+    stats.part_seconds["validate"] += _t1 - _t0
     for lane in EXEC_LANES:
         LANE_TXS[lane].inc(stats.lane_txs[lane])
         LANE_SECONDS[lane].inc(stats.lane_seconds[lane])
 
     if validate and not validated_scheduled:
+        _t0 = time.perf_counter()
         _validate_after(block, world, receipts, gas_used, check_root, hasher)
+        stats.part_seconds["validate"] += time.perf_counter() - _t0
+    stats.copies = copy_book[0] - copies0
+    stats.copy_seconds = copy_book[1] - copy_s0
     return BlockResult(world, receipts, gas_used, stats)
 
 
@@ -643,9 +670,12 @@ def _execute_scheduled(
     )
 
     merged = make_world(parent_root)
+    parts = stats.part_seconds
+    _t0 = time.perf_counter()
     plan = plan_block(
         txs, senders, header.beneficiary, merged.get_code_hash, LEARNER
     )
+    parts["plan"] += time.perf_counter() - _t0
     stats.conflict_count += plan.conflicted
     trusted_used: Set[bytes] = set()
     lanes = {"vector": 0, "checked": 0, "residue": 0}
@@ -665,6 +695,9 @@ def _execute_scheduled(
         batch execution validated with accumulated_gas=0, exactly like
         the optimistic pass."""
         nonlocal cumulative, accumulated_gas, posted
+        if posted >= limit:
+            return
+        _t0 = time.perf_counter()
         while posted < limit:
             r = outcomes[posted]
             if accumulated_gas + txs[posted].tx.gas_limit > header.gas_limit:
@@ -676,6 +709,7 @@ def _execute_scheduled(
                 config, merged, r, header.beneficiary, cumulative, receipts
             )
             posted += 1
+        parts["post"] += time.perf_counter() - _t0
 
     def run_captured(i: int, accumulated: int) -> Dict[str, Set]:
         """Validate + execute tx i on the merged world with fresh
@@ -874,7 +908,11 @@ def _execute_scheduled(
         # swaps reads/written: copy() shares ``reads`` (a superset
         # after a rollback is harmless, as after a reverted frame) and
         # copies ``written``
-        checkpoint = merged.copy() if checked else None
+        checkpoint = None
+        if checked:
+            _t0 = time.perf_counter()
+            checkpoint = merged.copy()
+            parts["checkpoint"] += time.perf_counter() - _t0
         counts = {"vector": 0, "checked": 0}
         try:
             for step in segment:

@@ -28,6 +28,7 @@ Design differences from the Scala (deliberate, same semantics):
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Set, Tuple
 
@@ -40,6 +41,7 @@ from khipu_tpu.domain.account import (
 )
 from khipu_tpu.base.rlp import rlp_decode, rlp_encode
 from khipu_tpu.evm.dataword import from_bytes, to_minimal_bytes
+from khipu_tpu.observability.thread_books import ThreadBooks
 from khipu_tpu.trie.mpt import MerklePatriciaTrie
 
 # Race categories (BlockWorldState.scala:53-57).
@@ -47,6 +49,11 @@ ON_ADDRESS = "address"  # existence / deadness
 ON_ACCOUNT = "account"  # nonce / balance
 ON_STORAGE = "storage"  # a (address, key) cell
 ON_CODE = "code"
+
+# BlockWorldState.copy calls and their seconds, by the thread that made
+# them (call frames, segment checkpoints): execute_block reads its own
+# thread's book before and after a block (Stats.copies, copy_seconds)
+WORLD_COPIES = ThreadBooks(0, 0.0)
 
 
 @dataclass
@@ -182,6 +189,7 @@ class BlockWorldState:
         races must survive the rollback (Ledger.runVM:728-733 merges
         race flags from reverted checkpoints). ``written`` is copied —
         a reverted write genuinely did not happen."""
+        t0 = time.perf_counter()
         w = BlockWorldState.__new__(BlockWorldState)
         w.account_trie = self.account_trie
         w.storage_source = self.storage_source
@@ -197,6 +205,9 @@ class BlockWorldState:
         w.selfdestructed = set(self.selfdestructed)
         w.reads = self.reads
         w.written = {k: set(v) for k, v in self.written.items()}
+        book = WORLD_COPIES.mine()
+        book[0] += 1
+        book[1] += time.perf_counter() - t0
         return w
 
     # ------------------------------------------------------------- reads

@@ -61,6 +61,7 @@ from khipu_tpu.base.crypto.keccak import keccak256
 from khipu_tpu.chaos import fault_point
 from khipu_tpu.native.keccak import keccak256_batch
 from khipu_tpu.observability.profiler import HOST, LEDGER
+from khipu_tpu.observability.thread_books import ThreadBooks
 from khipu_tpu.storage.datasource import (
     BlockDataSource,
     KeyValueDataSource,
@@ -140,6 +141,13 @@ class KesqueStore:
         self.reclaimed_bytes = 0
         self.disk_read_bytes = 0
         self.value_bytes_returned = 0
+        # get(), per reading thread (observability/thread_books.py): calls,
+        # seconds waiting for ``_lock``, seconds under it (index
+        # look-up, both preads, getting the GIL back after them)
+        self._gets = ThreadBooks(0, 0.0, 0.0)
+        # seconds the appends held ``_lock`` (added to under it): over
+        # wall time, the share of time a reader can be shut out
+        self.append_lock_held_seconds = 0.0
         self._open_all()
 
     # --------------------------------------------------------- open/load
@@ -324,7 +332,7 @@ class KesqueStore:
         mirror-tile spill — lands as one sequential run of back-to-back
         frames (``Segment.append_many``: chunked pwrites of the joined
         buffer, not one syscall per node). Returns bytes appended."""
-        t0 = time.perf_counter()
+        t0 = time.perf_counter() if LEDGER.enabled else 0.0
         # (is_delete, key, payload) in append order: tombstones first,
         # matching the (removes, upserts) SPI argument order
         entries: List[Tuple[bool, bytes, bytes]] = []
@@ -342,6 +350,7 @@ class KesqueStore:
             return 0
         nbytes = 0
         with self._lock:
+            t_in = time.perf_counter()
             i = 0
             while i < len(entries):
                 seg = self._active_locked()
@@ -372,8 +381,11 @@ class KesqueStore:
                         )
             self.appended_bytes += nbytes
             self.appended_records += len(entries)
-        LEDGER.record("kesque.append", HOST, nbytes,
-                      duration=time.perf_counter() - t0)
+            t_out = time.perf_counter()
+            self.append_lock_held_seconds += t_out - t_in
+        if t0:
+            LEDGER.record("kesque.append", HOST, nbytes,
+                          duration=t_out - t0)
         return nbytes
 
     def append_raw(self, raw: bytes,
@@ -388,8 +400,9 @@ class KesqueStore:
         re-CRC'd one record at a time."""
         if not raw:
             return
-        t0 = time.perf_counter()
+        t0 = time.perf_counter() if LEDGER.enabled else 0.0
         with self._lock:
+            t_in = time.perf_counter()
             seg = self._active_locked()
             if seg.end and seg.end + len(raw) > self.segment_bytes:
                 seg = self._roll_locked()
@@ -403,23 +416,40 @@ class KesqueStore:
                 self._index[key] = (seg.seq, base + rel, rec)
             self.appended_bytes += len(raw)
             self.appended_records += len(entries)
-        LEDGER.record("kesque.append", HOST, len(raw),
-                      duration=time.perf_counter() - t0)
+            t_out = time.perf_counter()
+            self.append_lock_held_seconds += t_out - t_in
+        if t0:
+            LEDGER.record("kesque.append", HOST, len(raw),
+                          duration=t_out - t0)
 
     # ------------------------------------------------------------- reads
 
     def get(self, key: bytes) -> Optional[bytes]:
         key = bytes(key)
+        payload = None
+        t0 = time.perf_counter()
         with self._lock:
+            t1 = time.perf_counter()
             loc = self._index.get(key)
-            if loc is None:
-                return None
-            seq, off, rec = loc
-            payload = self._segments[seq].read(off)
-            self.disk_read_bytes += rec
+            if loc is not None:
+                seq, off, rec = loc
+                payload = self._segments[seq].read(off)
+                self.disk_read_bytes += rec
+            t2 = time.perf_counter()
+        book = self._gets.mine()
+        book[0] += 1
+        book[1] += t1 - t0
+        book[2] += t2 - t1
+        if payload is None:
+            return None
         _tag, _k, value = decode_record(payload)
         self.value_bytes_returned += len(value)
         return value
+
+    def read_book(self, ident: Optional[int] = None) -> List:
+        """``[gets, lock-wait seconds, read seconds]`` of one reading
+        thread, or summed over all of them."""
+        return self._gets.of(ident)
 
     def keys(self) -> List[bytes]:
         with self._lock:
@@ -577,6 +607,9 @@ class KesqueKeyValueDataSource(KeyValueDataSource):
             return self._store.get(key)
         finally:
             self.clock.elapse(t0)
+
+    def read_book(self, ident: Optional[int] = None) -> List:
+        return self._store.read_book(ident)
 
     def update(self, to_remove, to_upsert) -> None:
         self._store.append_batch(to_remove, to_upsert)
@@ -872,6 +905,18 @@ class KesqueEngine:
             reclaimed += st.reclaimed_bytes
             torn += st.torn_bytes
             entries += st.count
+            gets, wait_s, read_s = st.read_book()
+            topic = {"topic": st.topic}
+            samples.extend([
+                ("khipu_kesque_get_total", "counter", topic, gets),
+                ("khipu_kesque_get_lock_wait_seconds_total", "counter",
+                 topic, round(wait_s, 6)),
+                ("khipu_kesque_get_read_seconds_total", "counter",
+                 topic, round(read_s, 6)),
+                ("khipu_kesque_append_lock_held_seconds_total",
+                 "counter", topic,
+                 round(st.append_lock_held_seconds, 6)),
+            ])
         samples.extend([
             ("khipu_kesque_segments", "gauge", {}, n_segs),
             ("khipu_kesque_live_bytes", "gauge", {}, live),

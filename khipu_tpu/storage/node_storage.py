@@ -7,12 +7,15 @@ wrapper for eth_call simulation), ArchiveNodeStorage (no prune).
 
 from __future__ import annotations
 
-import threading
 import time
 from typing import Dict, Iterable, List, Mapping, Optional
 
+from khipu_tpu.observability.thread_books import ThreadBooks
 from khipu_tpu.storage.cache import FIFOCache
 from khipu_tpu.storage.unconfirmed import SimpleMapWithUnconfirmed
+
+# columns of a thread's book of misses (NodeStorage._misses)
+_SOURCE, _MIRROR, _ABSENT, _SECONDS = range(4)
 
 
 class NodeStorage:
@@ -38,12 +41,9 @@ class NodeStorage:
         # source (ring + engine, found or not; a wait for the GIL after
         # the engine's pread is inside it). Miss path only: hits are
         # FIFOCache's own count. Readers are the driver, persist and
-        # RPC threads, so the adds are under a lock of their own.
-        self._miss_lock = threading.Lock()
-        self.source_reads = 0
-        self.mirror_reads = 0
-        self.absent_reads = 0
-        self.source_seconds = 0.0
+        # RPC threads: each adds to a book of its own, no lock
+        # (observability/thread_books.py), columns as _SOURCE.._SECONDS.
+        self._misses = ThreadBooks(0, 0, 0, 0.0)
 
     def get(self, key: bytes) -> Optional[bytes]:
         v = self._cache.get(key)
@@ -52,22 +52,40 @@ class NodeStorage:
         t0 = time.perf_counter()
         v = self._unconfirmed.get(key)
         dt = time.perf_counter() - t0
+        book = self._misses.mine()
+        book[_SECONDS] += dt
         if v is not None:
-            with self._miss_lock:
-                self.source_seconds += dt
-                self.source_reads += 1
+            book[_SOURCE] += 1
             self._cache.put(key, v)
             return v
         m = self.mirror
         if m is not None:
             v = m.get(key)
-        with self._miss_lock:
-            self.source_seconds += dt
-            if v is not None:
-                self.mirror_reads += 1
-            else:
-                self.absent_reads += 1
+        book[_MIRROR if v is not None else _ABSENT] += 1
         return v
+
+    @property
+    def source_reads(self) -> int:
+        return self._misses.of()[_SOURCE]
+
+    @property
+    def source_seconds(self) -> float:
+        return self._misses.of()[_SECONDS]
+
+    def thread_misses(self, ident: int) -> tuple:
+        """What thread ``ident`` has paid on this store's miss path so
+        far: ``(misses, seconds in the source look-up, of them waiting
+        for the ring's and the engine's locks, of them in the engine's
+        read)``. The engine's two are 0 where the source keeps no
+        ``read_book`` (every engine but Kesque)."""
+        src, mir, absent, seconds = self._misses.of(ident)
+        wait = self._unconfirmed.lock_wait.of(ident)[0]
+        engine = 0.0
+        read_book = getattr(self.source, "read_book", None)
+        if read_book is not None:
+            _gets, engine_wait, engine = read_book(ident)
+            wait += engine_wait
+        return src + mir + absent, seconds, wait, engine
 
     def put(self, key: bytes, value: bytes) -> None:
         self.update([], {key: value})
@@ -112,18 +130,22 @@ class NodeStorage:
     def registry_samples(self, store: str) -> list:
         """``khipu_nodestore_*`` samples of this store for a registry
         collector (``Storages.nodestore_samples``)."""
+        src, mir, absent, seconds = self._misses.of()
         out = [
             ("khipu_nodestore_reads_total", "counter",
              {"store": store, "from": origin}, n)
             for origin, n in (
                 ("cache", self._cache.hits),
-                ("source", self.source_reads),
-                ("mirror", self.mirror_reads),
-                ("absent", self.absent_reads),
+                ("source", src),
+                ("mirror", mir),
+                ("absent", absent),
             )
         ]
         out.append(("khipu_nodestore_source_seconds_total", "counter",
-                    {"store": store}, round(self.source_seconds, 6)))
+                    {"store": store}, round(seconds, 6)))
+        out.append(("khipu_nodestore_lock_wait_seconds_total", "counter",
+                    {"store": store},
+                    round(self._unconfirmed.lock_wait.of()[0], 6)))
         return out
 
 

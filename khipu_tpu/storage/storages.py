@@ -118,6 +118,16 @@ class Storages:
             out.extend(store.registry_samples(name))
         return out
 
+    def thread_misses(self, ident: int) -> tuple:
+        """``(misses, seconds, lock-wait seconds, engine-read seconds)``
+        that thread ``ident`` has paid so far on the miss paths of the
+        three node stores (``NodeStorage.thread_misses``). The replay
+        driver reads its own before and after a block when the span
+        ring is on; other threads' reads are in the registry's totals
+        only."""
+        rows = [s.thread_misses(ident) for s in self._node_storages]
+        return tuple(sum(col) for col in zip(*rows))
+
     @property
     def window_journal(self):
         """The crash-consistency WAL (lazy: sync/journal.py imports

@@ -12,8 +12,11 @@ batches are dropped without ever touching the source.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Deque, Dict, Iterable, List, Mapping, Optional, Tuple
+
+from khipu_tpu.observability.thread_books import ThreadBooks
 
 Batch = Tuple[frozenset, Dict[bytes, bytes]]  # (removes, upserts)
 
@@ -27,6 +30,10 @@ class SimpleMapWithUnconfirmed:
         self._queue: Deque[Batch] = deque()
         self._lock = threading.RLock()
         self._buffered = True
+        # seconds each reading thread waited for ``_lock`` in get()
+        # (update() holds it across the source's append once the ring
+        # is full or buffering is off), apart from the look in source
+        self.lock_wait = ThreadBooks(0.0)
 
     # -- mode switches (Storages.swithToWithUnconfirmed / clearUnconfirmed)
 
@@ -41,7 +48,9 @@ class SimpleMapWithUnconfirmed:
             self._buffered = on
 
     def get(self, key: bytes) -> Optional[bytes]:
+        t0 = time.perf_counter()
         with self._lock:
+            self.lock_wait.mine()[0] += time.perf_counter() - t0
             for removes, upserts in reversed(self._queue):
                 if key in upserts:
                     return upserts[key]

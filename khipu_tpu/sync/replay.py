@@ -1000,6 +1000,10 @@ class ReplayDriver:
         prev = parent
         import itertools
 
+        # what THIS thread pays on the node stores' miss paths, read
+        # from its own book around each block while the ring is on
+        thread_misses = self.blockchain.storages.thread_misses
+        me = threading.get_ident()
         try:
             for block in itertools.chain((first,), blocks):
                 header = block.header
@@ -1007,7 +1011,10 @@ class ReplayDriver:
                     "window.build",
                     block=header.number,
                     txs=len(block.body.transactions),
-                ):
+                ) as build_sp:
+                    traced = build_sp.token is not None
+                    if traced:
+                        miss0 = thread_misses(me)
                     t0 = time.perf_counter()
                     # cache-fronted recovery (sync/prefetch.py): a
                     # no-op sweep when the prefetch thread already
@@ -1075,16 +1082,25 @@ class ReplayDriver:
                         sp.set_tag("reruns", st.reruns)
                         sp.set_tag("rerun_txs", st.rerun_txs)
                         sp.set_tag("fallback", int(st.fallback))
+                        # execute outside its lanes (ledger.py
+                        # EXEC_PARTS), and the world copies inside them
+                        for part, secs in st.part_seconds.items():
+                            sp.set_tag(part + "_s", secs)
+                        sp.set_tag("copies", st.copies)
+                        sp.set_tag("copy_s", st.copy_seconds)
                     ph["execute"] += time.perf_counter() - t0
                     t0 = time.perf_counter()
-                    committer.commit_block(
-                        result.world, header,
-                        txs=(
-                            [stx.hash
-                             for stx in block.body.transactions]
-                            if JOURNEY.enabled else None
-                        ),
-                    )
+                    with span("commit", block=header.number) as sp:
+                        # by part: storage tries, account trie, root
+                        for tag, v in committer.commit_block(
+                            result.world, header,
+                            txs=(
+                                [stx.hash
+                                 for stx in block.body.transactions]
+                                if JOURNEY.enabled else None
+                            ),
+                        ).items():
+                            sp.set_tag(tag, v)
                     ph["commit"] += time.perf_counter() - t0
                     # window bookkeeping stays INSIDE the span: each
                     # statement outside a driver phase is a chance to
@@ -1097,6 +1113,13 @@ class ReplayDriver:
                     window_blocks[header.number] = block
                     results_cur.append((block, result))
                     prev = header
+                    if traced:
+                        for tag, a, b in zip(
+                            ("misses", "miss_s", "miss_wait_s",
+                             "miss_engine_s"),
+                            miss0, thread_misses(me),
+                        ):
+                            build_sp.set_tag(tag, b - a)
                 if len(results_cur) >= window_size:
                     # NO barrier before seal: cross-window refs resolve
                     # from the in-flight jobs' device digests (resolved-

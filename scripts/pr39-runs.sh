@@ -5,9 +5,9 @@
 # this PR also prints what khipu_trie_* gained over the window), and two more
 # values of <trace>: `p` runs scripts/profile_execute.py on the cell's traffic
 # at 20,000 accounts (cProfile's table of execute_block), `w` the same without
-# cProfile (the wall time a block and the counters); `s` is an untraced run
-# of snap.statesync through scripts/statesync_window_dump.py (the window's
-# CPU seconds, switches, the machine's jiffies and the syncer's phases).
+# cProfile (the wall time a block and the counters). (`s`, an untraced run of
+# snap.statesync through a wrapper that printed the window's CPU seconds, went
+# with the wrapper in PR 42: the driver's slices say it since PR 41.)
 #   chiprun --timeout 3400 -- env CALL=<name> RUNS="<run> ..." bash scripts/pr39-runs.sh
 here=$(pwd)
 out=$here/chiprun_out/${CALL:?}; mkdir -p $out
@@ -26,9 +26,8 @@ for r in ${RUNS:?}; do
   ps -eo pid= | sort > $out/.pids_before
   runner="benchmark/run.py"
   [ "$trace" = 1 ] && runner="$here/scripts/execute_span_dump.py $out/$name.spans.jsonl"
-  [ "$trace" = s ] && runner="$here/scripts/statesync_window_dump.py"
   (cd $here/$dir && python3 $runner --workload $cell --seed $seed \
-     --seconds 45 --trace ${trace/s/0} ${control:+--control $control}) \
+     --seconds 45 --trace $trace ${control:+--control $control}) \
      > $out/$name.out 2> $out/$name.err
   echo "$name rc=$? wall=$(( $(date +%s) - t0 ))s"
   ps -eo pid=,ppid=,stat=,args= > $out/.ps_after

@@ -16,7 +16,8 @@ benchmark's chain children do. ``cProfile`` is on around
 blocks without it. Prints, a block: the trie's look-ups (count, us
 each, ms), ``TrieStorage.key_bytes``, every ``keccak256`` through
 ctypes, ``BlockWorldState.copy``, the planner, the two batch lanes,
-``bloom_of_logs``, and ``khipu_trie_*`` over the profiled blocks.
+``bloom_of_logs``, the blocks' own ``Stats`` (lanes, the parts outside
+them, world copies) and ``khipu_trie_*`` over the profiled blocks.
 
 Host Python is host Python: shares carry from a sandbox to the chip's
 host, rates do not, and cProfile itself inflates what is made of many
@@ -49,6 +50,8 @@ ROWS = [
     ("execute_call_batch", ["khipu_tpu.ledger.batch_call:execute_call_batch"]),
     ("execute_fast_batch", ["khipu_tpu.ledger.batch_exec:execute_fast_batch"]),
     ("bloom_of_logs", ["khipu_tpu.ledger.bloom:bloom_of_logs"]),
+    ("recover_senders (a cell's replay has done it before)",
+     ["khipu_tpu.domain.transaction:recover_senders"]),
     ("execute_block", ["khipu_tpu.ledger.ledger:execute_block"]),
 ]
 
@@ -150,6 +153,7 @@ def main() -> int:
     prof = cProfile.Profile()
     inner = chain_builder.execute_block
     seen = {"blocks": 0, "wall": 0.0, "txs": 0, "before": None}
+    booked: dict = {}  # the blocks' own Stats: lanes, parts, copies
 
     def execute_block(block, *a, **kw):
         seen["blocks"] += 1
@@ -162,11 +166,20 @@ def main() -> int:
         if not args.plain:
             prof.enable()
         try:
-            return inner(block, *a, **kw)
+            result = inner(block, *a, **kw)
         finally:
             if not args.plain:
                 prof.disable()
             seen["wall"] += time.perf_counter() - t0
+        st = result.stats
+        for k, secs in {**st.lane_seconds,
+                        **getattr(st, "part_seconds", {})}.items():
+            booked[k + "_s"] = booked.get(k + "_s", 0.0) + secs
+        booked["copies"] = booked.get("copies", 0) + getattr(st, "copies", 0)
+        booked["copy_ms (inside the lanes)"] = booked.get(
+            "copy_ms (inside the lanes)", 0.0
+        ) + 1000 * getattr(st, "copy_seconds", 0.0)
+        return result
 
     chain_builder.execute_block = execute_block
     t0 = time.perf_counter()
@@ -193,6 +206,13 @@ def main() -> int:
             each = 1e6 * cum / calls if calls else 0.0
             print(f"{label:<36}{calls / n:>10.1f}{each:>10.2f}"
                   f"{1000 * cum / n:>10.2f}")
+    named = sum(v for k, v in booked.items() if k.endswith("_s"))
+    print("the blocks' own Stats, ms a block (a parent has no parts): "
+          + ", ".join(f"{k} {(1000 if k.endswith('_s') else 1) * v / n:.3f}"
+                      for k, v in booked.items() if v)
+          + f"; no lane or part (here mostly recover_senders, which a "
+          f"cell's replay has done before execute): "
+          f"{1000 * (seen['wall'] - named) / n:.3f}")
     after = trie_read_samples()
     print("khipu_trie_* over the profiled blocks, a block:")
     for (name, _k, labels, a), (_n, _k2, _l, b) in zip(

@@ -426,15 +426,20 @@ class TestCompileCache:
 
 class TestPhaseBreakdown:
     def test_driver_phases_tile_the_wall(self):
-        """The per-phase breakdown of a recorded replay: the driver
-        phases tile the driver's wall clock, so their sum lands within
-        10% of the replay's measured wall time on the tiny fixture
-        chain. Host hasher keeps this out of 'slow'."""
-        # The timing-agreement checks retry over up to 3 independent
-        # runs: on a loaded CI box the scheduler can preempt the
-        # process between a span exit and the busy-clock stop, pushing
-        # any SINGLE run past the band — while a real accounting bug
-        # disagrees on every run. The structural checks (phases
+        """The per-phase breakdown of a recorded replay: the driver's
+        spans tile what ``ReplayStats.phases`` booked on the same
+        thread around the same statements (within 10%), and both tile
+        the driver's wall clock on the tiny fixture chain. Host hasher
+        keeps this out of 'slow'."""
+        # Spans and phases are read on the driver thread a few
+        # statements apart, so a pre-emption lands in both and the
+        # tight band holds beside busy neighbours. The wall also holds
+        # what no phase books (the stage threads' start, the closing
+        # joins), which stretches when the scheduler is slow to run
+        # them: that comparison keeps a band that holds under load.
+        # Both retry over up to 3 independent runs (the first replay of
+        # a process pays its imports inside a span); a real accounting
+        # bug disagrees on every run. The structural checks (phases
         # present, no drops, block count) assert unconditionally.
         for attempt in range(3):
             stats, spans = _recorded_replay(24, 8)
@@ -449,21 +454,34 @@ class TestPhaseBreakdown:
                 v for k, v in breakdown.items()
                 if k in recorder.DRIVER_PHASES
             )
+            ph = stats.phases
+            built = sum(
+                ph[k] for k in ("senders", "validate", "execute", "commit")
+            )
+            build_span = breakdown[recorder.PHASE_BUILD]
+            phases_ok = abs(build_span - built) <= 0.10 * build_span
+            # the seal close-out and the stalls are a millisecond or
+            # two in all: held to the whole, not each to itself
+            booked = built + ph["seal"] + ph["collect"] + ph["save"]
+            phases_ok = phases_ok and (
+                abs(driver_total - booked) <= 0.10 * driver_total
+            )
             wall_ok = (
-                abs(driver_total - stats.seconds) <= 0.10 * stats.seconds
+                0.65 * stats.seconds <= driver_total
+                <= 1.02 * stats.seconds
             )
             # same self-measurement bias allowance as
             # test_occupancy_agrees_with_gauge
             occ_ok = abs(
                 recorder.occupancy(spans) - stats.pipeline_occupancy
             ) < 0.08
-            if wall_ok and occ_ok:
+            if phases_ok and wall_ok and occ_ok:
                 break
         else:
             raise AssertionError(
-                "breakdown disagreed with wall clock on 3/3 runs: "
-                f"driver {driver_total} wall {stats.seconds} "
-                f"{breakdown}"
+                "breakdown disagreed with the phases or the wall clock "
+                f"on 3/3 runs: driver {driver_total} booked {booked} "
+                f"wall {stats.seconds} {breakdown} {ph}"
             )
 
 
