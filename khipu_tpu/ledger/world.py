@@ -503,10 +503,7 @@ class BlockWorldState:
                 if window is not None:
                     session = window.storage_session(ts.trie._root_ref)
                     upserts, removes = ts.dirty_pairs()
-                    for kb in removes:
-                        session = session.remove(kb)
-                    for kb, enc in upserts:
-                        session = session.put(kb, enc)
+                    session = session.update_many(removes, upserts)
                     root32 = session.force_hashed_root()
                     acc = Account(
                         nonce=acc.nonce,

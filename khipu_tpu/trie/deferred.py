@@ -464,8 +464,4 @@ def batch_commit(
         _logs={h: [c, e] for h, (c, e) in trie._logs.items()},
         _staged=dict(trie._staged),
     )
-    for key in removes:
-        d = d.remove(key)
-    for key, value in upserts:
-        d = d.put(key, value)
-    return d.commit(hasher)
+    return d.update_many(removes, upserts).commit(hasher)

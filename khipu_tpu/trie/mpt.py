@@ -26,6 +26,7 @@ delete), matching the reference's archive semantics.
 
 from __future__ import annotations
 
+from bisect import bisect_left
 from typing import Dict, List, Optional, Tuple, Union
 
 from khipu_tpu.base import rlp as _rlp
@@ -253,6 +254,31 @@ class MerklePatriciaTrie:
         t._log_remove(old_ref)
         return t
 
+    def update_many(self, removes, upserts) -> "MerklePatriciaTrie":
+        """``removes`` then ``upserts`` (``(key, value)`` pairs; of a
+        key given twice the last counts, and ``b""`` removes) as ONE
+        descent: the root, the live nodes and ``changes()`` are those of
+        the ``remove`` / ``put`` fold, but a node under which several
+        keys fall is resolved, rebuilt, encoded and ref'd once, not
+        once for every key under it. The removes stay a per-key fold
+        (``_delete`` collapses what it leaves, and a block removes
+        little)."""
+        t = self
+        for key in removes:
+            t = t.remove(key)
+        latest = dict(upserts)
+        for key in [k for k, v in latest.items() if v == b""]:
+            t = t.remove(key)
+            del latest[key]
+        if not latest:
+            return t
+        t = t._child()
+        items = [(bytes_to_nibbles(k), v) for k, v in sorted(latest.items())]
+        root = t._resolve(t._root_ref)
+        t._log_remove(t._root_ref)  # the old root node is superseded
+        t._root_ref = t._ref(t._insert_many(root, items, 0, len(items), 0))
+        return t
+
     def _child(self) -> "MerklePatriciaTrie":
         # Logs/staged are SHARED with the parent (not copied): a session
         # accumulates one write-log across all mutations until persist(),
@@ -340,6 +366,84 @@ class MerklePatriciaTrie:
             new_child = self._insert(child, nibbles[common:], value)
             return [node[0], self._ref(new_child)]
         return self._split(path, node[1], False, nibbles, value, common)
+
+    def _insert_many(self, node: Node, items: List, lo: int, hi: int,
+                     depth: int) -> Node:
+        """``_insert`` for ``items[lo:hi]``: ``(nibbles, value)`` pairs
+        sorted by nibbles, no key twice, all agreeing in
+        ``nibbles[:depth]``, the path down to ``node``. Every case ends
+        in ONE branch at the nibble where the keys part: the one that
+        was there, or a new one that also takes what a leaf or an
+        extension held. Each touched child of it is resolved,
+        superseded and ref'd once, before its parent."""
+        if hi - lo == 1:
+            nibbles, value = items[lo]
+            return self._insert(node, nibbles[depth:], value)
+        common = 0  # nibbles the keys share below ``depth``
+        held = None  # a shortened extension, not ref'd yet
+        if _is_branch(node):
+            branch = list(node)
+        else:
+            branch = [BLANK] * 16 + [b""]
+            path, is_leaf = (
+                (None, False) if node == BLANK else hp_decode(node[0])
+            )
+            if is_leaf:
+                # the leaf's entry is one more key, unless one overwrites it
+                old = items[lo][0][:depth] + path
+                items = items[lo:hi]
+                pos = bisect_left(items, (old,))
+                if pos == len(items) or items[pos][0] != old:
+                    items.insert(pos, (old, node[1]))
+                lo, hi = 0, len(items)
+            common = _common_prefix_len(
+                items[lo][0][depth:], items[hi - 1][0][depth:]
+            )
+            if path is not None and not is_leaf:  # an extension
+                common = _common_prefix_len(
+                    path, items[lo][0][depth : depth + common]
+                )
+                if common == len(path):  # every key goes on below it
+                    child_ref = node[1]
+                    child = self._resolve(child_ref)
+                    self._log_remove(child_ref)
+                    return [node[0], self._ref(self._insert_many(
+                        child, items, lo, hi, depth + common
+                    ))]
+                # what it led to hangs under the new branch: the child
+                # itself, or a shorter extension, which is ref'd only
+                # once no key has gone into it
+                rest = path[common:]
+                if len(rest) == 1:
+                    branch[rest[0]] = node[1]
+                else:
+                    held = [hp_encode(rest[1:], False), node[1]]
+                    branch[rest[0]] = held
+
+        at = depth + common
+        i = lo
+        if len(items[i][0]) == at:  # a key that ends at the branch
+            branch[16] = items[i][1]
+            i += 1
+        while i < hi:
+            nib = items[i][0][at]
+            j = i + 1
+            while j < hi and items[j][0][at] == nib:
+                j += 1
+            child_ref = branch[nib]
+            child = self._resolve(child_ref)
+            self._log_remove(child_ref)
+            branch[nib] = self._ref(
+                self._insert_many(child, items, i, j, at + 1)
+            )
+            i = j
+        if held is not None and branch[rest[0]] is held:
+            branch[rest[0]] = self._ref(held)
+        if common:
+            return [
+                hp_encode(items[lo][0][depth:at], False), self._ref(branch)
+            ]
+        return branch
 
     def _split(
         self,

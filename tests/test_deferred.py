@@ -4,6 +4,7 @@ equality with the eager host MPT, and the device/mesh integrations
 
 import random
 
+import numpy as np
 import pytest
 
 from khipu_tpu.base.crypto.keccak import keccak256
@@ -356,3 +357,231 @@ def test_seal_scan_matches_resolution_inputs():
     # seal pre-substitutes resolved placeholders; with none resolved
     # yet the encodings must be byte-identical too
     assert job.to_resolve == want_resolve
+
+
+# ---------------------------------------------------------------------------
+# update_many: a block's writes as one sorted batch. The per-key fold
+# (remove, then put) is the oracle.
+
+def _tall(rng, n):
+    """``n`` 32-byte keys as a block's are, with values of account size."""
+    return [(keccak256(rng.randbytes(4)), rng.randbytes(rng.randrange(40, 90)))
+            for _ in range(n)]
+
+
+def _squat(rng, n):
+    """``n`` short keys over a few nibbles, so that keys end inside
+    branches, share extensions and split leaves; every value is small
+    enough for its node to stay inline."""
+    return [
+        (bytes(rng.randrange(3) * 16 + rng.randrange(3)
+               for _ in range(rng.randrange(1, 5))),
+         rng.randbytes(rng.randrange(1, 4)))
+        for _ in range(n)
+    ]
+
+
+def _case_random(seed, maker, n_base, n_up, n_rm, blanks=0):
+    def make():
+        rng = random.Random(seed)
+        base = maker(rng, n_base)
+        ups = maker(rng, n_up) + [
+            (rng.choice(base)[0], rng.randbytes(50)) for _ in range(n_up // 2)
+        ] + [(rng.choice(base)[0], b"") for _ in range(blanks)]
+        rng.shuffle(ups)
+        rms = [rng.choice(base)[0] for _ in range(n_rm)] + [b"\x77absent"] * (
+            n_rm > 0)
+        return base, rms, ups
+    return make
+
+
+V = b"v" * 40  # a value whose leaf hashes
+# the base trie's root is an extension 1,2,3,4,5 over a branch
+EXT_BASE = [(b"\x12\x34\x56", V), (b"\x12\x34\x57", V)]
+UPDATE_MANY_CASES = {
+    "empty_trie": lambda: ([], [], _tall(random.Random(3), 40)),
+    "empty_trie_short_keys": lambda: ([], [], _squat(random.Random(4), 40)),
+    "empty_batch": lambda: (_tall(random.Random(5), 20), [], []),
+    "batch_of_one": lambda: (
+        _tall(random.Random(6), 50), [], _tall(random.Random(7), 1)),
+    "duplicate_keys_last_wins": lambda: (
+        _tall(random.Random(8), 10), [],
+        [(b"\x01" * 32, b"first" * 9), (b"\x02" * 32, V),
+         (b"\x01" * 32, b"last" * 9), (b"\x02" * 32, b""),
+         (b"\x03" * 32, b""), (b"\x03" * 32, V)]),
+    # one leaf at the root; keys past it, short of it, beside it, on it
+    "split_leaf": lambda: (
+        [(b"\x12\x34\x56", V)], [],
+        [(b"\x12\x34\x57", V), (b"\x12\x35\x00", V), (b"\x12\x34", V),
+         (b"\x12\x34\x56\x78", V)]),
+    "split_leaf_and_overwrite": lambda: (
+        [(b"\x12\x34\x56", V)], [],
+        [(b"\x12\x34\x56", b"w" * 40), (b"\x12\x30", V), (b"\x92", V)]),
+    # every key leaves the extension in its middle; what it led to
+    # moves under a shorter one that no key enters
+    "split_extension_untouched": lambda: (
+        EXT_BASE, [], [(b"\x12\x44\x00", V), (b"\x12\x45\x00", V)]),
+    # some leave it, one goes on into the shorter extension
+    "split_extension_entered": lambda: (
+        EXT_BASE, [], [(b"\x12\x44\x00", V), (b"\x12\x34\x58", V)]),
+    # left at its last nibble: the child moves up, and is entered
+    "split_extension_child_moves_up": lambda: (
+        EXT_BASE, [], [(b"\x12\x34\x60", V), (b"\x12\x34\x58", V),
+                       (b"\x12\x34\x56", b"w" * 40)]),
+    # left at its first nibble, and by a key that ends inside it
+    "split_extension_at_its_head": lambda: (
+        EXT_BASE, [], [(b"\x92\x34\x56", V), (b"\x12", V), (b"", V)]),
+    "all_below_extension": lambda: (
+        EXT_BASE, [], [(b"\x12\x34\x58", V), (b"\x12\x34\x59\x01", V),
+                       (b"\x12\x34\x59\x02", V)]),
+    "inline_nodes": _case_random(11, _squat, 30, 30, 0),
+    "inline_nodes_removes_mixed_in": _case_random(12, _squat, 40, 20, 8, 4),
+    "removes_mixed_in": _case_random(13, _tall, 300, 60, 25),
+    "blank_values": _case_random(14, _tall, 200, 40, 0, blanks=15),
+    "block_shape": _case_random(15, _tall, 3000, 350, 0),
+    "random_1": _case_random(21, _tall, 500, 120, 10, 5),
+    "random_2": _case_random(22, _squat, 80, 60, 10, 5),
+    "random_3": _case_random(23, _tall, 1, 200, 1, 1),
+}
+
+
+class _WriteOnce(dict):
+    """A staged map that refuses to stage a key twice."""
+
+    def __setitem__(self, key, value):
+        assert key not in self, "a placeholder was staged twice"
+        super().__setitem__(key, value)
+
+
+@pytest.mark.parametrize("kind", ["eager", "deferred"])
+@pytest.mark.parametrize("case", sorted(UPDATE_MANY_CASES))
+def test_update_many_equals_the_per_key_fold(case, kind):
+    from khipu_tpu.trie.deferred import (
+        DeferredMPT,
+        _is_placeholder,
+        find_sites,
+    )
+
+    base, removes, upserts = UPDATE_MANY_CASES[case]()
+    src = MemoryNodeDataSource()
+    parent = eager_apply(MerklePatriciaTrie(src), base, []).persist()
+    want = eager_apply(
+        MerklePatriciaTrie(src, root_hash=parent.root_hash), upserts, removes)
+
+    if kind == "eager":
+        got = MerklePatriciaTrie(
+            src, root_hash=parent.root_hash).update_many(removes, upserts)
+    else:
+        staged = _WriteOnce()
+        session = DeferredMPT(
+            src, root_hash=parent.root_hash, _staged=staged
+        ).update_many(removes, upserts)
+        created = session._counter[0]
+        phs = [ph for ph in staged if _is_placeholder(ph)]
+        assert len(phs) == created
+        live = [ph for ph, rec in session._logs.items()
+                if _is_placeholder(ph) and rec[0] > 0]
+        if not removes and all(v for _k, v in upserts):
+            # nothing is built that the batch's own root does not reach
+            assert created == len(live)
+        # children are ref'd before their parent: an own child's counter
+        # is below its parent's (WindowCommitter._pack_sites counts on it)
+        sites = find_sites([staged[ph] for ph in phs])
+        own = sites.ctr[(sites.ctr >= 0) & (sites.ctr < created)]
+        parents = np.array(
+            [int.from_bytes(ph[-14:], "big") for ph in phs])[sites.node]
+        parents = parents[(sites.ctr >= 0) & (sites.ctr < created)]
+        assert (own < parents).all()
+        got = session.commit(host_hasher)
+
+    assert got.root_hash == want.root_hash
+    removed_w, upserted_w = want.changes()
+    removed_g, upserted_g = got.changes()
+    assert sorted(removed_g) == sorted(removed_w)
+    assert upserted_g == upserted_w
+    # and what it built reads back
+    final = dict(base)
+    for k in removes:
+        final.pop(k, None)
+    for k, v in upserts:
+        final[k] = v
+    for k, v in final.items():
+        assert got.get(k) == (v or None)
+
+
+def test_window_commits_build_no_node_that_no_block_root_reaches():
+    """Three blocks through ``WindowCommitter.commit_block`` (accounts,
+    and storage tries written in several blocks): every root equals the
+    eager host world's, and the sealed window packs nothing but the
+    nodes its blocks' roots reach: the live ones, and those a later
+    block of the window superseded."""
+    from khipu_tpu.domain.account import Account
+    from khipu_tpu.domain.block_header import (
+        EMPTY_OMMERS_HASH,
+        BlockHeader,
+    )
+    from khipu_tpu.ledger.window import WindowCommitter
+    from khipu_tpu.ledger.world import BlockWorldState
+    from khipu_tpu.storage.storages import Storages
+    from khipu_tpu.trie.deferred import _PLACEHOLDER_PREFIX
+    from khipu_tpu.trie.mpt import EMPTY_TRIE_HASH
+
+    rng = random.Random(43)
+    addrs = [rng.randbytes(20) for _ in range(400)]
+    tokens = addrs[:3]
+
+    def write(world):
+        for a in rng.sample(addrs, 120):
+            world.save_account(
+                a, Account(nonce=rng.randrange(9), balance=rng.randrange(1, 10**18)))
+        for t in tokens:
+            world.save_account(t, world.get_account(t) or Account(nonce=1))
+            for _ in range(40):
+                # never 0: a removed key is a fold of its own, and what
+                # that builds and drops again is not this test's matter
+                world.save_storage(
+                    t, rng.randrange(64), 1 + rng.getrandbits(200))
+
+    host = Storages()
+    committer = WindowCommitter(Storages(), EMPTY_TRIE_HASH, hasher=host_hasher)
+    host_root, roots, state = EMPTY_TRIE_HASH, [], None
+    for number in (1, 2, 3):
+        state = rng.getstate()
+        eager = BlockWorldState(
+            MerklePatriciaTrie(host.account_node_storage, root_hash=host_root),
+            host.storage_node_storage, host.evmcode_storage)
+        write(eager)
+        host_root = eager.persist(
+            host.account_node_storage, host.storage_node_storage,
+            host.evmcode_storage)
+        roots.append(host_root)
+        rng.setstate(state)  # the same writes again
+        world = committer.make_world(None)
+        write(world)
+        parts = committer.commit_block(world, BlockHeader(
+            parent_hash=b"\x00" * 32, ommers_hash=EMPTY_OMMERS_HASH,
+            beneficiary=b"\x00" * 20, state_root=host_root,
+            transactions_root=b"\x00" * 32, receipts_root=b"\x00" * 32,
+            logs_bloom=b"\x00" * 256, difficulty=1, number=number,
+            gas_limit=1, gas_used=0, unix_timestamp=number))
+        # one descent a trie: well under the fold's nodes a put
+        assert 0 < parts["created"] < 3 * (parts["accounts"] + parts["slots"])
+
+    job = committer.seal()
+    committer.pack_and_dispatch(job)
+    packed = dict(job.to_resolve)
+    reached, todo = set(), [ref for _h, ref in job.pending_blocks]
+    while todo:
+        ph = todo.pop()
+        if ph in reached or ph not in packed:
+            continue
+        reached.add(ph)
+        enc, pos = packed[ph], packed[ph].find(_PLACEHOLDER_PREFIX)
+        while pos >= 0:
+            todo.append(enc[pos:pos + 32])
+            pos = enc.find(_PLACEHOLDER_PREFIX, pos + 32)
+    assert set(job.live) <= set(packed)
+    assert set(packed) == reached
+    assert set(packed) - set(job.live), "no block superseded another's nodes"
+    # collect checks each root against its header, and raises if not
+    assert [root for _h, root in committer.collect(job)] == roots
