@@ -11,7 +11,7 @@ khipu_tpu.storage.storages.Storages).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Tuple
 
 FAR = 1 << 62  # "fork not scheduled" sentinel block number
 
@@ -125,6 +125,12 @@ class SyncConfig:
     # node-download scheduler (processDownload:537-667 role)
     nodes_per_request: int = 50
     peer_request_timeout: float = 5.0
+    # fast sync's device mirror (storage/device_mirror.py): pairs of
+    # (rate blocks of 136 B, rows), a class each, sized to the state's
+    # own node counts; the board builds the mirror, every verified
+    # trie node is admitted to it and the sync closes with the device
+    # verify over all of it. Empty: no mirror, the host check alone
+    fast_sync_mirror_rows: Tuple[Tuple[int, int], ...] = ()
     commit_window_blocks: int = 1  # blocks batched per TPU trie commit
     # windows sealed-but-uncollected allowed in flight: the driver
     # seals window N+1 (cross-window refs ride the dispatch as

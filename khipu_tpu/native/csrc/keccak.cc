@@ -10,6 +10,11 @@
 // Exposed C ABI (ctypes, see khipu_tpu/native/keccak.py):
 //   khipu_keccak(rate_bytes, in, in_len, out, out_len)
 //   khipu_keccak_batch(rate_bytes, msgs, offsets, n, out, out_len)
+//   khipu_keccak_absorb(rate_bytes, state, in, n_blocks)
+//   khipu_keccak_peek(rate_bytes, state, tail, tail_len, out, out_len)
+// The last two are a running sponge whose 25 lanes the caller keeps: RLPx's
+// frame MAC absorbs every frame into one and reads a digest after each
+// without ending the stream (network/rlpx.py).
 
 #include <cstdint>
 #include <cstring>
@@ -95,6 +100,39 @@ extern "C" {
 void khipu_keccak(int rate, const uint8_t* in, uint64_t in_len, uint8_t* out,
                   int out_len) {
   keccak(rate, in, in_len, out, out_len);
+}
+
+// state: 25 lanes the caller keeps; in: n_blocks whole blocks of `rate`.
+void khipu_keccak_absorb(int rate, uint64_t* state, const uint8_t* in,
+                         uint64_t n_blocks) {
+  for (; n_blocks; --n_blocks, in += rate) {
+    for (int i = 0; i < rate / 8; ++i) {
+      uint64_t w;
+      std::memcpy(&w, in + 8 * i, 8);  // little-endian hosts only
+      state[i] ^= w;
+    }
+    keccak_f1600(state);
+  }
+}
+
+// The digest the stream would have if it ended after `tail` (< rate bytes
+// not absorbed yet); `state` is left as it was.
+void khipu_keccak_peek(int rate, const uint64_t* state, const uint8_t* tail,
+                       uint64_t tail_len, uint8_t* out, int out_len) {
+  uint64_t a[25];
+  uint8_t block[200];
+  std::memcpy(a, state, sizeof a);
+  std::memset(block, 0, rate);
+  std::memcpy(block, tail, tail_len);
+  block[tail_len] = 0x01;
+  block[rate - 1] |= 0x80;
+  for (int i = 0; i < rate / 8; ++i) {
+    uint64_t w;
+    std::memcpy(&w, block + 8 * i, 8);
+    a[i] ^= w;
+  }
+  keccak_f1600(a);
+  std::memcpy(out, a, out_len);
 }
 
 // msgs: concatenated messages; offsets: n+1 cumulative offsets.

@@ -29,6 +29,15 @@ def _get_lib():
                 ctypes.POINTER(ctypes.c_uint64), ctypes.c_uint64,
                 ctypes.c_char_p, ctypes.c_int,
             ]
+            lib.khipu_keccak_absorb.argtypes = [
+                ctypes.c_int, ctypes.POINTER(ctypes.c_uint64),
+                ctypes.c_char_p, ctypes.c_uint64,
+            ]
+            lib.khipu_keccak_peek.argtypes = [
+                ctypes.c_int, ctypes.POINTER(ctypes.c_uint64),
+                ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
+                ctypes.c_int,
+            ]
         _lib = lib
     return _lib
 
@@ -80,3 +89,31 @@ def keccak256_batch(messages: Sequence[bytes]) -> List[bytes]:
     lib.khipu_keccak_batch(_RATE_256, blob, offsets, n, out, 32)
     raw = out.raw
     return [raw[i * 32 : (i + 1) * 32] for i in range(n)]
+
+
+class RunningKeccak256:
+    """A Keccak-256 stream that can be read without being ended:
+    ``update`` absorbs, ``digest`` is what the stream would hash to if
+    it ended here, and the stream goes on (RLPx's frame MAC,
+    network/rlpx.py). The 25 lanes live here; the permutation runs in
+    the native library. Build one only where ``available()``."""
+
+    __slots__ = ("_lib", "_lanes", "_tail")
+
+    def __init__(self):
+        self._lib = _get_lib()
+        self._lanes = (ctypes.c_uint64 * 25)()
+        self._tail = b""  # under one block, not absorbed yet
+
+    def update(self, data: bytes) -> None:
+        data = self._tail + data
+        whole = len(data) // _RATE_256
+        if whole:
+            self._lib.khipu_keccak_absorb(_RATE_256, self._lanes, data, whole)
+        self._tail = data[whole * _RATE_256:]
+
+    def digest(self) -> bytes:
+        out = ctypes.create_string_buffer(32)
+        self._lib.khipu_keccak_peek(
+            _RATE_256, self._lanes, self._tail, len(self._tail), out, 32)
+        return out.raw

@@ -336,6 +336,10 @@ class PeerManager:
                     peer.disconnect(reason=0x03)  # useless peer
                     raise PeerError(f"fork check failed: {e}")
             peer.handlers.update(self.handlers)
+            # the dial's timeout bounded the handshakes; left on the
+            # socket it would end the reader loop, and with it the
+            # connection, after that long without a message
+            peer.sock.settimeout(None)
             peer.start_loop()
             with self._lock:
                 self.peers.append(peer)

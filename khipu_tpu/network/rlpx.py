@@ -28,6 +28,7 @@ from khipu_tpu.base.crypto.secp256k1 import (
     privkey_to_pubkey,
 )
 from khipu_tpu.base.rlp import rlp_decode_first, rlp_encode
+from khipu_tpu.native import keccak as native_keccak
 from khipu_tpu.network.ecies import decrypt as ecies_decrypt
 from khipu_tpu.network.ecies import ecdh_raw
 from khipu_tpu.network.ecies import encrypt as ecies_encrypt
@@ -37,7 +38,9 @@ _RATE = 136
 
 class _IncrementalKeccak:
     """Streaming keccak-256: update() absorbs, digest() pads a COPY of
-    the state so the stream continues — the RLPx MAC contract."""
+    the state so the stream continues — the RLPx MAC contract. The pure
+    sponge: ~0.5 ms a 136-byte block, 25 ms for the frame of one
+    50-node answer (``_mac_stream`` takes the native one where it can)."""
 
     __slots__ = ("state", "buffer")
 
@@ -71,6 +74,15 @@ class _IncrementalKeccak:
 
 def _xor(a: bytes, b: bytes) -> bytes:
     return bytes(x ^ y for x, y in zip(a, b))
+
+
+def _mac_stream():
+    """One direction's MAC sponge. Every byte of every frame goes
+    through it, so it is the native ``RunningKeccak256`` (the
+    permutation in C) unless the library is missing."""
+    if native_keccak.available():
+        return native_keccak.RunningKeccak256()
+    return _IncrementalKeccak()
 
 
 def _aes256_ctr_stream(key: bytes):
@@ -211,8 +223,8 @@ class AuthHandshake:
         aes = keccak256(eph + shared)
         mac = keccak256(eph + aes)
 
-        egress = _IncrementalKeccak()
-        ingress = _IncrementalKeccak()
+        egress = _mac_stream()
+        ingress = _mac_stream()
         if self.initiator:
             egress.update(_xor(mac, self.remote_nonce) + self.init_wire)
             ingress.update(_xor(mac, self.nonce) + self.ack_wire)

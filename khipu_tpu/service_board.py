@@ -77,6 +77,7 @@ class ServiceBoard:
         self._discovery = None
         self._regular_sync = None
         self._fast_sync = None
+        self._fast_sync_mirror = None
         self._cluster = None
         self._cluster_health = None
         self._rebalancer = None
@@ -416,10 +417,24 @@ class ServiceBoard:
         if self._peer_manager is None:
             raise RuntimeError("start_network first")
         kwargs.setdefault("cluster", self._cluster)
+        kwargs.setdefault("mirror", self.fast_sync_mirror)
         self._fast_sync = FastSyncService(
             self.blockchain, self.config, self._peer_manager, **kwargs
         )
         return self._fast_sync
+
+    @property
+    def fast_sync_mirror(self):
+        """The device mirror a fast sync fills and verifies: built on
+        first use with ``sync.fast_sync_mirror_rows`` per size class and
+        kept by the board, so that a sync started again finds what the
+        last one admitted. None where no rows are configured."""
+        rows = self.config.sync.fast_sync_mirror_rows
+        if self._fast_sync_mirror is None and rows:
+            from khipu_tpu.storage.device_mirror import DeviceNodeMirror
+
+            self._fast_sync_mirror = DeviceNodeMirror(dict(rows))
+        return self._fast_sync_mirror
 
     def start_discovery(self, host: str = "127.0.0.1", port: int = 30303) -> int:
         from khipu_tpu.network.discovery import DiscoveryService
@@ -437,7 +452,8 @@ class ServiceBoard:
     def shutdown(self) -> None:
         """CoordinatedShutdown (Khipu.scala:58-66): services first,
         storages flushed+closed last."""
-        for svc in (self._rpc_server, self._bridge_server,
+        for svc in (self._fast_sync, self._rpc_server,
+                    self._bridge_server,
                     self._peer_manager, self._discovery,
                     self._cluster_health, self._watchdog,
                     self._telemetry):

@@ -24,7 +24,8 @@ is the store-ingest side where the reference also pays its layout
 stays the backing byte store; the mirror is an accelerator cache with
 ring eviction, safe to drop at any time.
 
-Capacity is fixed per size class at construction: one preallocated
+Capacity is fixed per size class at construction (one number for all
+classes, or rows per class: ``DeviceNodeMirror.__init__``): one preallocated
 device buffer per class, filled in place with donated jit updates
 (stable shapes -> a handful of XLA compiles for the process lifetime).
 Unfilled rows hold a synthetic padding row whose claimed digest is
@@ -35,7 +36,7 @@ from __future__ import annotations
 
 import threading
 from functools import lru_cache
-from typing import Dict, List, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Tuple, Union
 
 import numpy as np
 
@@ -45,6 +46,7 @@ from khipu_tpu.observability.trace import span as _span
 from khipu_tpu.ops.keccak_jnp import RATE, class_tag
 
 TILE = 8 * 128  # messages per kernel tile (keccak_pallas.TILE)
+DEFAULT_ROWS = 16 * TILE  # a size class's ring where no one says
 
 MIRROR_GAUGES = REGISTRY.gauge_group("khipu_mirror", {
     # ring evictions that overwrote a window row BEFORE the persist
@@ -550,9 +552,26 @@ class DeviceNodeMirror:
     """Multi-class device mirror; admit in batches, verify in one
     dispatch per class. See module docstring."""
 
-    def __init__(self, capacity_rows_per_class: int = 16 * TILE,
-                 interpret: bool = False):
-        self.capacity = capacity_rows_per_class
+    def __init__(
+        self,
+        capacity_rows_per_class: Union[int, Mapping[int, int]] = DEFAULT_ROWS,
+        interpret: bool = False,
+    ):
+        """One number: every size class gets that many rows (the replay
+        path's ring). A mapping ``{rate blocks: rows}``: each class it
+        names gets its own rows, because a snapshot's classes differ a
+        hundred to one (fast sync sizes it from
+        ``SyncConfig.fast_sync_mirror_rows``); a class it does not name
+        takes the default ring."""
+        self.capacity = DEFAULT_ROWS
+        self.capacity_by_class: Dict[int, int] = {}
+        if isinstance(capacity_rows_per_class, Mapping):
+            self.capacity_by_class = {
+                int(nb): int(rows)
+                for nb, rows in capacity_rows_per_class.items()
+            }
+        else:
+            self.capacity = capacity_rows_per_class
         self.interpret = interpret
         # keyed by (nblocks, exact_len-or-None): generic padded classes
         # serve arbitrary node lengths; exact classes store uniform-
@@ -567,7 +586,9 @@ class DeviceNodeMirror:
         cm = self._classes.get(key)
         if cm is None:
             cm = _ClassMirror(
-                nblocks, self.capacity, self.interpret, exact_len
+                nblocks,
+                self.capacity_by_class.get(nblocks, self.capacity),
+                self.interpret, exact_len,
             )
             self._classes[key] = cm
         return cm

@@ -389,6 +389,11 @@ class _PhaseClock:
         self.t, self.phase = now, name
 
 
+class SyncStopped(Exception):
+    """``StateSyncer.stop`` was honoured: the batch in hand was stored,
+    the checkpoint written, and ``start`` left with work pending."""
+
+
 class StateSyncer:
     """Download a state trie to local storages via a fetch callback,
     with checkpoint/resume (SyncingHandler role, peers abstracted).
@@ -418,6 +423,7 @@ class StateSyncer:
         # so the post-sync whole-snapshot re-verification (config #5)
         # runs on resident tiles with zero layout work
         self.mirror = mirror
+        self._stop_asked = False
         self.stats = SyncStats()
         # the newest syncer owns the slot: one fast sync runs at a time
         REGISTRY.register_collector("fastsync", self.stats.samples)
@@ -432,19 +438,32 @@ class StateSyncer:
             digests = self.hasher(values)
             return [d == h for d, h in zip(digests, hashes)]
 
-    def start(self, target_root: bytes) -> SyncState:
+    def stop(self) -> None:
+        """Ask a running ``start`` (another thread's) to leave: after
+        the batch in hand it writes the checkpoint and raises
+        ``SyncStopped``, so that the next ``start`` resumes with
+        nothing to fetch twice (a node's shutdown; a kill resumes from
+        the last periodic checkpoint instead)."""
+        self._stop_asked = True
+
+    def start(self, target_root: bytes,
+              batch_size: Optional[int] = None) -> SyncState:
         """Begin (or resume) syncing toward target_root; runs to
         completion (the peer-request loop is the fetch callback's
-        concern). Returns the final state."""
+        concern). Returns the final state. ``batch_size``: nodes a
+        batch for this run, where the caller knows it only now (a peer
+        pool's width); None: the constructor's."""
+        self._stop_asked = False
         clock = _PhaseClock(self.stats, "checkpoint")  # the resume read
         try:
-            return self._run(target_root, clock.enter)
+            return self._run(target_root, clock.enter,
+                             batch_size or self.batch_size)
         finally:
             # also when fetch (a peer pool) raises out of the loop: the
             # open phase and the loop's seconds are booked either way
             clock.enter(None)
 
-    def _run(self, target_root: bytes, enter) -> SyncState:
+    def _run(self, target_root: bytes, enter, batch_size: int) -> SyncState:
         stats = self.stats
         state = self.state_storage.get_sync_state()
         if state is None or state.target_root != target_root:
@@ -464,12 +483,12 @@ class StateSyncer:
         seen: Set[bytes] = set()
         while pending:
             with span("fastsync.batch", batch=batches_done,
-                      nodes=min(self.batch_size, len(pending)),
+                      nodes=min(batch_size, len(pending)),
                       pending=len(pending)):
                 enter("queue")
                 with span("fastsync.queue"):
                     batch = [pending.popleft() for _ in range(
-                        min(self.batch_size, len(pending)))]
+                        min(batch_size, len(pending)))]
                     taken += len(batch)
                     want = [h for _, h in batch]
                 enter("fetch")
@@ -542,7 +561,8 @@ class StateSyncer:
                             f"no progress: {len(missing)} nodes unavailable"
                         )
                 batches_done += 1
-                if batches_done % self.checkpoint_every == 0:
+                stopping = self._stop_asked
+                if stopping or batches_done % self.checkpoint_every == 0:
                     enter("checkpoint")
                     with span("fastsync.checkpoint",
                               pending=len(pending)) as sp:
@@ -561,6 +581,10 @@ class StateSyncer:
                 stats.requested += len(want)
                 stats.pending = len(pending)
                 stats.pending_max = max(stats.pending_max, stats.pending)
+            if stopping and pending:
+                raise SyncStopped(
+                    f"stopped after {state.downloaded_nodes} nodes, "
+                    f"{len(pending)} pending")
         if self.mirror is not None:
             # re-verification of every RESIDENT node on word-major
             # tiles: one dispatch per size class, zero layout work.

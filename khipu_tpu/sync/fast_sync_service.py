@@ -18,9 +18,11 @@ the whole flow end to end.
 from __future__ import annotations
 
 import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Mapping, Optional
 
-from khipu_tpu.base.crypto.keccak import keccak256
+from khipu_tpu.native.keccak import keccak256_batch
 from khipu_tpu.config import KhipuConfig
 from khipu_tpu.domain.block import BlockBody
 from khipu_tpu.domain.block_header import BlockHeader
@@ -41,6 +43,8 @@ from khipu_tpu.network.messages import (
     decode_headers,
 )
 from khipu_tpu.network.peer import Peer, PeerError, PeerManager
+from khipu_tpu.observability.registry import REGISTRY
+from khipu_tpu.observability.trace import current_tracer, span
 from khipu_tpu.sync.fast_sync import FastSyncStateStorage, StateSyncer, SyncState
 from khipu_tpu.validators.roots import (
     ommers_hash,
@@ -51,6 +55,49 @@ from khipu_tpu.validators.roots import (
 
 class FastSyncError(Exception):
     pass
+
+
+# node-data requests the pool may have in flight, however many peers
+# are live (the upstream's max-concurrent-requests)
+MAX_CONCURRENT_REQUESTS = 50
+
+
+def _pool_counter(name: str, help: str, **labels):
+    return REGISTRY.counter(
+        "khipu_fastsync_" + name,
+        help=help + " (sync/fast_sync_service.py)", labels=labels or None)
+
+
+# The pool's books: the process's, so they run on through a restarted
+# sync's new pool. Added to once a request, under the pool's lock.
+PEER_REQUESTS = {
+    outcome: _pool_counter(
+        "peer_requests_total", "node-data requests to peers, by outcome",
+        outcome=outcome)
+    for outcome in ("ok", "timeout", "garbage")
+}
+PEER_REQUEST_SECONDS = _pool_counter(
+    "peer_request_seconds_total",
+    "seconds from a node-data request's send to its answer or timeout")
+PEER_BYTES = _pool_counter(
+    "peer_bytes_total", "bytes of the blobs peers answered with")
+PEERS_BLACKLISTED = _pool_counter(
+    "peers_blacklisted_total",
+    "peers blacklisted for stalling or for an answer that is no NodeData")
+PEER_IN_FLIGHT_SECONDS = _pool_counter(
+    "peer_requests_in_flight_seconds_total",
+    "time-integral of node-data requests in flight: over the fetch "
+    "phase's seconds, how many peers were working at once")
+
+
+def _node_data(body) -> List[bytes]:
+    """The blobs of a NodeData answer. ``Peer.request`` hands over the
+    peer's RLP as it came: anything but a list of strings raises."""
+    if not isinstance(body, list):
+        raise ValueError("NodeData: not a list")
+    if not all(isinstance(b, (bytes, bytearray, memoryview)) for b in body):
+        raise ValueError("NodeData: a nested list among the blobs")
+    return [bytes(b) for b in body]
 
 
 class PeerFetchPool:
@@ -73,6 +120,13 @@ class PeerFetchPool:
         self.max_rounds = max_rounds
         self.log = log or (lambda s: None)
         self.blacklisted = 0
+        # requests on the wire now, and since when that many
+        self._in_flight = 0
+        self._in_flight_since = 0.0
+        self._lock = threading.Lock()
+        # kept from call to call, started by the first ``fetch_nodes``
+        # and ended by ``close``
+        self._workers: Optional[ThreadPoolExecutor] = None
         self._rr = 0  # rotating start so small fetches still spread
         # sharded node-cache cluster: consulted before the peer pool —
         # a shard read is one verified RPC vs. a devp2p round-trip, and
@@ -86,6 +140,28 @@ class PeerFetchPool:
             if p.alive
             and not self.manager.blacklist.is_blacklisted(p.remote_pub)
         ]
+
+    def width(self) -> int:
+        """Requests the pool may have in flight: one a live peer, under
+        the cap. A syncer batch of ``per_request`` times this gives
+        ``fetch_nodes`` a chunk for every peer."""
+        return max(1, min(len(self._live_peers()), MAX_CONCURRENT_REQUESTS))
+
+    def close(self) -> None:
+        """End the pool's worker threads (the next ``fetch_nodes``
+        starts new ones)."""
+        workers, self._workers = self._workers, None
+        if workers is not None:
+            workers.shutdown(wait=True)
+
+    def _flight(self, change: int) -> None:
+        """A request went out or came back (``_lock`` held): book the
+        time the old count stood."""
+        now = time.perf_counter()
+        PEER_IN_FLIGHT_SECONDS.inc(
+            self._in_flight * (now - self._in_flight_since))
+        self._in_flight += change
+        self._in_flight_since = now
 
     def fetch_nodes(self, hashes: List[bytes]) -> Mapping[bytes, bytes]:
         """StateSyncer fetch callback: every returned value is keyed by
@@ -108,40 +184,75 @@ class PeerFetchPool:
                 raise FastSyncError("no live peers for node download")
             start = self._rr % len(peers)
             self._rr += 1
-            peers = peers[start:] + peers[:start]
+            # one request a peer in flight, and no more than the cap
+            peers = (peers[start:] + peers[:start])[:MAX_CONCURRENT_REQUESTS]
             chunks = [
                 pending[i : i + self.per_request]
                 for i in range(0, len(pending), self.per_request)
             ]
-            lock = threading.Lock()
+            lock = self._lock
             got_any = [False]
+            # the workers' spans: this thread's tracer, under its span
+            tracer = current_tracer()
+            token = tracer.current_token()
 
             def worker(peer: Peer, mine: List[List[bytes]]) -> None:
                 for chunk in mine:
-                    try:
-                        body = peer.request(
-                            ETH_OFFSET + GET_NODE_DATA,
-                            list(chunk),
-                            ETH_OFFSET + NODE_DATA,
-                            timeout=self.timeout,
-                        )
-                    except PeerError:
-                        # stalling / dead peer: blacklist, abandon its
-                        # remaining chunks (requeued by the outer round)
+                    with tracer.span(
+                        "fastsync.pool.request", parent=token,
+                        peer=peer.remote_pub[:4].hex(), hashes=len(chunk),
+                    ) as sp:
+                        with lock:
+                            self._flight(+1)
+                        t0 = time.perf_counter()
+                        try:
+                            body = peer.request(
+                                ETH_OFFSET + GET_NODE_DATA,
+                                list(chunk),
+                                ETH_OFFSET + NODE_DATA,
+                                timeout=self.timeout,
+                            )
+                            outcome = "ok"
+                        except PeerError:
+                            outcome = "timeout"
+                        seconds = time.perf_counter() - t0
+                        blobs: List[bytes] = []
+                        if outcome == "ok":
+                            # the peer's RLP, unchecked until here
+                            try:
+                                blobs = _node_data(body)
+                            except ValueError:
+                                outcome = "garbage"
+                        nbytes = sum(map(len, blobs))
+                        sp.set_tag("blobs", len(blobs))
+                        sp.set_tag("bytes", nbytes)
+                        sp.set_tag("outcome", outcome)
+                        with lock:
+                            self._flight(-1)
+                            PEER_REQUESTS[outcome].inc()
+                            PEER_REQUEST_SECONDS.inc(seconds)
+                            PEER_BYTES.inc(nbytes)
+                    if outcome != "ok":
+                        # stalling, dead or lying peer: blacklist,
+                        # abandon its remaining chunks (requeued by the
+                        # outer round)
                         self.manager.blacklist.add(
                             peer.remote_pub, duration=600.0
                         )
                         peer.disconnect()
                         self.blacklisted += 1
+                        PEERS_BLACKLISTED.inc()
                         self.log(
-                            "blacklisted stalling peer "
-                            f"{peer.remote_pub[:4].hex()}"
+                            f"blacklisted peer {peer.remote_pub[:4].hex()}"
+                            f": {outcome}"
                         )
                         return
+                    # one native call hashes the answer's blobs, on
+                    # this worker and outside the lock
+                    keyed = dict(zip(keccak256_batch(blobs), blobs))
                     with lock:
-                        for blob in body:
-                            results[keccak256(bytes(blob))] = bytes(blob)
-                            got_any[0] = True
+                        results.update(keyed)
+                        got_any[0] = got_any[0] or bool(keyed)
 
             # round-robin chunk assignment across the live pool
             assign: Dict[int, List[List[bytes]]] = {
@@ -149,17 +260,20 @@ class PeerFetchPool:
             }
             for i, chunk in enumerate(chunks):
                 assign[i % len(peers)].append(chunk)
-            threads = [
-                threading.Thread(
-                    target=worker, args=(peers[i], assign[i]), daemon=True
-                )
+            # the pool's own workers, kept from call to call: starting
+            # a thread a peer a round cost more than the requests did
+            # (each start waits for the GIL to go to the new thread and
+            # come back, while the workers already out compete for it)
+            if self._workers is None:
+                self._workers = ThreadPoolExecutor(
+                    max_workers=MAX_CONCURRENT_REQUESTS,
+                    thread_name_prefix="fastsync-pool")
+            for done in [
+                self._workers.submit(worker, peers[i], assign[i])
                 for i in range(len(peers))
                 if assign[i]
-            ]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
+            ]:
+                done.result()
             pending = [h for h in pending if h not in results]
             if pending and not got_any[0] and not self._live_peers():
                 break
@@ -178,11 +292,18 @@ class FastSyncService:
         hasher=None,
         log: Optional[Callable[[str], None]] = None,
         cluster=None,
+        mirror=None,
     ):
+        """``hasher``: the node's batched device hasher for the
+        per-batch content-address check (None: the host's). ``mirror``:
+        the node's ``DeviceNodeMirror``; every verified trie node is
+        admitted to it and the sync closes with the device verify over
+        all of it (None: no mirror, the per-batch check alone)."""
         self.blockchain = blockchain
         self.config = config
         self.manager = manager
         self.hasher = hasher
+        self.mirror = mirror
         self.log = log or (lambda s: None)
         sync = config.sync
         self.min_peers = sync.min_peers_to_choose_pivot
@@ -193,6 +314,16 @@ class FastSyncService:
             timeout=sync.peer_request_timeout,
             log=self.log,
             cluster=cluster,
+        )
+        # built here, not in run(): from now on the registry's
+        # khipu_fastsync_* families are this service's, from zero
+        self.syncer = StateSyncer(
+            blockchain.storages,
+            FastSyncStateStorage(blockchain.storages.app_state.source),
+            self.pool.fetch_nodes,
+            batch_size=sync.nodes_per_request,  # run() asks for wider
+            hasher=hasher,
+            mirror=mirror,
         )
 
     # -------------------------------------------------------------- pivot
@@ -213,6 +344,12 @@ class FastSyncService:
     def choose_pivot(self) -> BlockHeader:
         """Median best number over >= min_peers peers, minus the offset
         (FastSyncService.scala:184-273)."""
+        with span("fastsync.pivot", min_peers=self.min_peers) as sp:
+            header = self._choose_pivot()
+            sp.set_tag("number", header.number)
+            return header
+
+    def _choose_pivot(self) -> BlockHeader:
         peers = [p for p in self.pool._live_peers() if p.status is not None]
         if len(peers) < self.min_peers:
             raise FastSyncError(
@@ -263,6 +400,10 @@ class FastSyncService:
         """Headers/bodies/receipts genesis..pivot, stored WITHOUT
         execution (the state trie arrived separately); every link is
         validated: parent hashes, tx/ommers roots, receipts roots."""
+        with span("fastsync.backfill", pivot=pivot.number):
+            self._backfill(pivot)
+
+    def _backfill(self, pivot: BlockHeader) -> None:
         s = self.blockchain.storages
         expected_parent = self.blockchain.get_hash_by_number(0)
         td = self.blockchain.get_total_difficulty(0) or 0
@@ -380,20 +521,32 @@ class FastSyncService:
 
     def run(self) -> SyncState:
         """Full fast sync: pivot -> state download -> block backfill.
-        After this, regular sync takes over from the pivot."""
-        pivot = self.choose_pivot()
-        syncer = StateSyncer(
-            self.blockchain.storages,
-            FastSyncStateStorage(self.blockchain.storages.app_state.source),
-            self.pool.fetch_nodes,
-            batch_size=self.config.sync.nodes_per_request,
-            hasher=self.hasher,
-        )
-        state = syncer.start(pivot.state_root)
-        self.log(
-            f"state download complete: {state.downloaded_nodes} nodes "
-            f"({self.pool.blacklisted} peers blacklisted)"
-        )
-        self._backfill_blocks(pivot)
-        self.log(f"backfilled block data to pivot #{pivot.number}")
-        return state
+        After this, regular sync takes over from the pivot. One syncer
+        batch is as many requests wide as the pool may have in flight,
+        so that every live peer is asked at once."""
+        try:
+            pivot = self.choose_pivot()
+            width = self.pool.width()
+            state = self.syncer.start(
+                pivot.state_root,
+                batch_size=self.config.sync.nodes_per_request * width)
+            self.log(
+                f"state download complete: {state.downloaded_nodes} nodes, "
+                f"{width} requests wide "
+                f"({self.pool.blacklisted} peers blacklisted)"
+            )
+            self._backfill_blocks(pivot)
+            self.log(f"backfilled block data to pivot #{pivot.number}")
+            return state
+        finally:
+            # however the run ends (done, stopped, failed): the pool's
+            # worker threads end with it
+            self.pool.close()
+
+    def stop(self) -> None:
+        """A node's shutdown while ``run`` is under way on another
+        thread: the syncer stores the batch in hand, writes its
+        checkpoint and ``run`` raises ``SyncStopped`` and closes the
+        pool on its way out; the next service's ``run`` resumes from
+        there. A service that never ran has nothing to stop."""
+        self.syncer.stop()

@@ -226,9 +226,14 @@ class TestConfigSurface:
             and cls is not config.KhipuConfig
         ]
         assert len(leaves) == 9
-        assert sum(len(dataclasses.fields(c)) for c in leaves) == 118
+        assert sum(len(dataclasses.fields(c)) for c in leaves) == 119
         sync = {f.name for f in dataclasses.fields(config.SyncConfig)}
-        assert len(sync) == 27
+        assert len(sync) == 28
+        # added in PR 44, with fast sync through the node's normal path:
+        # the fast-sync mirror's rows per size class (the pool's cap is
+        # a constant of sync/fast_sync_service.py: one value in use)
+        assert "fast_sync_mirror_rows" in sync
+        assert "max_concurrent_requests" not in sync
         # retired in PR 29 (one never ran, one lost on the chip): no
         # execute-stage device switch, no sender hash switch
         assert not [n for n in sync if n.startswith("exec_")

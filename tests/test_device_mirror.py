@@ -215,3 +215,60 @@ def test_long_string_overflow_rejected():
             R.rlp_decode(bad)
         with _pytest.raises(R.RLPError):
             R._py_rlp_decode(bad)
+
+
+# ---------------------------------------------- rows per size class
+
+
+def _nodes_of(length: int, n: int, tag: int):
+    encs = [bytes([tag]) + i.to_bytes(4, "big") + b"\x5a" * (length - 5)
+            for i in range(n)]
+    return {keccak256(e): e for e in encs}
+
+
+def test_rows_per_size_class_from_a_mapping():
+    m = DeviceNodeMirror({1: 3072, 4: 1024})
+    small, big = _nodes_of(70, 2500, 1), _nodes_of(532, 900, 2)
+    m.admit(small)
+    m.admit(big)
+    m.flush()
+    by_class = {nb: cm for (nb, _exact), cm in m._classes.items()}
+    assert by_class[1].capacity == 3072 and by_class[1].tiles == 3
+    assert by_class[4].capacity == 1024 and by_class[4].tiles == 1
+    # each class holds all of its own nodes: nothing wrapped round
+    assert by_class[1].count == 2500 and by_class[4].count == 900
+    assert m.resident_count == 3400 and m.verify() == 0
+
+
+def test_a_class_the_mapping_does_not_name_takes_the_default_ring():
+    m = DeviceNodeMirror({1: 2048})
+    m.admit(_nodes_of(300, 10, 3))  # class 3
+    m.flush()
+    (cm,) = m._classes.values()
+    assert cm.nblocks == 3 and cm.capacity == 16 * 1024
+    assert m.capacity_by_class == {1: 2048}
+
+
+@pytest.mark.parametrize("rows", [{1: 1000}, {"2": 1536}])
+def test_a_class_of_the_mapping_still_wants_whole_tiles(rows):
+    m = DeviceNodeMirror(rows)
+    nb = int(next(iter(rows)))
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        m.admit(_nodes_of(136 * nb - 60, 1, 4))
+        m.flush()
+
+
+def test_the_one_number_form_is_unchanged():
+    m = DeviceNodeMirror(2048)
+    assert m.capacity == 2048 and m.capacity_by_class == {}
+    m.admit(_nodes_of(70, 5, 5))
+    m.admit(_nodes_of(532, 5, 6))
+    m.flush()
+    assert {cm.capacity for cm in m._classes.values()} == {2048}
+    # a class built after the number was changed from outside takes the
+    # new one (benchmark/drivers/statesync.py sizes its classes so)
+    m.capacity = 1024
+    m.admit(_nodes_of(300, 5, 7))
+    m.flush()
+    assert m._class(3).capacity == 1024 and m._class(1).capacity == 2048
+    assert DeviceNodeMirror().capacity == 16 * 1024

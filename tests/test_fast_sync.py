@@ -352,6 +352,9 @@ class TestSyncStats:
                 name: {tuple(lb.values()): v for lb, v in samples}
                 for name, (_k, _h, samples) in fams.items()
                 if name.startswith("khipu_fastsync_")
+                # the peer pool's are the process's own counters
+                # (sync/fast_sync_service.py), no syncer's
+                and not name.startswith("khipu_fastsync_peer")
             }
 
         root, nodes = _account_trie(300)

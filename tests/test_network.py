@@ -98,6 +98,32 @@ class TestIncrementalKeccak:
         assert k.digest() == keccak256(blob)
 
 
+    def test_the_native_running_sponge_is_the_pure_one(self):
+        """``rlpx._mac_stream`` hands the frame MAC the native sponge:
+        same digests as the pure one after every update, however the
+        stream is cut, and the stream goes on after each read."""
+        import random
+
+        from khipu_tpu.native import keccak as native_keccak
+        from khipu_tpu.network import rlpx
+
+        if not native_keccak.available():
+            pytest.skip("no native library on this host")
+        assert isinstance(rlpx._mac_stream(),
+                          native_keccak.RunningKeccak256)
+        rng = random.Random(44)
+        for _ in range(40):
+            native, pure, whole = (
+                native_keccak.RunningKeccak256(), _IncrementalKeccak(), b"")
+            for _ in range(rng.randint(1, 12)):
+                chunk = rng.randbytes(
+                    rng.choice([0, 1, 16, 135, 136, 137, 272, 300, 1000]))
+                native.update(chunk)
+                pure.update(chunk)
+                whole += chunk
+                assert native.digest() == pure.digest() == keccak256(whole)
+
+
 class TestRlpxHandshake:
     def _pair(self):
         initiator = AuthHandshake(PRIV_A)
